@@ -532,14 +532,9 @@ _KERNEL_TARGETS = re.compile(r"tpu_custom_call|mosaic|__gpu\$xla\.gpu\.triton")
 
 
 def _kernel_name_of_eqn(eqn) -> str:
-    """The kernel function's bare name from a pallas_call eqn's
-    name_and_src_info param (src location stripped — fingerprints must not
-    carry file:line churn)."""
-    info = eqn.params.get("name_and_src_info")
-    name = getattr(info, "name", None)
-    if not name:
-        name = str(info).split(" at ")[0] if info is not None else "pallas_kernel"
-    return name
+    """The kernel's bare name from a pallas_call eqn's ``name`` param (no
+    source location: fingerprints must not carry file:line churn)."""
+    return eqn.params.get("name") or "pallas_kernel"
 
 
 def _walk_jaxpr_kernels(jaxpr) -> list:
@@ -617,9 +612,9 @@ def _walk_jaxpr_callbacks(jaxpr) -> list:
 
 
 def _sub_jaxprs(val):
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, ClosedJaxpr):
         yield val.jaxpr
     elif hasattr(val, "eqns"):
         yield val

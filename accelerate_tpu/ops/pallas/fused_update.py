@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..registry import register_op
+from ..registry import _WARNED, register_op
 
 logger = logging.getLogger(__name__)
 
@@ -102,7 +102,21 @@ def _inner_update_fns(tx):
 
 def plan_fused_update(tx) -> FusedUpdatePlan | None:
     """Match ``tx`` against the supported optax constructions; None = run the
-    reference path (unsupported chains are a fallback, never an error)."""
+    reference path (unsupported chains are a fallback, never an error). Every
+    caller asks only when the ``fused_update`` kernel was requested, so a
+    None is logged once: the kernel that was asked for is not what runs."""
+    plan = _match_fused_update(tx)
+    if plan is None and "fused_update:unplanned" not in _WARNED:
+        _WARNED.add("fused_update:unplanned")
+        logger.warning(
+            "kernels: fused_update was requested but this optimizer is not one "
+            "the kernel knows (adam, adamw, sgd with a constant step size); "
+            "its update runs on the reference lowering."
+        )
+    return plan
+
+
+def _match_fused_update(tx) -> FusedUpdatePlan | None:
     fns = _inner_update_fns(tx)
     if not fns:
         return None

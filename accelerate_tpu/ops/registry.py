@@ -12,11 +12,10 @@ quantized matmul) and their ``ops/pallas/`` kernels:
   implementation (a ``pallas_call`` accepting ``interpret=``);
 - :func:`resolve_backend` maps the operator's spec (call-site override >
   ``ACCELERATE_KERNELS`` env) to one of ``pallas`` / ``interpret`` /
-  ``reference`` per op. ``pallas`` resolves to the compiled Mosaic kernel
-  only on a TPU backend; elsewhere it degrades to ``interpret`` — the same
-  kernel body run by the Pallas interpreter, which is what makes CPU parity
-  tests exercise the *kernel's* math, not a stand-in (and is why
-  ``ACCELERATE_KERNELS=pallas`` is safe to set fleet-wide);
+  ``reference`` per op. On a TPU backend ``pallas`` always means the compiled
+  Mosaic kernel; off the TPU the same token runs the same kernel body under
+  the Pallas interpreter (``interpret``), which is what the CPU parity tests
+  rely on to exercise the *kernel's* math and not a stand-in;
 - specs may be a bare token (applies to every op) or a per-op map
   (``paged_decode=pallas,int8_matmul=off``); unset means ``reference``.
 
@@ -78,14 +77,17 @@ def register_op(name: str, reference, kernel, doc: str = "") -> None:
 
 
 def _ensure_registered() -> None:
-    """Import the kernel modules (each self-registers) exactly once; a broken
-    pallas import degrades every op to its reference lowering rather than
-    taking the framework down — the always-available-fallback contract."""
+    """Import the kernel modules (each self-registers) exactly once. Off the
+    TPU a broken pallas import degrades every op to its reference lowering
+    (logged once); on a TPU backend it raises, because there a kernel that
+    was asked for must not be swapped for its reference without a word."""
     if _OPS:
         return
     try:
         from . import pallas  # noqa: F401  (self-registers on import)
     except Exception as exc:  # pragma: no cover - env-specific
+        if pallas_supported():
+            raise
         if "import" not in _WARNED:
             _WARNED.add("import")
             logger.warning(

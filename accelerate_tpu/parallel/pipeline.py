@@ -181,10 +181,10 @@ class PipelineSpec:
             segments = _window_segments(seq_or_none) if seq_or_none is not None else None
 
             def body(stage_layers, x, ctx_local):
-                # Aux accumulators ride as (1,) vectors, never rank-0: the
-                # 0.4.x shard_map transpose rematerializes device-varying
-                # residuals through an all-axes out_spec, which has no dim to
-                # pin on a scalar ("add at least one (singleton) axis").
+                # Aux accumulators ride as (1,) vectors, never rank-0:
+                # shard_map's transpose rematerializes device-varying
+                # residuals through an out_spec, which needs a dim to pin:
+                # a scalar has none ("add at least one (singleton) axis").
                 aux_acc = tuple(jnp.zeros((1,), jnp.float32) for _ in aux_keys)
 
                 def run_segment(x, aux_acc, seg, pattern):

@@ -1569,10 +1569,10 @@ class ContinuousBatcher:
 
     def _decode(self):
         """Compiled ``sync_every``-token window for all B slots — ONE program
-        dispatch per host check (a ``lax.scan`` over steps), so neither local
-        dispatch overhead nor a remote tunnel's per-call RTT is paid per
-        token. Inactive rows feed pads and their freshly written cache
-        columns are invalidated."""
+        dispatch per host check (a ``lax.scan`` over steps), so the host's
+        dispatch overhead is paid per window and not per token. Inactive
+        rows feed pads and their freshly written cache columns are
+        invalidated."""
         if self.paged:
             return self._decode_paged()
         if self._decode_fn is not None:

@@ -153,9 +153,9 @@ class PartialState:
         from .utils.xla_flags import install_preset_from_env
 
         install_preset_from_env()
-        # Persistent XLA compilation cache (ACCELERATE_COMPILE_CACHE_DIR):
-        # configured before the first compile so restarted jobs (and every
-        # bench re-run) load their programs instead of re-building them.
+        # Persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR, else
+        # ACCELERATE_COMPILE_CACHE_DIR): configured before the first compile
+        # so restarted jobs load their programs instead of re-building them.
         maybe_enable_compilation_cache()
         _maybe_init_jax_distributed()
         # Resilience wiring (resilience/): count this gang incarnation in the

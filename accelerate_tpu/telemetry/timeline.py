@@ -31,11 +31,14 @@ import jax
 
 from ..utils.transfer import array_is_ready, host_fetch
 
-# bf16 peak FLOPs per chip by generation (fallback: v5e) — the denominator of
-# the MFU estimate; bench.py's peak_flops_per_chip delegates here.
+# bf16 peak FLOPs per chip, keyed by a substring of ``device_kind`` (first
+# match wins): the denominator of the MFU estimate; bench.py's
+# peak_flops_per_chip delegates here. Source: Google Cloud TPU documentation
+# (v5e: 197 TFLOP/s). A device that is not listed has NO peak.
 _PEAK_FLOPS_BF16 = {
     "v5 lite": 197e12,
     "v5litepod": 197e12,
+    "v5e": 197e12,
     "v4": 275e12,
     "v5p": 459e12,
     "v5": 459e12,
@@ -44,18 +47,17 @@ _PEAK_FLOPS_BF16 = {
 }
 
 
-def device_peak_flops(device=None) -> float:
-    """bf16 peak for the local chip generation (fallback: v5e)."""
-    try:
-        if device is None:
-            device = jax.devices()[0]
-        kind = device.device_kind.lower()
-    except Exception:
-        return 197e12
+def device_peak_flops(device=None) -> float | None:
+    """bf16 peak of ``device`` (default: the first local one), or None for a
+    device kind the table does not know, the CPU included: no utilisation is
+    stated against a guessed peak."""
+    if device is None:
+        device = jax.devices()[0]
+    kind = device.device_kind.lower()
     for key, val in _PEAK_FLOPS_BF16.items():
         if key in kind:
             return val
-    return 197e12
+    return None
 
 
 def device_memory_stats() -> dict:
@@ -229,10 +231,10 @@ class StepTimeline:
             if per_tokens and wall > 0:
                 tps = per_tokens / wall
                 self._tokens_gauge.set(tps)
-                if self._flops_per_token:
+                peak = device_peak_flops() if self._flops_per_token else None
+                if peak is not None:
                     self._last_mfu = (
-                        tps * self._flops_per_token
-                        / (device_peak_flops() * jax.device_count())
+                        tps * self._flops_per_token / (peak * jax.device_count())
                     )
                     self._mfu_gauge.set(self._last_mfu)
         self._last_end = now
@@ -281,10 +283,9 @@ class StepTimeline:
         )
         mfu = None
         if tokens_per_s is not None and self._flops_per_token:
-            mfu = (
-                tokens_per_s * self._flops_per_token
-                / (device_peak_flops() * jax.device_count())
-            )
+            peak = device_peak_flops()
+            if peak is not None:
+                mfu = tokens_per_s * self._flops_per_token / (peak * jax.device_count())
         self._drain_loss()
         now_stats = transfer.transfer_stats()
         # A reset_transfer_stats() since this timeline baselined its deltas

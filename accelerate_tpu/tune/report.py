@@ -138,6 +138,11 @@ def load_winner(path: str) -> dict:
     return dict(winner["candidate"])
 
 
+def _fmt_mfu(mfu) -> str:
+    """Utilisation exists only where the device's peak is known."""
+    return "n/a" if mfu is None else f"{mfu:.4f}"
+
+
 def format_summary(report: dict, top: int = 5) -> str:
     """The human-facing ranked table `tune` prints (the full evidence lives
     in the JSON)."""
@@ -159,7 +164,7 @@ def format_summary(report: dict, top: int = 5) -> str:
         lines.append(
             f"  #{entry['rank']} {entry['key']}: "
             f"{entry['step_time_s'] * 1e3:.2f} ms/step "
-            f"(mfu~{entry['mfu_est']:.4f}, peak {entry['predicted_peak_bytes']} B)"
+            f"(mfu~{_fmt_mfu(entry['mfu_est'])}, peak {entry['predicted_peak_bytes']} B)"
             + attrib
         )
     for drop in report["dropped"]:

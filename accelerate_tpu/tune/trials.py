@@ -64,7 +64,7 @@ class TrialResult:
     step_time_s: float
     steps_per_sec: float
     tokens_per_sec: float
-    mfu_est: float
+    mfu_est: float | None  # None on a device with no known peak
     final_loss: float
     wall_s: float
     compile_s: float
@@ -88,7 +88,7 @@ class TrialResult:
             "step_time_s": round(self.step_time_s, 6),
             "steps_per_sec": round(self.steps_per_sec, 3),
             "tokens_per_sec": round(self.tokens_per_sec, 1),
-            "mfu_est": round(self.mfu_est, 4),
+            "mfu_est": None if self.mfu_est is None else round(self.mfu_est, 4),
             "final_loss": round(self.final_loss, 4),
             "wall_s": round(self.wall_s, 3),
             "compile_s": round(self.compile_s, 3),
@@ -363,9 +363,10 @@ class TrialRig:
             # Peak FLOPs and chip count come from the LIVE mesh the trial ran
             # on, not a raw device-list baseline (elastic reshards change it).
             mesh_devices = built.accelerator.mesh.devices
+            peak = device_peak_flops(mesh_devices.flat[0])
             mfu = (
-                tokens_per_sec * built.flops_per_token
-                / (device_peak_flops(mesh_devices.flat[0]) * mesh_devices.size)
+                None if peak is None
+                else tokens_per_sec * built.flops_per_token / (peak * mesh_devices.size)
             )
 
             fractions = overlap = trace_path = None
@@ -386,7 +387,7 @@ class TrialRig:
                 step_time_s=dt / steps_ran,
                 steps_per_sec=steps_per_sec,
                 tokens_per_sec=tokens_per_sec,
-                mfu_est=float(mfu),
+                mfu_est=mfu,
                 final_loss=final_loss,
                 wall_s=time.perf_counter() - t_start,
                 compile_s=compile_s,

@@ -1,10 +1,10 @@
-"""Version-compat shims over moving JAX APIs.
+"""The one home of ``shard_map`` and ``axis_size`` for this repo.
 
-``jax.shard_map`` graduated out of ``jax.experimental.shard_map`` (where it
-takes ``check_rep``/``auto``) into the top-level namespace (where it takes
-``check_vma``/``axis_names``). The repo targets the modern spelling; this
-shim translates it for the 0.4.x runtimes the CPU rigs carry, so call sites
-(parallel/pipeline.py's GPipe and 1F1B schedules) stay version-agnostic.
+Call sites (parallel/pipeline.py, parallel/ring.py, parallel/ulysses.py)
+import them from here, and the ``raw-shard-map`` lint rule keeps it so. The
+installed JAX carries ``jax.shard_map`` (``axis_names`` / ``check_vma``) and
+``jax.lax.axis_size``; these wrappers only pin the keyword surface the repo
+uses.
 """
 
 from __future__ import annotations
@@ -13,57 +13,24 @@ import jax
 
 
 def has_native_shard_map() -> bool:
-    """Whether this jax carries top-level ``jax.shard_map`` (partial-auto
-    manual mapping). When False, :func:`shard_map` falls back to FULL-MANUAL
-    ``jax.experimental.shard_map``: mesh axes the specs omit are treated as
-    replicated, so dp-replicated inputs are all-gathered at the region
-    boundary — the pp plan's zero-all-gather HLO property (and the program
-    auditor's dp-all-gather gate on shard_map programs) holds only on native
-    runtimes. tests/test_hlo_collectives.py keys its precise skip on this."""
-    import jax
-
-    return getattr(jax, "shard_map", None) is not None
+    """True: the installed JAX has top-level ``jax.shard_map`` with
+    partial-auto manual mapping (tests/test_hlo_collectives.py keys on it)."""
+    return True
 
 
 def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (absent on 0.4.x): the static size of a mapped
-    mesh axis. ``psum`` of the literal 1 constant-folds to the axis size on
-    every version, inside any mapped context."""
-    native = getattr(jax.lax, "axis_size", None)
-    if native is not None:
-        return native(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """The static size of a mapped mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """``jax.shard_map`` with the modern keyword surface on any jax version.
-
-    ``axis_names`` restricts MANUAL mapping to those mesh axes (the rest stay
-    automatic/GSPMD); on 0.4.x that is expressed inversely via ``auto`` =
-    every other axis. ``check_vma`` (varying-mesh-axes checking) maps onto the
-    old ``check_rep`` replication check — both default off here because the
-    pipeline schedules intentionally produce stage-varying values.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return native(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # 0.4.x partial-auto (auto = the non-manual axes) lowers through a
-    # PartitionId instruction XLA:CPU rejects, so the fallback goes fully
-    # manual instead. Specs mention only the manual axes, so the body traces
-    # at the same per-device shapes either way; axes the specs omit are
-    # treated as replicated — redundant compute rather than auto-partitioned
-    # compute on those axes, which the modern native path above avoids.
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=bool(check_vma),
-    )
+    """``jax.shard_map``. ``axis_names`` restricts MANUAL mapping to those
+    mesh axes (the rest stay automatic/GSPMD); ``check_vma`` is passed only
+    when given (the pipeline schedules turn it off: they produce
+    stage-varying values on purpose)."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, **kwargs)

@@ -123,12 +123,10 @@ def main():
     args = parser.parse_args()
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from bench import resolve_backend
-
-    backend = resolve_backend()
-    size = args.size or ("1b" if backend == "tpu" else "tiny")
-
     import jax
+
+    backend = jax.default_backend()
+    size = args.size or ("1b" if backend == "tpu" else "tiny")
 
     from accelerate_tpu.checkpointing import export_full_weights
 

@@ -304,7 +304,7 @@ def test_bench_audit_failure_line_is_schemad(capsys):
     bench._print_failure("tiny", exc)
     line = json.loads(capsys.readouterr().out.strip())
     assert line["schema_version"] == bench.BENCH_SCHEMA_VERSION
-    assert line["value"] == 0.0
+    assert line["value"] is None and line["failed"] is True
     assert line["detail"]["audit"]["dp_allgathers"] == 2
     assert "dp mesh axis" in line["detail"]["error"]
 
