@@ -1337,7 +1337,7 @@ class Accelerator:
         donate = safe_donate_argnums((0, 1, 2, 3))
 
         @partial(jax.jit, donate_argnums=donate)
-        def _step(params, opt_state, accum_grads, count, batch, rng, clip_norm):
+        def train_step(params, opt_state, accum_grads, count, batch, rng, clip_norm):
             return step_body(params, opt_state, accum_grads, count, batch, rng, clip_norm)
 
         from .telemetry import span
@@ -1363,11 +1363,11 @@ class Accelerator:
             telemetry = self.telemetry
             if not telemetry.enabled:
                 (handle.params, optimizer.opt_state, optimizer._accum_grads,
-                 count_box[0], loss) = _step(*_step_args(batch, rng, clip_norm))
+                 count_box[0], loss) = train_step(*_step_args(batch, rng, clip_norm))
                 return loss
             with span("train_step"):
                 (handle.params, optimizer.opt_state, optimizer._accum_grads,
-                 count_box[0], loss) = _step(*_step_args(batch, rng, clip_norm))
+                 count_box[0], loss) = train_step(*_step_args(batch, rng, clip_norm))
             # Per-step timeline sample: a clock read + deque append; the loss
             # scalar is retained (never fetched) so the dispatch stays async.
             telemetry.on_fused_step(tokens=batch_token_count(batch), loss=loss)
@@ -1377,7 +1377,7 @@ class Accelerator:
             """Lower (without running) the fused step for HLO inspection — used
             by the collective-count tests to pin each plan's communication
             pattern without multi-chip hardware."""
-            return _step.lower(*_step_args(batch, handle.rng, clip_norm))
+            return train_step.lower(*_step_args(batch, handle.rng, clip_norm))
 
         step.lower = lower
         step._audit_meta = self._builder_audit_meta(

@@ -503,12 +503,20 @@ class ContinuousBatcher:
         # adds zero device transfers (pinned by tests/test_fleet.py). A TTFT
         # breach books accelerate_slo_breaches_total + a flight event and can
         # arm a trace capture via the installed profile trigger.
+        # The same switch arms the loop's spans (telemetry/spans.py:
+        # serve.run / serve.iteration / serve.admit / serve.dispatch_* /
+        # serve.report_wait / serve.process_report — docs/observability.md);
+        # every attribute they carry is host bookkeeping too.
+        from .telemetry.spans import no_span, span
+
         if trace_requests:
             from .telemetry.requests import RequestTracer
 
             self.tracer: RequestTracer | None = RequestTracer(slo=self.slo)
+            self._span = span
         else:
             self.tracer = None
+            self._span = no_span
         # Token-streaming sink (serving_net/frontend.py installs one):
         # ``stream(rid, tokens, final)`` — per-window deltas from the report
         # the loop already reads, then ONE final call carrying the
@@ -714,6 +722,7 @@ class ContinuousBatcher:
                     "kv_mask": cache["kv_mask"].at[:, :P].set(1),
                 }
 
+            fill.__name__ = f"serve_prefix_fill_{P}"  # the program's name in a profile
             self._prefix_fns[P] = jax.jit(fill, donate_argnums=safe_donate_argnums((1,)))
         self._cache = self._prefix_fns[P](self.params, self._cache,
                                           jnp.asarray(prefix)[None])
@@ -853,7 +862,7 @@ class ContinuousBatcher:
         if self._host_pos == 0:
             return 0
         if self._compact_fn is None:
-            def run(cache, dead, pfx):
+            def serve_compact(cache, dead, pfx):
                 km = cache["kv_mask"]
                 # A retired request's columns stay valid until its slot is
                 # re-admitted (eviction is lazy); compaction is exactly when
@@ -871,7 +880,7 @@ class ContinuousBatcher:
                     "pos": jnp.max(jnp.sum(km, axis=1)).astype(cache["pos"].dtype),
                 }
 
-            self._compact_fn = jax.jit(run, donate_argnums=safe_donate_argnums((0,)))
+            self._compact_fn = jax.jit(serve_compact, donate_argnums=safe_donate_argnums((0,)))
         dead = jnp.asarray([r is None for r in self._slot_req])
         self._cache = self._compact_fn(self._cache, dead, jnp.int32(self._pfx))
         new_pos = int(host_fetch(self._cache["pos"]))  # the one readback compact pays
@@ -1075,6 +1084,7 @@ class ContinuousBatcher:
                      slot_max, slot_temp, slot_eos)
             return out["cache"], state, done0
 
+        run.__name__ = f"serve_admit_{P}"
         fn = jax.jit(run, donate_argnums=safe_donate_argnums((1, 2)))
         self._admit_fns[(P, pfx)] = fn
         return fn
@@ -1255,6 +1265,7 @@ class ContinuousBatcher:
                 jax.tree_util.tree_leaves(self._state_tuple())
             )
         effective_donate = safe_donate_argnums(donations)
+        run.__name__ = f"serve_prefill_chunk_{P}"
         fn = jax.jit(run, donate_argnums=effective_donate)
         param_leaves = jax.tree_util.tree_leaves(self.params)
         from .ops.registry import resolved_backends
@@ -1304,7 +1315,7 @@ class ContinuousBatcher:
         t = self.max_blocks_per_slot * bs
         w = self.sync_every
 
-        def run(params, pool, tables, lens, commit, force_stop, state):
+        def serve_decode_window(params, pool, tables, lens, commit, force_stop, state):
             (tok, pos, n_out, active, out_buf, keys,
              slot_max, slot_temp, slot_eos) = state
             B = tok.shape[0]
@@ -1366,7 +1377,7 @@ class ContinuousBatcher:
             return pool, state, report
 
         effective_donate = safe_donate_argnums((1, 6))
-        self._decode_fn = jax.jit(run, donate_argnums=effective_donate)
+        self._decode_fn = jax.jit(serve_decode_window, donate_argnums=effective_donate)
         donated_leaves = len(jax.tree_util.tree_leaves(self._pool)) + len(
             jax.tree_util.tree_leaves(self._state_tuple())
         )
@@ -1389,7 +1400,7 @@ class ContinuousBatcher:
             # pre-partitioning.
             "kernels": {"spec": self.kernels,
                         "backends": resolved_backends(self.kernels)},
-            "jaxpr_thunk": lambda *a, **k: jax.make_jaxpr(run)(*a, **k),
+            "jaxpr_thunk": lambda *a, **k: jax.make_jaxpr(serve_decode_window)(*a, **k),
             # The static-memory join for `accelerate-tpu memcheck --serving`:
             # the persistent pool is the class the per-device KV budget gate
             # prices (the gathered view + write window land in XLA's temp
@@ -1438,8 +1449,8 @@ class ContinuousBatcher:
         k = self.speculative_k
         S = k + 1
 
-        def run(params, d_params, pool, d_pool, tables, lens, commit,
-                force_stop, state):
+        def serve_spec_verify(params, d_params, pool, d_pool, tables, lens, commit,
+                              force_stop, state):
             (tok, pos, n_out, active, out_buf, keys,
              slot_max, slot_temp, slot_eos) = state
             B = tok.shape[0]
@@ -1538,7 +1549,7 @@ class ContinuousBatcher:
             return pool, d_pool, state, produced, report
 
         effective_donate = safe_donate_argnums((2, 3, 8))
-        self._verify_fn = jax.jit(run, donate_argnums=effective_donate)
+        self._verify_fn = jax.jit(serve_spec_verify, donate_argnums=effective_donate)
         donated_leaves = (
             len(jax.tree_util.tree_leaves(self._pool))
             + len(jax.tree_util.tree_leaves(self._draft_pool))
@@ -1557,7 +1568,7 @@ class ContinuousBatcher:
             "donation_dropped_by_policy": not effective_donate,
             "kernels": {"spec": self.kernels,
                         "backends": resolved_backends(self.kernels)},
-            "jaxpr_thunk": lambda *a, **kw: jax.make_jaxpr(run)(*a, **kw),
+            "jaxpr_thunk": lambda *a, **kw: jax.make_jaxpr(serve_spec_verify)(*a, **kw),
             "memory_classes": {
                 "kv_pool": (lambda: self._pool, lambda: None),
                 "draft_pool": (lambda: self._draft_pool, lambda: None),
@@ -1580,7 +1591,7 @@ class ContinuousBatcher:
         module = self.module
         pad = self.pad
 
-        def run(params, cache, state):
+        def serve_decode_contiguous(params, cache, state):
             def one_step(carry, _):
                 cache, state = carry
                 (tok, pos, n_out, active, out_buf, keys,
@@ -1619,7 +1630,7 @@ class ContinuousBatcher:
         # Donating cache+state halves the live KV footprint (the cache is the
         # engine's dominant allocation and is dead after each window).
         effective_donate = safe_donate_argnums((1, 2))
-        self._decode_fn = jax.jit(run, donate_argnums=effective_donate)
+        self._decode_fn = jax.jit(serve_decode_contiguous, donate_argnums=effective_donate)
         # Builder metadata for the auditor/fingerprint (the serving analog of
         # Accelerator._builder_audit_meta): the donation contract over
         # cache+state and the params' compute dtype. Leaf counts read the
@@ -2034,11 +2045,17 @@ class ContinuousBatcher:
                 return None
         return s
 
+    def _next_chunk(self, s: int):
+        """``(p, real tokens, final)`` of the chunk slot ``s`` dispatches
+        next: a final chunk pads to its bucket, the others are exact."""
+        chunks = self._slot_chunks[s]
+        size, final = int(chunks[0].size), len(chunks) == 1
+        return (self._bucket(size) if final else size), size, final
+
     def _dispatch_chunk(self, s: int, state):
+        p, _, final = self._next_chunk(s)
         chunk = self._slot_chunks[s].pop(0)
-        final = not self._slot_chunks[s]
         if final:
-            p = self._bucket(int(chunk.size))
             row = np.full((p,), self.pad, np.int32)
             mrow = np.zeros((p,), np.int32)
             row[: chunk.size] = chunk
@@ -2047,8 +2064,7 @@ class ContinuousBatcher:
             # p-1 (its logits row seeds the first sampled token)
             row_j, mrow_j = left_align(row[None], mrow[None])
             row_j, mrow_j = row_j[0], mrow_j[0]
-        else:
-            p = int(chunk.size)  # exact: hole-free, registrable
+        else:  # exact: hole-free, registrable
             row_j = jnp.asarray(chunk)
             mrow_j = jnp.ones((p,), jnp.int32)
         req = self._slot_req[s]
@@ -2151,11 +2167,25 @@ class ContinuousBatcher:
         (verdicts ride ``force_stop`` into the NEXT window), collect finished
         requests, and free their chains. The report was optimization-
         barrier'd out of the donated state, so reading it here — after the
-        next window was already dispatched — is the non-blocking sync."""
+        next window was already dispatched — is the non-blocking sync.
+        ``serve.report_wait`` is that first read alone: how long the host
+        was blocked on the device (its end is the moment the window's report
+        became ready, where the host waited at all)."""
         report, req_map = report
-        active_np = host_fetch(report[0]).copy()
+        with self._span("serve.report_wait"):
+            active_np = host_fetch(report[0]).copy()
+        with self._span("serve.process_report") as rec:
+            rec.attrs["tokens"], rec.attrs["finished"] = self._consume_report(
+                report, req_map, active_np, force_stop)
+
+    def _consume_report(self, report, req_map, active_np, force_stop: np.ndarray):
+        """``_process_report`` past its first read. Returns the tokens this
+        report handed out (stream deltas, and at a finish the rest of the
+        truncated output, so over a request they sum to what it returned)
+        and the requests it finished."""
         n_np = host_fetch(report[1])
         out_np = None
+        emitted = finished = 0
         now = time.monotonic()
         for s in range(self.B):
             req = self._slot_req[s]
@@ -2185,6 +2215,7 @@ class ContinuousBatcher:
                 if n > done:
                     self._emit_stream(req.rid, out_np[s][done:n].copy(), False)
                     self._streamed[req.rid] = n
+                    emitted += n - done
             if active_np[s] and req.stop:
                 if out_np is None:
                     out_np = host_fetch(report[2])
@@ -2193,9 +2224,13 @@ class ContinuousBatcher:
             if not active_np[s]:
                 if out_np is None:
                     out_np = host_fetch(report[2])
+                streamed = self._streamed.get(req.rid, 0)
                 self._finish(req, out_np[s][: int(n_np[s])])
                 self._free_chain(s)
+                emitted += int(self._results[req.rid].size) - streamed
+                finished += 1
         self._publish_pool_gauges()
+        return emitted, finished
 
     def _run_paged(self) -> dict[int, np.ndarray]:
         """The paged engine loop: per iteration, admit; dispatch at most ONE
@@ -2204,43 +2239,59 @@ class ContinuousBatcher:
         just dispatched overlaps all host work including the report fetch
         (zero blocking transfers in steady state, pinned by tests). Decode
         stall per iteration is bounded by one chunk's compute instead of one
-        prompt's — the chunked-prefill contract."""
+        prompt's — the chunked-prefill contract. Each turn is one
+        ``serve.iteration`` span whose children are the steps above."""
         state = self._state_tuple()
         pending = None
         force_stop = np.zeros((self.B,), bool)
         last_dispatch_t = None
         window_pace = None
+        window = (self.speculative_k + 1) if self.speculative_k else self.sync_every
         while True:
-            now = time.monotonic()
-            self._admit_paged(now)
-            chunk_slot = self._pick_chunk_slot(now, window_pace)
-            if chunk_slot is not None:
-                state = self._dispatch_chunk(chunk_slot, state)
-            decoding = any(m == "decode" for m in self._slot_mode)
-            new_pending = None
-            if decoding:
-                state, new_pending = self._dispatch_decode(state, force_stop)
-                force_stop[:] = False
-                t = time.monotonic()
-                if last_dispatch_t is not None:
-                    dt = t - last_dispatch_t
-                    window_pace = dt if window_pace is None else 0.5 * window_pace + 0.5 * dt
-                last_dispatch_t = t
-            if pending is not None:
-                self._process_report(pending, force_stop)
-            pending = new_pending
-            if pending is None and chunk_slot is None and not decoding:
-                if self._queue:
-                    if any(m != "free" for m in self._slot_mode):
-                        continue
-                    raise RuntimeError(
-                        f"KV pool capacity exhausted ({len(self._free_blocks)} of "
-                        f"{self.num_blocks} blocks free; the next request needs "
-                        "more); raise max_cache_len/num_blocks, or catch this, "
-                        "reset(), and run() again."
-                    )
-                if all(m == "free" for m in self._slot_mode):
-                    break
+            with self._span("serve.iteration") as turn:
+                now = time.monotonic()
+                with self._span("serve.admit") as rec:
+                    admitted = self._slo_decisions["admitted"]
+                    self._admit_paged(now)
+                    rec.attrs["admitted"] = self._slo_decisions["admitted"] - admitted
+                chunk_slot = self._pick_chunk_slot(now, window_pace)
+                chunk_p = 0
+                if chunk_slot is not None:
+                    chunk_p, tokens, final = self._next_chunk(chunk_slot)
+                    with self._span("serve.dispatch_chunk", rid=self._slot_req[chunk_slot].rid,
+                                    p=chunk_p, tokens=tokens, final=final):
+                        state = self._dispatch_chunk(chunk_slot, state)
+                decoding = sum(m == "decode" for m in self._slot_mode)
+                turn.attrs.update(
+                    chunk=chunk_p, decoding=decoding,
+                    prefilling=sum(m == "prefill" for m in self._slot_mode),
+                    queued=len(self._queue), free_blocks=len(self._free_blocks))
+                new_pending = None
+                if decoding:
+                    with self._span("serve.dispatch_decode", decoding=decoding,
+                                    slots=self.B, window=window):
+                        state, new_pending = self._dispatch_decode(state, force_stop)
+                    force_stop[:] = False
+                    t = time.monotonic()
+                    if last_dispatch_t is not None:
+                        dt = t - last_dispatch_t
+                        window_pace = dt if window_pace is None else 0.5 * window_pace + 0.5 * dt
+                    last_dispatch_t = t
+                if pending is not None:
+                    self._process_report(pending, force_stop)
+                pending = new_pending
+                if pending is None and chunk_slot is None and not decoding:
+                    if self._queue:
+                        if any(m != "free" for m in self._slot_mode):
+                            continue
+                        raise RuntimeError(
+                            f"KV pool capacity exhausted ({len(self._free_blocks)} of "
+                            f"{self.num_blocks} blocks free; the next request needs "
+                            "more); raise max_cache_len/num_blocks, or catch this, "
+                            "reset(), and run() again."
+                        )
+                    if all(m == "free" for m in self._slot_mode):
+                        break
         self._sync(state)
         self._publish_pool_gauges()
         wave, self._results = self._results, {}
@@ -2250,8 +2301,12 @@ class ContinuousBatcher:
         """Drive admits + decode until the queue drains and all slots finish.
         Returns THIS wave's results only: {request_id: generated token ids
         (eos included, no pads)} for every request finished during the call."""
-        if self.paged:
-            return self._run_paged()
+        with self._span("serve.run") as rec:
+            wave = self._run_paged() if self.paged else self._run_contiguous()
+            rec.attrs["finished"] = len(wave)
+        return wave
+
+    def _run_contiguous(self) -> dict[int, np.ndarray]:
         state = (self._tok, self._pos, self._n_out, self._active, self._out_buf,
                  self._keys, self._slot_max, self._slot_temp, self._slot_eos)
         while True:
@@ -2334,10 +2389,7 @@ class ContinuousBatcher:
                     jnp.int32(req.eos),
                 )
                 self._host_pos += P
-                # Host-side wall clock in the HOST engine loop (the linter's
-                # traced_names heuristic collides on the jitted bodies all
-                # being named `run` too).
-                admit_t = time.monotonic()  # accelerate-lint: disable=traced-host-impurity
+                admit_t = time.monotonic()
                 self._req_times.setdefault(req.rid, {"submit": req.submit_t})[
                     "first_token"
                 ] = admit_t

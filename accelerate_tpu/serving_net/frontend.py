@@ -63,6 +63,7 @@ import urllib.request
 import numpy as np
 
 from ..logging import get_logger
+from ..telemetry.spans import no_span, record_span, span
 from ..utils.transfer import host_view
 from .handoff import export_chain, import_chain, release_chain, run_prefill_only
 from .lease import LeaseHeartbeat, drain_grace_from_env
@@ -189,6 +190,13 @@ class ServingFrontend:
         self._kill_rids: set[int] = set()
         self._slow: dict[int, float] = {}  # rid -> injected per-event delay
         self._killed = False
+        # The engine's one tracing switch (``trace_requests``) arms the front
+        # end's spans too: ``frontend.submit`` on the handler's thread, and
+        # ``frontend.relay`` for each request's FIRST stream event, from the
+        # loop thread's push to the handler thread's yield of that frame
+        # (rid -> time of the push; None until the push happens).
+        self._span = span if engine.tracer is not None else no_span
+        self._relay_t0: dict[int, float | None] = {}
         engine.stream = self._on_stream
 
     # ------------------------------------------------------------ lifecycle
@@ -412,6 +420,8 @@ class ServingFrontend:
         """The engine's streaming sink (runs on the loop thread, fed from
         the report the loop already fetches)."""
         kind = "final" if final else "tokens"
+        if self._relay_t0.get(rid, 0.0) is None:
+            self._relay_t0[rid] = time.perf_counter()
         self._push(rid, (kind, [int(t) for t in host_view(tokens).reshape(-1)]))
 
     def _push(self, rid: int, item):
@@ -441,6 +451,15 @@ class ServingFrontend:
         subscriber = self._streams[rid]
         slow_s = self._slow.get(rid)
         streamed_any = False
+
+        def relayed(frame: str) -> str:
+            """Close ``frontend.relay`` at the first frame this stream hands
+            to the handler; later frames pass through."""
+            pushed_at = self._relay_t0.pop(rid, None)
+            if pushed_at is not None:
+                record_span("frontend.relay", pushed_at, time.perf_counter(), rid=rid)
+            return frame
+
         try:
             while True:
                 deadline_wall = self._deadlines.get(rid)
@@ -470,15 +489,15 @@ class ServingFrontend:
                     return
                 if kind == "final":
                     record = self._trace_record(rid)
-                    yield sse_event("done", {
+                    yield relayed(sse_event("done", {
                         "rid": rid,
                         "tokens": payload,
                         "ttft_s": (record or {}).get("ttft_s"),
                         "tpot_s": (record or {}).get("tpot_s"),
                         "trace": [record] if record else [],
-                    })
+                    }))
                     return
-                yield sse_event("tokens", {"rid": rid, "tokens": payload})
+                yield relayed(sse_event("tokens", {"rid": rid, "tokens": payload}))
                 streamed_any = True
                 if rid in self._kill_rids and streamed_any:
                     # worker_kill: die AFTER the client saw a delta, so the
@@ -496,6 +515,7 @@ class ServingFrontend:
             self._streams.pop(rid, None)
             self._deadlines.pop(rid, None)
             self._slow.pop(rid, None)
+            self._relay_t0.pop(rid, None)
 
     # ------------------------------------------------------------- handlers
     def handle_get(self, path: str, query: dict):
@@ -567,7 +587,17 @@ class ServingFrontend:
         }
 
     def _handle_generate(self, request: dict):
+        with self._span("frontend.submit") as rec:
+            reply = self._submit_generate(request, rec)
+        if reply[0] == "sse":
+            self._notify()
+        return reply
+
+    def _submit_generate(self, request: dict, rec):
+        """``_handle_generate`` from the parsed body to the return of
+        ``engine.submit``: the ``frontend.submit`` span (``rec``)."""
         prompt = np.asarray(request.get("prompt", []), np.int32).reshape(-1)
+        rec.attrs["prompt_tokens"] = int(prompt.size)
         if prompt.size == 0:
             return ("json", 400, {"error": "empty or missing 'prompt'",
                                   "retryable": False})
@@ -596,6 +626,7 @@ class ServingFrontend:
             rid = (int(request["request_id"])
                    if request.get("request_id") is not None
                    else self.engine._next_rid)
+            rec.rid = rid
             self._arm_request_fault(fault, rid)
             if self.role.name == "prefill":
                 decode_endpoint = request.get("decode_endpoint")
@@ -615,9 +646,10 @@ class ServingFrontend:
             self._streams[rid] = queue.Queue()
             if deadline_wall is not None:
                 self._deadlines[rid] = float(deadline_wall)
+            if self.engine.tracer is not None:
+                self._relay_t0[rid] = None
             self.engine.submit(prompt, request_id=rid, tier=self.role.name,
                                **kwargs)
-        self._notify()
         return ("sse", self._stream_response(rid))
 
     # ---------------------------------------------------------------- relay

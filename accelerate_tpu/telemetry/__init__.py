@@ -89,7 +89,7 @@ from .profiler import (
     reset_profile_manager,
     set_profile_manager,
 )
-from .spans import SpanRecord, SpanRing, get_span_ring, reset_spans, span
+from .spans import SpanRecord, SpanRing, get_span_ring, record_span, reset_spans, span
 from .straggler import SkewReport, StragglerMonitor
 from .timeline import StepTimeline, device_memory_stats, device_peak_flops
 
@@ -133,6 +133,7 @@ __all__ = [
     "publish_metrics_endpoint",
     "record_breach",
     "record_event",
+    "record_span",
     "reset_fleet",
     "reset_flight_recorder",
     "reset_journal",

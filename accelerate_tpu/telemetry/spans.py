@@ -5,7 +5,10 @@ AND enters a ``jax.profiler.TraceAnnotation`` of the same name, so the label a
 user (or the framework — prepare/train_step/checkpoint/gather are
 pre-instrumented) sees in the step timeline is the label they find in a
 captured XLA/perfetto trace. Spans nest; each record carries its depth and its
-``outer/inner`` path.
+``outer/inner`` path (the parent), an optional ``rid`` shared by the spans of
+one request, and ``attrs``: small counts taken where the work happens.
+:func:`record_span` pushes an interval whose two ends were read on different
+threads (``time.perf_counter()`` both); it enters no annotation.
 
 The ring is a fixed-size slot array indexed by an ``itertools.count`` — the
 one CPython-atomic primitive that makes concurrent pushes (orbax background
@@ -26,6 +29,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass
 
+from .journal import journal_event
+
 try:  # host-side runtime trace annotation; absent on exotic builds
     from jax.profiler import TraceAnnotation as _TraceAnnotation
 except Exception:  # pragma: no cover
@@ -39,6 +44,8 @@ class SpanRecord:
     duration_s: float
     depth: int  # 0 = top-level
     path: str  # "outer/inner"
+    rid: int | None = None  # request id: spans of one request share it
+    attrs: dict | None = None  # small host-side counts, taken where the work happens
 
 
 class SpanRing:
@@ -102,40 +109,72 @@ def _span_hist():
     return _SPAN_HIST()
 
 
+def _publish(record: SpanRecord):
+    """Histogram + durable journal tee of one finished span; never raises."""
+    try:
+        _span_hist().observe(record.duration_s, name=record.name)
+    except Exception:  # pragma: no cover - instrumentation never raises
+        pass
+    # Durable tee (telemetry/journal.py): no-op when journaling is off; pure
+    # host bookkeeping (the record itself) when on.
+    try:
+        journal_event("span", name=record.name, path=record.path, depth=record.depth,
+                      duration_s=round(record.duration_s, 6))
+    except Exception:  # pragma: no cover - instrumentation never raises
+        pass
+
+
 @contextmanager
-def span(name: str, ring: SpanRing | None = None, record_metric: bool = True):
+def span(name: str, ring: SpanRing | None = None, record_metric: bool = True,
+         rid: int | None = None, **attrs):
     """Time a block into the span ring (and the XLA trace). Nestable; safe on
-    any thread; never raises from instrumentation."""
+    any thread; never raises from instrumentation.
+
+    ``attrs`` known at entry also ride the ``TraceAnnotation``, so the event
+    in a captured profile carries them. The block receives the record
+    (``with span(...) as rec``) and may set ``rec.rid`` or add to
+    ``rec.attrs`` what it only learns inside; those reach the ring alone."""
     ring = _RING if ring is None else ring
     stack = getattr(_tls, "stack", None)
     if stack is None:
         stack = _tls.stack = []
-    path = "/".join(stack) + "/" + name if stack else name
+    record = SpanRecord(name=name, start_s=0.0, duration_s=0.0, depth=len(stack),
+                        path="/".join(stack) + "/" + name if stack else name,
+                        rid=rid, attrs=attrs)
     stack.append(name)
-    ann = _TraceAnnotation(name) if _TraceAnnotation is not None else None
+    ann = _TraceAnnotation(name, **attrs) if _TraceAnnotation is not None else None
     if ann is not None:
         ann.__enter__()
-    t0 = time.perf_counter()
+    record.start_s = time.perf_counter()
     try:
-        yield
+        yield record
     finally:
-        duration = time.perf_counter() - t0
+        record.duration_s = time.perf_counter() - record.start_s
         if ann is not None:
             ann.__exit__(None, None, None)
         stack.pop()
-        ring.push(SpanRecord(name=name, start_s=t0, duration_s=duration,
-                             depth=len(stack), path=path))
+        if not record.attrs:
+            record.attrs = None
+        ring.push(record)
         if record_metric:
-            try:
-                _span_hist().observe(duration, name=name)
-            except Exception:  # pragma: no cover - instrumentation never raises
-                pass
-            # Durable tee (telemetry/journal.py): no-op when journaling is
-            # off; pure host bookkeeping (the record above) when on.
-            try:
-                from .journal import journal_event
+            _publish(record)
 
-                journal_event("span", name=name, path=path,
-                              depth=len(stack), duration_s=round(duration, 6))
-            except Exception:  # pragma: no cover - instrumentation never raises
-                pass
+
+def record_span(name: str, start_s: float, end_s: float, rid: int | None = None,
+                ring: SpanRing | None = None, **attrs):
+    """Push a span whose two ends were read with ``time.perf_counter()`` by
+    the caller: an interval that begins on one thread and ends on another
+    (the front end's relay). Top-level, and no ``TraceAnnotation``: an
+    annotation must be entered and left on one thread."""
+    record = SpanRecord(name=name, start_s=start_s, duration_s=end_s - start_s,
+                        depth=0, path=name, rid=rid, attrs=attrs or None)
+    (_RING if ring is None else ring).push(record)
+    _publish(record)
+
+
+@contextmanager
+def no_span(name: str, rid: int | None = None, **attrs):
+    """``span``'s shape with nothing recorded: what a caller whose
+    instrumentation is switched off binds in its place."""
+    yield SpanRecord(name=name, start_s=0.0, duration_s=0.0, depth=0, path=name,
+                     rid=rid, attrs=attrs)

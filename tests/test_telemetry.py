@@ -110,6 +110,14 @@ def test_framework_spans_cover_prepare_and_train_step():
     assert {"prepare", "train_step"} <= names
 
 
+def test_fused_step_program_is_named_train_step():
+    """The fused step's name in a profile (``jit_train_step`` on the device
+    plane's module line) is the name of the host span around its dispatch."""
+    accelerator, pmodel, popt = _build()
+    step = accelerator.build_train_step(pmodel, popt)
+    assert "module @jit_train_step " in step.lower(_batch(1)).as_text().split("\n", 1)[0]
+
+
 # ---------------------------------------------------------------- timeline
 def test_fused_loop_timeline_zero_blocking_transfers():
     """Acceptance: the always-on timeline never stalls the dispatch thread —
