@@ -2,8 +2,9 @@
 
 A mix is data: the arrival law (``poisson`` at a fixed ``rate_per_s``,
 ``gamma`` with a coefficient of variation for bursts, or ``backlog``: every
-request due at 0), and the laws of prompt and output length (lognormal or
-fixed, clipped). A new mix is a new file, never new code.
+request due at once, ``lead_in_s`` before the window opens), and the laws of
+prompt and output length (lognormal or fixed, clipped). A new mix is a new
+file, never new code.
 
 **Every seed gets the same work.** For ``n`` requests the lengths are the
 law's ``n`` evenly spaced quantiles (so the clips, the mean and the tail are
@@ -23,6 +24,17 @@ loopback and stamps every SSE event as it arrives. Latency is counted from the
 time a request was **due**; how late it was really sent is recorded beside it.
 The HTTP client and the SSE reader are copies of what ``chip_smoke.py`` and
 ``serving_net/frontend.py`` use, kept here as part of the yardstick.
+
+The closed loop is the same machinery under a ``backlog``: every request is
+due at once, so the pool's ``client_threads`` bound how many are with the
+server, and each answer's end sends the next of the schedule. One parameter,
+``stop_sending_at``, closes the loop, and makes it one sample path in two
+ways. From that time on nothing more is sent (a request never sent has no
+``sent`` time and is no one's failure), so the backlog can be sized never to
+run out. And a request is sent only once the one before it in the schedule has
+its response's headers, which the server writes after the engine's ``submit``
+returned; otherwise two answers that end in one report race their successors
+into the engine's queue, and the order of that queue is the work.
 """
 
 from __future__ import annotations
@@ -83,7 +95,7 @@ class Request:
     due: float            # seconds from the start of the window (< 0: lead-in)
     prompt_len: int
     max_new: int
-    counted: bool         # due inside the window
+    counted: bool         # due inside the window (a backlog's: all; the runner attempts those sent)
     body: bytes = b""     # the encoded POST body, made during set-up
     prompt: np.ndarray | None = None
     sent: float | None = None
@@ -117,17 +129,19 @@ def _phase(traffic: dict, order, rng, n: int, start: float, duration: float, cou
 
 
 def build_schedule(traffic: dict, seed: int, seconds: float, vocab: int) -> list:
-    """The lead-in (uncounted, due before 0) and the window's requests.
+    """The lead-in (uncounted, due before 0) and the window's requests; under
+    a ``backlog`` one phase, all counted and all due ``lead_in_s`` before 0.
 
     The order of lengths and gaps comes from the mix's ``schedule_seed``, the
     prompts' tokens from the run's ``seed``."""
     order = np.random.default_rng(traffic["schedule_seed"])
     rng = np.random.default_rng(seed)
     arrivals = traffic["arrivals"]
+    lead = traffic.get("lead_in_s", 0.0)
     if arrivals["law"] == "backlog":
         n = max(1, math.ceil(arrivals["requests_per_s_of_window"] * seconds))
-        return _phase(traffic, order, rng, n, 0.0, 0.0, True, vocab, 0)
-    rate, lead = arrivals["rate_per_s"], traffic.get("lead_in_s", 0.0)
+        return _phase(traffic, order, rng, n, -lead, 0.0, True, vocab, 0)
+    rate = arrivals["rate_per_s"]
     n_lead = round(rate * lead)
     lead_in = (_phase(traffic, order, rng, n_lead, -lead, lead, False, vocab, 0)
                if n_lead else [])
@@ -156,8 +170,11 @@ def iter_sse(fp):
         yield (kind or "message", "\n".join(data))
 
 
-def generate(endpoint: str, request: Request, clock, timeout_s: float) -> None:
+def generate(endpoint: str, request: Request, clock, timeout_s: float,
+             accepted=lambda: None) -> None:
     """One request over HTTP; every event stamped on ``clock`` as it arrives.
+    ``accepted`` is called once the first attempt has its response's headers
+    (or has failed before them).
 
     The wire contract marks an ``error`` frame ``retryable`` where sending the
     request again may succeed. As a client of that contract would, a request
@@ -173,6 +190,7 @@ def generate(endpoint: str, request: Request, clock, timeout_s: float) -> None:
             conn.request("POST", "/v1/generate", body=request.body,
                          headers={"Content-Type": "application/json"})
             response = conn.getresponse()
+            accepted()
             if response.status != 200:
                 request.error = f"HTTP {response.status}: {response.read()[:200]!r}"
                 return
@@ -198,6 +216,7 @@ def generate(endpoint: str, request: Request, clock, timeout_s: float) -> None:
             request.error = repr(exc)
         finally:
             conn.close()
+            accepted()
         if not retry:
             return
         request.retries += 1
@@ -205,27 +224,51 @@ def generate(endpoint: str, request: Request, clock, timeout_s: float) -> None:
 
 class OpenLoop:
     """Sends a schedule against ``endpoint``; ``t0`` is the window's start on
-    ``time.perf_counter``. ``start`` returns at once; ``wait`` joins."""
+    ``time.perf_counter``. ``start`` returns at once; ``wait`` joins.
+
+    ``stop_sending_at`` (on the window's clock) closes the loop: sends go out
+    one at a time in the schedule's order, and none from then on (the module's
+    docstring)."""
 
     def __init__(self, endpoint: str, requests: list, t0: float, client_threads: int,
-                 timeout_s: float = 300.0):
+                 timeout_s: float = 300.0, stop_sending_at: float | None = None):
         self.endpoint, self.requests, self.t0 = endpoint, requests, t0
-        self.timeout_s = timeout_s
+        self.timeout_s, self.stop_sending_at = timeout_s, stop_sending_at
         self.pool = concurrent.futures.ThreadPoolExecutor(client_threads,
                                                           thread_name_prefix="bench-client")
         self.futures: list = []
         self.thread = threading.Thread(target=self._schedule, name="bench-loadgen", daemon=True)
+        self._turn, self._next = threading.Condition(), 0
 
     def clock(self) -> float:
         return time.perf_counter() - self.t0
 
+    def _send(self, position: int, request: Request):
+        """One client's work. The pool hands requests out in the schedule's
+        order, so the holder of the lowest unsent position is always running:
+        waiting for one's turn cannot deadlock."""
+        if self.stop_sending_at is None:
+            return generate(self.endpoint, request, self.clock, self.timeout_s)
+
+        def pass_turn():
+            with self._turn:
+                self._next = max(self._next, position + 1)
+                self._turn.notify_all()
+
+        with self._turn:
+            self._turn.wait_for(lambda: self._next == position)
+        try:
+            if self.clock() < self.stop_sending_at:
+                generate(self.endpoint, request, self.clock, self.timeout_s, pass_turn)
+        finally:
+            pass_turn()
+
     def _schedule(self):
-        for request in sorted(self.requests, key=lambda r: r.due):
+        for position, request in enumerate(sorted(self.requests, key=lambda r: r.due)):
             delay = request.due - self.clock()
             if delay > 0:
                 time.sleep(delay)
-            self.futures.append(self.pool.submit(generate, self.endpoint, request,
-                                                 self.clock, self.timeout_s))
+            self.futures.append(self.pool.submit(self._send, position, request))
 
     def start(self):
         self.thread.start()

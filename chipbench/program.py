@@ -69,6 +69,12 @@ def compiled_peak_bytes(compiled) -> int:
         + memory.output_size_in_bytes - memory.alias_size_in_bytes))
 
 
+def peak_bytes_in_use(devices) -> int:
+    """The largest ``peak_bytes_in_use`` the devices report (a process's peak
+    never falls again, so a runner reads it before its reference check)."""
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
+
+
 class SetupMarks(dict):
     """Seconds since the process started at which each stage of set-up ended."""
 

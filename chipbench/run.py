@@ -16,7 +16,9 @@ Everything about a cell is data that this file finds by name:
 A later PR adds a cell by adding files and entries, and edits nothing here.
 
 The last line of standard output is the one JSON object the contract asks
-for; everything else goes on earlier lines. Off the TPU, or with fewer chips
+for (its last key, ``checks``, holds each number that ``correct`` compared
+beside its limit; the same goes, one check a line, last on standard error);
+everything else goes on earlier lines. Off the TPU, or with fewer chips
 than the cell asks for, the command exits non-zero and prints no result.
 ``--rehearse`` takes its cells from ``chipbench/rehearse/`` instead, runs on
 whatever backend is there, and prints every metric as ``null``: a rehearsal of
@@ -137,6 +139,9 @@ def main(argv=None) -> int:
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
     parser.add_argument("--keep-trace", default=None, metavar="DIR",
                         help="with --trace 1: also copy the profiler's .xplane.pb into DIR")
+    parser.add_argument("--control", default=None, metavar="NAME",
+                        help="lay chipbench/controls/NAME.json over the cell's configuration: a run "
+                             "that has to come out not correct, never one of the benchmark's own")
     parser.add_argument("--rehearse", action="store_true",
                         help="cells of chipbench/rehearse/ on any backend; metrics print as null")
     args = parser.parse_args(argv)
@@ -145,6 +150,9 @@ def main(argv=None) -> int:
         sys.path.insert(0, ROOT)
     loaded = load_cell(args.workload, args.rehearse)
     cell, traffic = loaded["cell"], loaded["traffic"]
+    if args.control:
+        for key, over in load_json(HERE, "controls", args.control + ".json")["config"].items():
+            loaded["config"][key] = {**loaded["config"].get(key, {}), **over}
 
     import jax
 
@@ -169,7 +177,7 @@ def main(argv=None) -> int:
     cache_dir = enable_compile_cache()
     cache_before = len(os.listdir(cache_dir))
     say(phase="start", workload=args.workload, seed=args.seed, seconds=args.seconds,
-        trace=args.trace, rehearsal=args.rehearse, device=device, jax=jax.__version__,
+        trace=args.trace, rehearsal=args.rehearse, control=args.control, device=device, jax=jax.__version__,
         compile_cache_dir=cache_dir, compile_cache_entries=cache_before)
 
     trace_dir = tempfile.mkdtemp(prefix="chipbench_trace_") if args.trace else None
@@ -196,9 +204,11 @@ def main(argv=None) -> int:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
-    stats = [d.memory_stats() or {} for d in devices]
-    device["memory_peak_bytes"] = int(max(
-        [result.get("compiled_peak_bytes", 0)] + [s.get("peak_bytes_in_use", 0) for s in stats]))
+    # A runner reads the devices' peak itself where its reference check runs after the window.
+    from chipbench import program
+
+    in_use = result.get("peak_bytes_in_use") or program.peak_bytes_in_use(devices)
+    device["memory_peak_bytes"] = int(max(result.get("compiled_peak_bytes", 0), in_use))
     units = {m["name"]: m["unit"] for m in loaded["end_to_end"] + loaded["per_layer"]}
     if args.trace:
         values = {m["name"]: read_layer_metric(m["name"], record, args.rehearse)
@@ -221,7 +231,13 @@ def main(argv=None) -> int:
     if args.trace:
         line["breakdown"] = {"device_ops": record["trace"]["device_ops"],
                              "idle_gaps": record["trace"]["idle_gaps"]}
-    print(json.dumps(line), flush=True)
+    # What ``correct`` compared, each number beside its limit: last on the line
+    # and, one check a line, last on standard error.
+    line["checks"] = result["checks"]
+    for name, check in result["checks"].items():
+        print(f"check {name}: {json.dumps(check, default=str)}", file=sys.stderr)
+    sys.stderr.flush()
+    print(json.dumps(line, default=str), flush=True)
     if result.get("hard_exit"):
         sys.stderr.flush()
         os._exit(0)

@@ -65,10 +65,11 @@ def test_lead_in_is_uncounted_and_due_before_zero():
     assert all(-traffic["lead_in_s"] <= r.due < 0 for r in lead)
 
 
-def test_backlog_is_all_due_at_zero():
+def test_backlog_is_all_due_at_once_before_the_window():
     traffic = load("traffic", "backlog.json")
     requests = loadgen.build_schedule(traffic, 7, 10.0, 1000)
-    assert len(requests) == 20 and all(r.due == 0.0 and r.counted for r in requests)
+    assert len(requests) == 120 and all(r.counted for r in requests)
+    assert {r.due for r in requests} == {-traffic["lead_in_s"]}
 
 
 def test_lognormal_quantiles_have_the_law_s_median_and_spread():
