@@ -267,21 +267,9 @@ class GPTX(Module):
         v = v.reshape(B, S, nh, hd)
         new_cache = None
         if cache_layer is not None:
-            from ..ops.attention import cached_attention
+            from ..ops.attention import write_and_attend
 
-            pos = ctx["cache_pos"]
-            k_cache = jax.lax.dynamic_update_slice(
-                cache_layer["k"], k.astype(cache_layer["k"].dtype), (0, pos, 0, 0)
-            )
-            v_cache = jax.lax.dynamic_update_slice(
-                cache_layer["v"], v.astype(cache_layer["v"].dtype), (0, pos, 0, 0)
-            )
-            attn = cached_attention(
-                q, k_cache, v_cache,
-                q_positions=ctx["positions"],
-                kv_mask=ctx.get("kv_mask"),
-            )
-            new_cache = {"k": k_cache, "v": v_cache}
+            attn, new_cache = write_and_attend(q, k, v, cache_layer, ctx)
         else:
             attn = _attention(
                 q, k, v, causal=True, mask=ctx["attention_mask"], impl=cfg.attention_impl
@@ -349,6 +337,9 @@ class GPTX(Module):
             pos + jnp.arange(S, dtype=jnp.int32)[None], (B, S)
         )
         token_positions = slot_positions if positions is None else positions
+        view = cache.get("view")  # paged serving's read-only chains (Llama._apply_cached)
+        if view is not None and positions is None:
+            token_positions = slot_positions + view["k"].shape[2]
         chunk_mask = (
             attention_mask.astype(jnp.int32)
             if attention_mask is not None
@@ -359,13 +350,19 @@ class GPTX(Module):
         ctx["positions"] = slot_positions
         ctx["kv_mask"] = kv_mask
         ctx["cache_pos"] = pos
+        if view is not None:
+            ctx["prefix_mask"] = view["kv_mask"]
 
         def scan_step(x, inp):
-            layer, ck, cv = inp
-            x, new = self.block(layer, x, ctx, cache_layer={"k": ck, "v": cv})
+            layer, ck, cv, pre = inp
+            x, new = self.block(layer, x, ctx, cache_layer={"k": ck, "v": cv, "prefix": pre})
             return x, (new["k"], new["v"])
 
-        x, (nk, nv) = jax.lax.scan(scan_step, x, (params["layers"], cache["k"], cache["v"]))
+        x, (nk, nv) = jax.lax.scan(
+            scan_step, x,
+            (params["layers"], cache["k"], cache["v"],
+             None if view is None else (view["k"], view["v"])),
+        )
         out = self.head(params, x, labels=labels, attention_mask=attention_mask)
         out["cache"] = {"k": nk, "v": nv, "pos": pos + S, "kv_mask": kv_mask}
         return out

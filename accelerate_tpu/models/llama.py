@@ -508,24 +508,12 @@ class Llama(Module):
             k = apply_rope(k, cos, sin)
             new_cache = None
             if cache_layer is not None:
-                from ..ops.attention import cached_attention
+                from ..ops.attention import write_and_attend
 
-                pos = ctx["cache_pos"]
-                k_cache = jax.lax.dynamic_update_slice(
-                    cache_layer["k"], k.astype(cache_layer["k"].dtype), (0, pos, 0, 0)
+                attn_out, new_cache = write_and_attend(
+                    q, k, v, cache_layer, ctx, window=window,
+                    softcap=cfg.attn_logit_softcap, scale=scale,
                 )
-                v_cache = jax.lax.dynamic_update_slice(
-                    cache_layer["v"], v.astype(cache_layer["v"].dtype), (0, pos, 0, 0)
-                )
-                attn_out = cached_attention(
-                    q, k_cache, v_cache,
-                    q_positions=ctx["positions"],
-                    kv_mask=ctx.get("kv_mask"),
-                    window=window,
-                    softcap=cfg.attn_logit_softcap,
-                    scale=scale,
-                )
-                new_cache = {"k": k_cache, "v": v_cache}
             else:
                 if nkv != nh:
                     rep = nh // nkv
@@ -752,12 +740,25 @@ class Llama(Module):
         RoPE; causal masking always uses the cache *slot* indices. For RoPE a
         per-row constant offset between the two cancels, but ragged batches
         give absolute-position models (GPT-2 wpe) mask-derived token positions
-        through this split (VERDICT r2 #6)."""
+        through this split (VERDICT r2 #6).
+
+        A cache with a ``"view"`` (``{"k", "v", "kv_mask"}``, the paged
+        engine's gathered chains) is two-part: the view's columns precede
+        column 0 of ``cache["k"]``, every layer attends both and writes only
+        ``cache["k"]``/``["v"]`` — the view is an input of the layer scan that
+        is never returned, so nothing of its size is copied. The returned
+        cache is the advanced write window alone; the view stays the caller's."""
         B, S = input_ids.shape
         pos = cache["pos"]
+        view = cache.get("view")
+        capacity = cache["k"].shape[2]
         slot_positions = pos + jnp.arange(S, dtype=jnp.int32)[None]
         slot_positions = jnp.broadcast_to(slot_positions, (B, S))
         rope_positions = slot_positions if positions is None else positions
+        if view is not None:
+            capacity += view["k"].shape[2]
+            if positions is None:
+                rope_positions = slot_positions + view["k"].shape[2]
         chunk_mask = (
             attention_mask.astype(jnp.int32)
             if attention_mask is not None
@@ -771,11 +772,13 @@ class Llama(Module):
         # used the stretched one (advisor r3 finding).
         x, ctx = self.embed(
             params, input_ids, rope_positions, attention_mask,
-            rope_seq_len=cache["k"].shape[2],
+            rope_seq_len=capacity,
         )
         ctx["positions"] = slot_positions
         ctx["kv_mask"] = kv_mask
         ctx["cache_pos"] = pos
+        if view is not None:
+            ctx["prefix_mask"] = view["kv_mask"]
 
         # Same per-regime segmentation as training (_run_layers): each
         # segment's scan applies its own static window to cached_attention.
@@ -791,29 +794,33 @@ class Llama(Module):
 
             seg_layers = jax.tree_util.tree_map(sl, params["layers"])
             seg_k, seg_v = sl(cache["k"]), sl(cache["v"])
+            seg_view = None if view is None else (sl(view["k"]), sl(view["v"]))
             if p > 1:
                 fold = lambda t: t.reshape(seg_len // p, p, *t.shape[1:])
                 seg_layers = jax.tree_util.tree_map(fold, seg_layers)
                 seg_k, seg_v = fold(seg_k), fold(seg_v)
+                seg_view = jax.tree_util.tree_map(fold, seg_view)
 
             def scan_step(x, inp, _pattern=pattern, _p=p):
-                layer, ck, cv = inp
+                layer, ck, cv, pre = inp
                 if _p == 1:
                     x, new = self.block(
-                        layer, x, ctx, cache_layer={"k": ck, "v": cv}, window=_pattern[0]
+                        layer, x, ctx, cache_layer={"k": ck, "v": cv, "prefix": pre},
+                        window=_pattern[0],
                     )
                     return x, (new["k"], new["v"])
                 nks, nvs = [], []
                 for j in range(_p):
-                    lj = jax.tree_util.tree_map(lambda t: t[j], layer)
+                    lj, pj = jax.tree_util.tree_map(lambda t: t[j], (layer, pre))
                     x, new = self.block(
-                        lj, x, ctx, cache_layer={"k": ck[j], "v": cv[j]}, window=_pattern[j]
+                        lj, x, ctx, cache_layer={"k": ck[j], "v": cv[j], "prefix": pj},
+                        window=_pattern[j],
                     )
                     nks.append(new["k"])
                     nvs.append(new["v"])
                 return x, (jnp.stack(nks), jnp.stack(nvs))
 
-            x, (nk, nv) = jax.lax.scan(scan_step, x, (seg_layers, seg_k, seg_v))
+            x, (nk, nv) = jax.lax.scan(scan_step, x, (seg_layers, seg_k, seg_v, seg_view))
             if p > 1:
                 nk = nk.reshape(seg_len, *nk.shape[2:])
                 nv = nv.reshape(seg_len, *nv.shape[2:])

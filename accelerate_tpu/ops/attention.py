@@ -265,13 +265,20 @@ def splash_attention(q, k, v, *, causal=True, mask=None, window=None, softcap=No
 
 
 def cached_attention(q, k_cache, v_cache, *, q_positions, kv_mask=None, window=None,
-                     softcap=None, scale=None):
+                     softcap=None, scale=None, prefix=None):
     """Attention of a query chunk against a pre-allocated KV cache (decode path).
 
     q: (B, S, H, D); k_cache/v_cache: (B, K, Hkv, D) with H = G·Hkv (GQA).
     q_positions: (S,) or (B, S) global positions of the queries.
     kv_mask: (B, K) validity of cache slots (1 = real token). Slots beyond the
     write offset are excluded by the causal comparison alone.
+
+    ``prefix`` = ``(k, v, mask)`` of shapes (B, T, Hkv, D) and (B, T) makes the
+    cache two-part: read-only keys that all precede column 0 of ``k_cache``
+    (the paged engine's gathered view before its write window), with
+    ``q_positions`` and ``kv_mask`` still counted in ``k_cache``'s columns.
+    The result is that of one cache holding ``prefix`` then ``k_cache``,
+    without ever joining them (:func:`_prefixed_cached_attention`).
 
     Sliding windows measure VALID-slot distance when a ``kv_mask`` is given: a
     key is in a query's window iff fewer than ``window`` valid slots separate
@@ -287,6 +294,11 @@ def cached_attention(q, k_cache, v_cache, *, q_positions, kv_mask=None, window=N
     directly. For S=1 decode this is a bandwidth-bound GEMV over the cache,
     which is the best any kernel can do; no flash kernel needed.
     """
+    if prefix is not None:
+        return _prefixed_cached_attention(
+            q, k_cache, v_cache, prefix, q_positions=q_positions, kv_mask=kv_mask,
+            window=window, softcap=softcap, scale=scale,
+        )
     B, S, H, D = q.shape
     K, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = H // Hkv
@@ -314,6 +326,80 @@ def cached_attention(q, k_cache, v_cache, *, q_positions, kv_mask=None, window=N
     probs = jax.nn.softmax(scores + bias, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgsk,bkhd->bshgd", probs, v_cache)
     return out.reshape(B, S, H, D)
+
+
+def _prefixed_cached_attention(q, k_cache, v_cache, prefix, *, q_positions, kv_mask,
+                               window, softcap, scale):
+    """:func:`cached_attention` over ``prefix`` followed by ``k_cache`` with
+    the two never joined: scores against each part, ONE float32 softmax over
+    both (a shared max, the two sums added), values summed from both. A
+    ``concatenate`` of the parts would copy the prefix — the whole gathered
+    view, every layer of every decode step — to append a few columns.
+
+    Every prefix column precedes every query, so causality only cuts inside
+    ``k_cache``; a sliding window's valid-slot rank runs on from the prefix's
+    last rank into ``k_cache``, so windowed models stay exact across the seam."""
+    k_pre, v_pre, pre_mask = prefix
+    if kv_mask is None:
+        raise ValueError("a two-part cache carries a kv_mask for both parts")
+    B, S, H, D = q.shape
+    K, Hkv = k_cache.shape[1], k_cache.shape[2]
+    G = H // Hkv
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
+    qg = q.reshape(B, S, Hkv, G, D)
+
+    def scores_against(k):
+        s = jnp.einsum("bshgd,bkhd->bhgsk", qg, k).astype(jnp.float32) * scale
+        return s if softcap is None else softcap_scores(s, softcap)
+
+    if q_positions.ndim == 1:
+        q_positions = jnp.broadcast_to(q_positions[None], (B, S))
+    delta = q_positions[:, None, None, :, None] - jnp.arange(K)[None, None, None, None, :]
+    keep = (delta >= 0) & kv_mask[:, None, None, None, :].astype(bool)
+    pre_keep = pre_mask[:, None, None, None, :].astype(bool)
+    if window is not None:  # sliding window: the last `window` valid tokens
+        pre_rank = jnp.cumsum(pre_mask.astype(jnp.int32), axis=1)  # (B, T)
+        rank = pre_rank[:, -1:] + jnp.cumsum(kv_mask.astype(jnp.int32), axis=1)
+        q_rank = jnp.take_along_axis(rank, q_positions.astype(jnp.int32), axis=1)
+        q_rank = q_rank[:, None, None, :, None]
+        keep = keep & (q_rank - rank[:, None, None, None, :] < window)
+        pre_keep = pre_keep & (q_rank - pre_rank[:, None, None, None, :] < window)
+    s_pre = scores_against(k_pre) + jnp.where(pre_keep, 0.0, -1e30)
+    s_new = scores_against(k_cache) + jnp.where(keep, 0.0, -1e30)
+    top = jnp.maximum(s_pre.max(axis=-1), s_new.max(axis=-1))[..., None]
+    e_pre, e_new = jnp.exp(s_pre - top), jnp.exp(s_new - top)
+    total = e_pre.sum(axis=-1, keepdims=True) + e_new.sum(axis=-1, keepdims=True)
+    out = jnp.einsum("bhgsk,bkhd->bshgd", (e_pre / total).astype(q.dtype), v_pre,
+                     preferred_element_type=jnp.float32)
+    out = out + jnp.einsum("bhgsk,bkhd->bshgd", (e_new / total).astype(q.dtype), v_cache,
+                           preferred_element_type=jnp.float32)
+    return out.astype(q.dtype).reshape(B, S, H, D)
+
+
+def write_and_attend(q, k, v, cache_layer, ctx, *, window=None, softcap=None, scale=None):
+    """One layer's step through the KV cache, shared by the cached decoder
+    families: write this chunk's K/V into ``cache_layer["k"]``/``["v"]``
+    (B, K, Hkv, D) at ``ctx["cache_pos"]`` and attend against the result.
+    Returns ``(attn, {"k", "v"})``. A ``cache_layer["prefix"]`` = ``(k, v)``
+    (with ``ctx["prefix_mask"]``) is the paged engine's read-only view: it is
+    attended, never written and never returned."""
+    pos = ctx["cache_pos"]
+    k_cache = jax.lax.dynamic_update_slice(
+        cache_layer["k"], k.astype(cache_layer["k"].dtype), (0, pos, 0, 0)
+    )
+    v_cache = jax.lax.dynamic_update_slice(
+        cache_layer["v"], v.astype(cache_layer["v"].dtype), (0, pos, 0, 0)
+    )
+    prefix = cache_layer.get("prefix")
+    attn = cached_attention(
+        q, k_cache, v_cache,
+        q_positions=ctx["positions"],
+        kv_mask=ctx.get("kv_mask"),
+        window=window, softcap=softcap, scale=scale,
+        prefix=None if prefix is None else (*prefix, ctx["prefix_mask"]),
+    )
+    return attn, {"k": k_cache, "v": v_cache}
 
 
 def resolve_auto_impl(seq_len: int, num_heads: int, head_dim: int,

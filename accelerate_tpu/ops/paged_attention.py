@@ -14,10 +14,13 @@ This module is the op-level seam:
 - :func:`init_kv_pool` / :func:`gather_block_view` / :func:`gather_block_mask`
   are the pool primitives the engine's compiled programs are built from. The
   gather is the **reference lowering** — an XLA gather over the block axis
-  that materializes each slot's chain as a contiguous per-slot view, which
-  the model's ordinary ``cached_attention`` path then consumes unchanged (so
-  every model family — rope, learned wpe, sliding windows, softcap — stays
-  bit-exact with zero model changes).
+  that materializes each slot's chain as a contiguous per-slot view. The
+  engine hands that view to the model READ-ONLY, as the ``prefix`` of a
+  two-part cache whose second part is a write window the size of what the
+  program writes; ``cached_attention(prefix=...)`` attends both under one
+  softmax with the same hole-tolerant masks, so every model family — rope,
+  learned wpe, sliding windows, softcap — stays exact and nothing the size
+  of the view is written.
 - :func:`export_chain_blocks` / :func:`import_chain_blocks` are the KV-chain
   handoff faces: a finished prefill's block chain leaves one host's pool and
   splices into another's (serving_net/handoff.py) as a bounded per-chain

@@ -17,9 +17,9 @@ Two kernels kill it:
 - :func:`gather_block_view_kernel` — the chain-walk *assembly* kernel behind
   ``gather_block_view``: per-(layer, slot) DMA of pool blocks straight into
   the output view, skipping dead slots. This is the swap the serving
-  engine's uniform-write-window design consumes today (the view feeds the
-  unmodified model forward); the fused kernel above is the no-view seam the
-  model-side paged-cache integration targets.
+  engine's uniform-write-window design consumes today (the view is the
+  read-only part of the model forward's two-part cache); the fused kernel
+  above is the no-view seam the model-side paged-cache integration targets.
 
 Bit-exactness: inside the attention kernel the assembled chain is fed to the
 SAME ``cached_attention`` math the reference composes (a pure-jnp function —

@@ -83,10 +83,14 @@ HBM is consumed per *chain*, not per ``B x max_cache_len`` rectangle:
   accounting in the goodput-ledger idiom decides whether to admit, chunk,
   defer, or escalate a prefill (``slo_report()``); TTFT/TPOT histograms and
   pool gauges publish to the MetricsRegistry (docs/observability.md).
-- **The decode/chunk programs** gather each slot's chain into a contiguous
-  view with one uniform write window and run the UNMODIFIED model forward
-  over it (the reference block-table lowering), then scatter written columns
-  back onto chain tails. The engine loop runs one window AHEAD of its sync:
+- **The decode/chunk/verify programs** gather each slot's chain once into a
+  contiguous READ-ONLY view and give the model forward a two-part cache:
+  that view plus an empty write window the size of what the program writes
+  (``_paged_view_cache``). Every layer attends both parts under one softmax
+  and writes the window alone (``ops/attention.py`` ``cached_attention(prefix=
+  ...)``); the decode window's scan carries the window, never the view, and
+  the window is scattered onto chain tails as it is — nothing the size of
+  the view is copied per step. The engine loop runs one window AHEAD of its sync:
   each window's (active, n_out, out_buf) report is read only after the next
   window is dispatched, so the steady-state loop performs zero blocking
   transfers (pinned by tests).
@@ -1091,13 +1095,19 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------- compiled (paged)
     def _paged_view_cache(self, pool, tables, lens, write_cols: int):
-        """Gather every slot's block chain into a contiguous view cache plus a
-        fresh ``write_cols``-wide write window at one uniform offset — the
-        shape that lets the unmodified model forward (one global write
-        offset, hole-tolerant kv_mask, positions channel) run over paged
-        storage. The frontier comparison masks stale bits of reused
-        (freed→reallocated) blocks, so the free-list never needs device-side
-        scrubbing."""
+        """The two-part cache of a paged program, as ``(view, window)``.
+
+        ``view`` (``{"k", "v"}`` of (L, B, T, Hkv, D) and a ``"kv_mask"`` of
+        (B, T)) is every slot's block chain gathered once, and is read-only:
+        a program closes over it, every layer attends it, nothing writes it.
+        ``window`` is an ordinary empty cache ``write_cols`` wide (``"k"``,
+        ``"v"`` of (L, B, write_cols, Hkv, D), ``"kv_mask"``, ``"pos"`` 0) whose
+        columns follow the view's: what the program writes lands there, at
+        one uniform offset for all slots, and ``_scatter_pool`` takes it as
+        it is. The model forward gets ``{**window, "view": view}`` and
+        returns the advanced window (``Llama._apply_cached``). The frontier
+        comparison masks stale bits of reused (freed→reallocated) blocks, so
+        the free-list never needs device-side scrubbing."""
         bs = self.block_size
         t = self.max_blocks_per_slot * bs
         # Registry-dispatched assembly (op `paged_gather`): the Pallas
@@ -1123,14 +1133,10 @@ class ContinuousBatcher:
         vmask = jnp.where(jnp.arange(t)[None] < lens[:, None], vmask, 0)
         zeros = jnp.zeros(view_k.shape[:2] + (write_cols,) + view_k.shape[3:],
                           view_k.dtype)
-        return {
-            "k": jnp.concatenate([view_k, zeros], axis=2),
-            "v": jnp.concatenate([view_v, zeros], axis=2),
-            "pos": jnp.int32(t),
-            "kv_mask": jnp.concatenate(
-                [vmask, jnp.zeros((b, write_cols), jnp.int32)], axis=1
-            ),
-        }
+        view = {"k": view_k, "v": view_v, "kv_mask": vmask}
+        window = {"k": zeros, "v": zeros, "pos": jnp.int32(0),
+                  "kv_mask": jnp.zeros((b, write_cols), jnp.int32)}
+        return view, window
 
     def _scatter_pool(self, pool, blk, off, k_new, v_new, mask_new):
         """Append freshly written view columns onto chain tails — the single
@@ -1172,7 +1178,6 @@ class ContinuousBatcher:
         d_module = self._draft_module
         pad = self.pad
         bs = self.block_size
-        t = self.max_blocks_per_slot * bs
         spec = bool(self.speculative_k)
 
         def body(params, pool, state, tables, lens, slot, chunk_row, mask_row,
@@ -1181,7 +1186,7 @@ class ContinuousBatcher:
             (tok, pos, n_out, active, out_buf, keys,
              slot_max, slot_temp, slot_eos) = state
             B = tok.shape[0]
-            cache = self._paged_view_cache(pool, tables, lens, P)
+            view, window = self._paged_view_cache(pool, tables, lens, P)
             ids = jnp.zeros((B, P), jnp.int32).at[slot].set(chunk_row)
             mask = jnp.zeros((B, P), jnp.int32).at[slot].set(mask_row)
             # Token positions continue the slot's REAL-token count (holes
@@ -1189,14 +1194,13 @@ class ContinuousBatcher:
             # exact across chunk boundaries and identical to a monolithic
             # prefill of the same prompt.
             out = module.apply(params, input_ids=ids, attention_mask=mask,
-                               cache=cache, positions=mask_positions(mask) + base_pos)
+                               cache={**window, "view": view},
+                               positions=mask_positions(mask) + base_pos)
             idx = lens[slot] + jnp.arange(P)
             blk = tables[slot][idx // bs]
             off = idx % bs
             pool = self._scatter_pool(
-                pool, blk, off,
-                out["cache"]["k"][:, slot, t:t + P],
-                out["cache"]["v"][:, slot, t:t + P],
+                pool, blk, off, out["cache"]["k"][:, slot], out["cache"]["v"][:, slot],
                 jnp.where(blk != 0, mask_row, 0),
             )
             if spec:
@@ -1205,14 +1209,14 @@ class ContinuousBatcher:
                 # resident chain (including aliased shared-prefix blocks,
                 # which are written exactly once, here) carries draft KV by
                 # the time the first verify round needs it.
-                d_cache = self._paged_view_cache(d_pool, tables, lens, P)
+                d_view, d_window = self._paged_view_cache(d_pool, tables, lens, P)
                 d_out = d_module.apply(
-                    d_params, input_ids=ids, attention_mask=mask, cache=d_cache,
+                    d_params, input_ids=ids, attention_mask=mask,
+                    cache={**d_window, "view": d_view},
                     positions=mask_positions(mask) + base_pos)
                 d_pool = self._scatter_pool(
                     d_pool, blk, off,
-                    d_out["cache"]["k"][:, slot, t:t + P],
-                    d_out["cache"]["v"][:, slot, t:t + P],
+                    d_out["cache"]["k"][:, slot], d_out["cache"]["v"][:, slot],
                     jnp.where(blk != 0, mask_row, 0),
                 )
             real = jnp.sum(mask_row).astype(jnp.int32)
@@ -1312,7 +1316,6 @@ class ContinuousBatcher:
         module = self.module
         pad = self.pad
         bs = self.block_size
-        t = self.max_blocks_per_slot * bs
         w = self.sync_every
 
         def serve_decode_window(params, pool, tables, lens, commit, force_stop, state):
@@ -1325,23 +1328,26 @@ class ContinuousBatcher:
             active = active & ~force_stop
             state = (tok, pos, n_out, active, out_buf, keys,
                      slot_max, slot_temp, slot_eos)
-            cache = self._paged_view_cache(pool, tables, lens, w)
+            view, window = self._paged_view_cache(pool, tables, lens, w)
 
             def one_step(carry, _):
-                cache, state = carry
+                # The carry is the window alone; the view is closed over and
+                # constant across the steps.
+                window, state = carry
                 (tok, pos, n_out, active, out_buf, keys,
                  slot_max, slot_temp, slot_eos) = state
-                col = cache["pos"]  # view column this step writes
+                col = window["pos"]  # window column this step writes
                 feed = jnp.where(active, tok, pad)
-                out = module.apply(params, input_ids=feed[:, None], cache=cache,
+                out = module.apply(params, input_ids=feed[:, None],
+                                   cache={**window, "view": view},
                                    positions=pos[:, None])
                 nxt = self._sample_rows(out["logits"][:, -1], keys, n_out, slot_temp)
                 nxt = jnp.where(active, nxt, pad)
-                cache2 = out["cache"]
-                cache2 = {
-                    **cache2,
-                    "kv_mask": cache2["kv_mask"].at[:, col].set(
-                        jnp.where(active, cache2["kv_mask"][:, col], 0)
+                window2 = out["cache"]
+                window2 = {
+                    **window2,
+                    "kv_mask": window2["kv_mask"].at[:, col].set(
+                        jnp.where(active, window2["kv_mask"][:, col], 0)
                     ),
                 }
                 emit_idx = jnp.clip(n_out, 0, self.max_new - 1)
@@ -1353,10 +1359,10 @@ class ContinuousBatcher:
                 still = active & (nxt != slot_eos) & (n_out < slot_max)
                 state = (nxt, pos + 1, n_out, still, out_buf, keys,
                          slot_max, slot_temp, slot_eos)
-                return (cache2, state), None
+                return (window2, state), None
 
-            (cache, state), _ = jax.lax.scan(one_step, (cache, state), None, length=w)
-            # Persist the window: committed slots append their written view
+            (window, state), _ = jax.lax.scan(one_step, (window, state), None, length=w)
+            # Persist the window: committed slots append their written
             # columns (valid or holed — the per-slot chain mirrors the
             # contiguous scheme's unconditional global advance); everything
             # else lands in the trash block with a forced-zero mask, so
@@ -1368,10 +1374,9 @@ class ContinuousBatcher:
                 0,
             )
             off = (idx % bs).astype(jnp.int32)
-            wm = cache["kv_mask"][:, t:t + w]
             pool = self._scatter_pool(
-                pool, blk, off, cache["k"][:, :, t:t + w],
-                cache["v"][:, :, t:t + w], jnp.where(blk != 0, wm, 0),
+                pool, blk, off, window["k"], window["v"],
+                jnp.where(blk != 0, window["kv_mask"], 0),
             )
             report = jax.lax.optimization_barrier((state[3], state[2], state[4]))
             return pool, state, report
@@ -1403,7 +1408,7 @@ class ContinuousBatcher:
             "jaxpr_thunk": lambda *a, **k: jax.make_jaxpr(serve_decode_window)(*a, **k),
             # The static-memory join for `accelerate-tpu memcheck --serving`:
             # the persistent pool is the class the per-device KV budget gate
-            # prices (the gathered view + write window land in XLA's temp
+            # prices (the gathered view and the write window land in XLA's temp
             # workspace via memory_analysis, not here).
             "memory_classes": {
                 "kv_pool": (lambda: self._pool, lambda: None),
@@ -1445,7 +1450,6 @@ class ContinuousBatcher:
         d_module = self._draft_module
         pad = self.pad
         bs = self.block_size
-        t = self.max_blocks_per_slot * bs
         k = self.speculative_k
         S = k + 1
 
@@ -1460,26 +1464,27 @@ class ContinuousBatcher:
             # ACCEPTED draft token's draft-KV is written too — without it a
             # fully-accepted round would leave the draft chain one column
             # short of the target chain.
-            d_cache = self._paged_view_cache(d_pool, tables, lens, S)
+            d_view, d_window = self._paged_view_cache(d_pool, tables, lens, S)
 
             def d_step(carry, _):
-                d_cache, d_tok, d_pos = carry
+                d_window, d_tok, d_pos = carry
                 feed = jnp.where(active, d_tok, pad)
                 d_out = d_module.apply(d_params, input_ids=feed[:, None],
-                                       cache=d_cache, positions=d_pos[:, None])
+                                       cache={**d_window, "view": d_view},
+                                       positions=d_pos[:, None])
                 nxt = jnp.argmax(d_out["logits"][:, -1], axis=-1).astype(jnp.int32)
                 return (d_out["cache"], nxt, d_pos + 1), feed
 
-            (d_cache, _, _), fed = jax.lax.scan(
-                d_step, (d_cache, tok, pos), None, length=S
+            (d_window, _, _), fed = jax.lax.scan(
+                d_step, (d_window, tok, pos), None, length=S
             )
             ids = fed.T  # (B, S): [cur, d_0 .. d_{k-1}] per row
             # --- target leg: ONE forward over the whole window.
-            cache = self._paged_view_cache(pool, tables, lens, S)
+            view, window = self._paged_view_cache(pool, tables, lens, S)
             mask = jnp.broadcast_to(active[:, None], (B, S)).astype(jnp.int32)
             out = module.apply(
                 params, input_ids=jnp.where(active[:, None], ids, pad),
-                attention_mask=mask, cache=cache,
+                attention_mask=mask, cache={**window, "view": view},
                 positions=pos[:, None] + jnp.arange(S)[None],
             )
             choices = jnp.stack(
@@ -1534,16 +1539,14 @@ class ContinuousBatcher:
                     axis=1),
                 0)
             off = (idx % bs).astype(jnp.int32)
-            vcache = out["cache"]
+            window = out["cache"]
             pool = self._scatter_pool(
-                pool, blk, off, vcache["k"][:, :, t:t + S],
-                vcache["v"][:, :, t:t + S],
-                jnp.where(blk != 0, vcache["kv_mask"][:, t:t + S], 0),
+                pool, blk, off, window["k"], window["v"],
+                jnp.where(blk != 0, window["kv_mask"], 0),
             )
             d_pool = self._scatter_pool(
-                d_pool, blk, off, d_cache["k"][:, :, t:t + S],
-                d_cache["v"][:, :, t:t + S],
-                jnp.where(blk != 0, d_cache["kv_mask"][:, t:t + S], 0),
+                d_pool, blk, off, d_window["k"], d_window["v"],
+                jnp.where(blk != 0, d_window["kv_mask"], 0),
             )
             report = jax.lax.optimization_barrier((state[3], state[2], state[4]))
             return pool, d_pool, state, produced, report

@@ -57,7 +57,7 @@ class SpanRing:
     separate length bookkeeping a concurrent pusher could regress (the
     read-modify-write that a plain ``self._n = i + 1`` hides)."""
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = 16384):
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self.capacity = capacity

@@ -101,3 +101,48 @@ def test_init_kv_pool_probes_model_layout():
     assert pool["v"].shape == pool["k"].shape
     assert pool["mask"].shape == (7, 4)
     assert int(np.asarray(pool["mask"]).sum()) == 0
+
+
+@pytest.mark.parametrize("softcap", [None, 5.0])
+@pytest.mark.parametrize("chunk", [1, 4])
+@pytest.mark.parametrize("window", [None, 3, 9])
+def test_cached_attention_over_two_parts_equals_the_joined_cache(window, chunk, softcap):
+    """``cached_attention(prefix=(k, v, mask))`` — the paged engine's read-only
+    view before its write window — equals ``cached_attention`` over the
+    concatenation within float32 rounding: holes in both parts, a query chunk
+    written part-way into the window (later columns cut by causality), a row
+    whose prefix is empty, and sliding windows shorter and longer than the
+    columns already in the window, so the valid-slot rank crosses the seam."""
+    rng = np.random.default_rng(17)
+    b, t, w, hkv, g, d = 3, 12, 8, 2, 2, 8
+    k_pre, v_pre = (jnp.asarray(rng.standard_normal((b, t, hkv, d)), jnp.float32) for _ in range(2))
+    k_win, v_win = (jnp.asarray(rng.standard_normal((b, w, hkv, d)), jnp.float32) for _ in range(2))
+    pre_mask = np.ones((b, t), np.int32)
+    pre_mask[0, [2, 5, 11]] = 0       # bucket holes inside a chain
+    pre_mask[1, 7:] = 0               # a short chain: stale columns past its frontier
+    pre_mask[2, :] = 0                # an empty chain
+    start = 2                         # two columns already in the window, one a hole
+    win_mask = np.zeros((b, w), np.int32)
+    win_mask[:, :start + chunk] = 1
+    win_mask[0, 1] = 0
+    q = jnp.asarray(rng.standard_normal((b, chunk, hkv * g, d)), jnp.float32)
+    q_pos = jnp.broadcast_to(start + jnp.arange(chunk)[None], (b, chunk))
+    two = cached_attention(
+        q, k_win, v_win, q_positions=q_pos, kv_mask=jnp.asarray(win_mask), window=window,
+        softcap=softcap, prefix=(k_pre, v_pre, jnp.asarray(pre_mask)),
+    )
+    one = cached_attention(
+        q, jnp.concatenate([k_pre, k_win], axis=1), jnp.concatenate([v_pre, v_win], axis=1),
+        q_positions=q_pos + t, kv_mask=jnp.asarray(np.concatenate([pre_mask, win_mask], axis=1)),
+        window=window, softcap=softcap,
+    )
+    np.testing.assert_allclose(np.asarray(two), np.asarray(one), rtol=2e-6, atol=2e-6)
+    # The poisoned columns (masked, or ahead of the query) never reach the sum.
+    poisoned = cached_attention(
+        q, jnp.where(jnp.asarray(win_mask)[..., None, None] > 0, k_win, 1e6),
+        v_win.at[:, start + chunk:].set(1e6), q_positions=q_pos,
+        kv_mask=jnp.asarray(win_mask), window=window, softcap=softcap,
+        prefix=(jnp.where(jnp.asarray(pre_mask)[..., None, None] > 0, k_pre, 1e6), v_pre,
+                jnp.asarray(pre_mask)),
+    )
+    np.testing.assert_allclose(np.asarray(poisoned), np.asarray(two), rtol=2e-6, atol=2e-6)

@@ -6,6 +6,8 @@ independent — and sampled outputs depend only on (engine rng, request id),
 not on traffic or slot assignment. Exceeds the reference, which serves whole
 batches through ``model.generate`` with head-of-line blocking."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -728,3 +730,236 @@ def test_paged_telemetry_histograms_and_gauges(llama):
     assert 0.0 <= util <= 1.0
     assert snap["accelerate_serving_kv_pool_blocks_free"] == float(engine.num_blocks)
     assert all(r in engine._req_times for r in rids)
+
+
+# ------------------------------------------- two-part paged cache (PR 31)
+
+
+class _JoinedCache:
+    """Test oracle: the joined form the paged engine used to build. It takes
+    the two-part cache, concatenates view and write window into one ordinary
+    cache, runs the wrapped model's one-part forward over it and hands back
+    the window's columns — same mathematics, one softmax over one array."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def apply(self, params, *, cache, **kwargs):
+        view = cache["view"]
+        t = view["k"].shape[2]
+        joined = {
+            "k": jnp.concatenate([view["k"], cache["k"]], axis=2),
+            "v": jnp.concatenate([view["v"], cache["v"]], axis=2),
+            "kv_mask": jnp.concatenate([view["kv_mask"], cache["kv_mask"]], axis=1),
+            "pos": cache["pos"] + t,
+        }
+        out = self._inner.apply(params, cache=joined, **kwargs)
+        new = out["cache"]
+        out["cache"] = {"k": new["k"][:, :, t:], "v": new["v"][:, :, t:],
+                        "kv_mask": new["kv_mask"][:, t:], "pos": new["pos"] - t}
+        return out
+
+
+@pytest.mark.parametrize("family", ["llama", "llama_windowed", "gpt2", "gptx"])
+def test_two_part_cache_forward_equals_the_joined_forward(family):
+    """``apply(cache={**window, "view": view})`` for each cached decoder
+    family, called directly and without ``positions`` (the token positions
+    then run on from the view's length): logits and the returned window
+    equal the one-part forward over the joined cache, with holes in the view
+    and a window that already holds a column."""
+    from accelerate_tpu.models.gptx import GPTX, GPTXConfig
+
+    if family == "gpt2":
+        model = GPT2(GPT2Config(vocab_size=128, hidden_size=32, num_hidden_layers=2,
+                                num_attention_heads=2, max_position_embeddings=64))
+    elif family == "gptx":
+        model = GPTX(GPTXConfig.tiny())
+    else:
+        model = Llama(LlamaConfig.tiny(
+            num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+            sliding_window=3 if family == "llama_windowed" else None))
+    params = model.init_params(jax.random.key(5))
+    b, t, w, s = 2, 12, 6, 2
+    layers, _, _, hkv, d = model.init_cache(b, w, dtype=jnp.float32)["k"].shape
+    rng = np.random.default_rng(23)
+    rand = lambda cols: jnp.asarray(rng.standard_normal((layers, b, cols, hkv, d)), jnp.float32)
+    view_mask = np.ones((b, t), np.int32)
+    view_mask[0, [1, 6]], view_mask[1, 9:] = 0, 0
+    view = {"k": rand(t), "v": rand(t), "kv_mask": jnp.asarray(view_mask)}
+    window = {"k": rand(w).at[:, :, 1:].set(0), "v": rand(w).at[:, :, 1:].set(0),
+              "pos": jnp.int32(1), "kv_mask": jnp.zeros((b, w), jnp.int32).at[:, 0].set(1)}
+    ids = jnp.asarray(rng.integers(1, 100, (b, s)), jnp.int32)
+    two = model.apply(params, input_ids=ids, cache={**window, "view": view})
+    one = _JoinedCache(model).apply(params, input_ids=ids, cache={**window, "view": view})
+    np.testing.assert_allclose(two["logits"], one["logits"], rtol=2e-5, atol=2e-5)
+    assert set(two["cache"]) == {"k", "v", "pos", "kv_mask"} and int(two["cache"]["pos"]) == 1 + s
+    for name in ("k", "v", "kv_mask"):
+        np.testing.assert_allclose(two["cache"][name], one["cache"][name], rtol=2e-5, atol=2e-5)
+
+
+def _two_part_case(name):
+    """(model, prompts, shared prefix or None, paged-engine overrides, max_new)."""
+    rng = np.random.default_rng(310)
+    ragged = [rng.integers(1, 120, (n,)).astype(np.int32) for n in (5, 9, 3, 12, 7, 4)]
+    tiny = dict(num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
+    if name == "gpt2":
+        model = GPT2(GPT2Config(vocab_size=128, hidden_size=32, num_hidden_layers=2,
+                                num_attention_heads=2, max_position_embeddings=64))
+        model.init_params(jax.random.key(3))
+        return model, ragged[:3], None, dict(max_cache_len=64, bucket_sizes=(16,)), 5
+    window = 4 if name == "sliding_window" else None
+    model = Llama(LlamaConfig.tiny(sliding_window=window, **tiny))
+    model.init_params(jax.random.key(0))
+    if name == "plain":  # bucket-sized prompts, one-step windows: no hole anywhere
+        full = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (8, 16)]
+        return model, full, None, dict(sync_every=1), 8
+    if name == "shared_prefix":
+        prefix = rng.integers(1, 256, (12,)).astype(np.int32)
+        suffixes = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (4, 7, 3, 6)]
+        return model, suffixes, prefix, dict(
+            bucket_sizes=(8,), prefill_chunk=8, max_tokens_per_request=64), 6
+    if name == "kv_quant":
+        return model, ragged, None, dict(kv_quant="int8", sync_every=4), 8
+    return model, ragged, None, dict(sync_every=4), 8  # bucket_holes, sliding_window
+
+
+def _serve(engine, prompts, prefix):
+    if prefix is not None:
+        engine.set_prefix(prefix)
+    rids = [engine.submit(p) for p in prompts]
+    outs = engine.run()
+    return [outs[r] for r in rids]
+
+
+@pytest.mark.parametrize(
+    "case", ["plain", "bucket_holes", "shared_prefix", "sliding_window", "kv_quant", "gpt2"])
+def test_paged_two_part_cache_serves_the_joined_cache_s_tokens(case):
+    """The paged programs attend (read-only view, write window) without ever
+    joining them. Their tokens equal those of the same engine over the joined
+    cache (the oracle above), the contiguous engine's and solo generate()'s:
+    with no hole, with bucket and finished-row holes, across aliased prefix
+    blocks, with a window whose valid-slot rank crosses the seam, and for a
+    model with learned positions. An int8 pool is lossy against the
+    contiguous float cache, so it is held to the joined oracle alone (which
+    reads and writes the same quantized rows)."""
+    model, prompts, prefix, overrides, max_new = _two_part_case(case)
+    served = _serve(_paged(model, max_new_tokens=max_new, **overrides), prompts, prefix)
+    joined = _serve(_paged(_JoinedCache(model), max_new_tokens=max_new, **overrides),
+                    prompts, prefix)
+    for got, want in zip(served, joined):
+        np.testing.assert_array_equal(got, want)
+    if case == "kv_quant":
+        return
+    contiguous = _serve(
+        ContinuousBatcher(model, batch_slots=2, max_new_tokens=max_new,
+                          max_cache_len=overrides.get("max_cache_len", 512),
+                          cache_dtype=jnp.float32,
+                          bucket_sizes=overrides.get("bucket_sizes", (8, 16)),
+                          sync_every=overrides.get("sync_every", 2)),
+        prompts, prefix)
+    for got, want, p in zip(served, contiguous, prompts):
+        np.testing.assert_array_equal(got, want)
+        whole = p if prefix is None else np.concatenate([prefix, p])
+        np.testing.assert_array_equal(got, _solo(model, whole, max_new)[: len(got)])
+
+
+def test_paged_decode_window_straddling_blocks_scatters_to_its_chain(llama):
+    """One slot, 6 tokens in its chain, blocks of 4 and a window of 8 steps:
+    the window's columns are chain positions 6..13, the tail of block 1, all
+    of block 2 and half of block 3 of a table that lists its blocks out of
+    order. After the program those pool rows, and no others, hold the
+    window's keys: the same rows as under the joined oracle."""
+    def window_run(model):
+        engine = _paged(model, batch_slots=1, sync_every=8, max_new_tokens=16)
+        params, pool, tables, lens, commit, stop, state = engine._decode_args()
+        rng = np.random.default_rng(7)
+        chain = np.asarray([5, 2, 7, 3], np.int32)
+        tables = np.zeros_like(np.asarray(tables))
+        tables[0, :4] = chain
+        pool = {k: jnp.asarray(rng.standard_normal(v.shape), v.dtype) for k, v in pool.items()}
+        mask = np.zeros(pool["mask"].shape, np.int32)
+        mask[5, :], mask[2, :2] = 1, 1      # 6 tokens, and stale bits beyond them:
+        mask[2, 2:], mask[7, :] = 1, 1      # a reused block's, which the frontier hides
+        pool["mask"] = jnp.asarray(mask)
+        tok, pos, n_out, active, out_buf, keys, slot_max, slot_temp, slot_eos = state
+        state = (tok.at[0].set(11), pos.at[0].set(6), n_out.at[0].set(1),
+                 active.at[0].set(True), out_buf, keys, slot_max.at[0].set(16),
+                 slot_temp, slot_eos)
+        before = {k: np.asarray(v) for k, v in pool.items()}
+        new_pool, new_state, _ = engine._decode()(
+            params, pool, jnp.asarray(tables), jnp.asarray([6], jnp.int32),
+            jnp.asarray([True]), stop, state)
+        return before, {k: np.asarray(v) for k, v in new_pool.items()}, np.asarray(new_state[4])
+
+    before, after, tokens = window_run(llama)
+    written = [(2, 2), (2, 3), (7, 0), (7, 1), (7, 2), (7, 3), (3, 0), (3, 1)]
+    touched = np.zeros(before["mask"].shape, bool)
+    for blk, off in written:
+        touched[blk, off] = True
+        assert after["mask"][blk, off] == 1
+        assert not np.array_equal(after["k"][:, blk, off], before["k"][:, blk, off])
+    np.testing.assert_array_equal(after["mask"][~touched], before["mask"][~touched])
+    np.testing.assert_array_equal(after["k"][:, ~touched], before["k"][:, ~touched])
+    np.testing.assert_array_equal(after["v"][:, ~touched], before["v"][:, ~touched])
+    _, joined, joined_tokens = window_run(_JoinedCache(llama))
+    np.testing.assert_array_equal(tokens, joined_tokens)
+    for name in ("k", "v"):
+        np.testing.assert_allclose(after[name], joined[name], rtol=1e-5, atol=1e-6)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple)) else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_eqns(inner)
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk", "spec_verify"])
+def test_paged_programs_never_rewrite_the_gathered_view(llama, program):
+    """Holds PR 31's gain. The gathered view ``(L, B, T, Hkv, D)`` is read-only
+    in every paged program: nothing concatenates onto it, updates a slice of
+    it or copies it (whole, or a layer's ``(B, T, Hkv, D)`` slice of it, with
+    or without a write window appended), in the traced program and in the
+    compiled one, and no scan carries an array of its size from step to step
+    — the decode window's carry is the window buffer. A change that hands the
+    model a joined cache again fails here before it costs 2 GB of copies a
+    decode step on the chip."""
+    spec = dict(speculative_k=2, draft_model=llama) if program == "spec_verify" else {}
+    engine = _paged(llama, sync_every=3, **spec)
+    if program == "prefill_chunk":
+        fn, args, wide = engine._chunk_fn(8), engine._chunk_args(8), 8
+    elif program == "spec_verify":
+        fn, args, wide = engine._spec_verify(), engine._verify_args(), 3
+    else:
+        fn, args, wide = engine._decode(), engine._decode_args(), 3
+    layers, _, bs, hkv, d = engine._pool["k"].shape
+    t = engine.max_blocks_per_slot * bs
+    per_layer = {(engine.B, cols, hkv, d) for cols in (t, t + wide)}
+    view_shapes = per_layer | {(layers,) + shape for shape in per_layer}
+    view_size = layers * engine.B * t * hkv * d
+
+    rewrites = {"concatenate", "dynamic_update_slice", "copy", "pad", "scatter"}
+    scans = 0
+    for eqn in _walk_eqns(fn._audit_meta["jaxpr_thunk"](*args).jaxpr):
+        shapes = {tuple(v.aval.shape) for v in list(eqn.invars) + list(eqn.outvars)
+                  if hasattr(v.aval, "shape")}
+        if eqn.primitive.name in rewrites:
+            assert not shapes & view_shapes, (eqn.primitive.name, shapes & view_shapes)
+        if eqn.primitive.name == "scan":
+            scans += 1
+            carried = eqn.outvars[:eqn.params["num_carry"]]
+            assert all(v.aval.size < view_size for v in carried), [v.aval for v in carried]
+    assert scans >= (1 if program == "prefill_chunk" else 2)  # the layer scan, inside the step scan
+
+    pattern = "|".join(r"\[" + ",".join(map(str, shape)) + r"\]" for shape in view_shapes)
+    for line in fn.lower(*args).compile().as_text().splitlines():
+        op = re.search(r"= \S+ (copy|concatenate|dynamic-update-slice|pad)\(", line)
+        if op:
+            assert not re.search(pattern, line), line.strip()[:200]

@@ -81,6 +81,24 @@ def test_span_records_rid_and_attrs_and_nests_as_before():
     assert outer.duration_s >= inner.duration_s >= 0.0
 
 
+def test_default_ring_holds_a_benchmark_run_s_records():
+    """The process ring has to keep every record a serving cell writes from
+    its window's start to the read after the drain (``PERF.md`` section 7: a
+    reader gives nothing once the ring has wrapped past the window's start).
+    A run writes 6.9 records a turn; 16,384 slots hold 70 s of turns down to
+    30 ms. A push stays one slot write whatever the capacity."""
+    from accelerate_tpu.telemetry import get_span_ring
+
+    assert SpanRing().capacity == get_span_ring().capacity == 16384
+    ring = SpanRing()
+    for i in range(ring.capacity + 5):
+        with span("turn", ring=ring, rid=i):
+            pass
+    kept = ring.snapshot()
+    assert ring.total == ring.capacity + 5 and len(kept) == ring.capacity
+    assert (kept[0].rid, kept[-1].rid) == (5, ring.capacity + 4)
+
+
 def test_record_span_pushes_an_interval_read_on_two_threads():
     """The second entry point: explicit ends on ``time.perf_counter()``, no
     annotation, and the calling thread's span stack left alone."""
