@@ -2,6 +2,7 @@ from .bert import BertConfig, BertForSequenceClassification
 from .gpt2 import GPT2, GPT2Config
 from .gptx import GPTX, GPTXConfig
 from .llama import Llama, LlamaConfig
+from .minicpm_sala import MiniCPMSALA, MiniCPMSALAConfig
 from .moe import MoELlama, MoELlamaConfig
 from .t5 import T5Config, T5ForConditionalGeneration
 from .vision import ConvNetConfig, ConvNetForImageClassification

@@ -66,15 +66,43 @@ import jax.numpy as jnp
 from .attention import cached_attention
 
 
+# What a module's ``init_cache`` dict holds, for the paged engine. ``by_token``
+# entries are ``(layers, batch, columns, kv_heads, head_dim)`` and become block
+# pools, paged by token through the block tables; ``by_slot`` entries are
+# ``(layers, batch, ...)`` of any dtype and are held once a batch slot, whatever
+# the sequence's length (a recurrent state). ``dense_chain`` asks the engine to
+# keep a chain free of holes below its frontier, so that a key's column is its
+# token's position. ``attended_keys`` says that the cached forward of one token
+# returns ``"attended_keys"`` and ``"context_keys"`` (B,) beside its logits. A
+# module without a ``cache_layout`` is the plain case.
+PLAIN_CACHE_LAYOUT = {"by_token": ("k", "v"), "by_slot": (), "dense_chain": False,
+                      "attended_keys": False}
+
+
+def cache_layout(module) -> dict:
+    """``module.cache_layout`` over the plain layout: which entries of its
+    ``init_cache`` are paged by token and which are held by slot."""
+    layout = {**PLAIN_CACHE_LAYOUT, **getattr(module, "cache_layout", {})}
+    if tuple(layout["by_token"]) != ("k", "v"):
+        raise ValueError(
+            f"the paged pool pages 'k' and 'v' by token; {type(module).__name__}."
+            f"cache_layout asks for {layout['by_token']!r}")
+    return layout
+
+
 def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
-                 quant: str | None = None):
-    """Allocate the per-layer block pool for ``module``'s cache layout.
+                 quant: str | None = None, slots: int = 1):
+    """Allocate the block pool for ``module``'s cache layout.
 
     Returns ``{"k": (L, N, bs, Hkv, D), "v": same, "mask": (N, bs) int32}``
     with ``N = num_blocks + 1`` — block 0 is the reserved trash block (see
-    module docstring). The layer/head/dim axes are probed from the module's
-    own ``init_cache`` so every cached decoder family (Llama/GPT-2/GPT-X)
-    gets its exact layout without a second cache contract.
+    module docstring). The layer/head/dim axes are probed from the ``by_token``
+    entries of the module's own ``init_cache`` (:func:`cache_layout`), so every
+    cached decoder family (Llama/GPT-2/GPT-X) gets its exact layout without a
+    second cache contract; ``L`` counts the layers that HAVE keys and values.
+    Each ``by_slot`` entry of the probe (``(layers, 1, ...)``) is allocated as
+    ``(layers, slots, ...)`` zeros of its own dtype beside the blocks: one a
+    batch slot, never paged.
 
     ``quant="int8"`` stores the K/V payloads as int8 and adds per-block scale
     tables ``{"k_scale": (L, N, bs) float32, "v_scale": same}`` — one scale
@@ -85,6 +113,7 @@ def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     the pool itself."""
     if quant not in (None, "int8"):
         raise ValueError(f"kv pool quant must be None or 'int8', got {quant!r}")
+    layout = cache_layout(module)
     probe = module.init_cache(1, block_size, dtype=dtype)
     L, _, _, hkv, hd = probe["k"].shape
     n = num_blocks + 1
@@ -97,7 +126,18 @@ def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     if quant == "int8":
         pool["k_scale"] = jnp.zeros((L, n, block_size), jnp.float32)
         pool["v_scale"] = jnp.zeros((L, n, block_size), jnp.float32)
+    for name in layout["by_slot"]:
+        held = probe[name]
+        pool[name] = jnp.zeros((held.shape[0], slots) + held.shape[2:], held.dtype)
     return pool
+
+
+def pool_bytes(pool, layout=PLAIN_CACHE_LAYOUT) -> dict:
+    """The cache's persistent device bytes by kind: ``"kv"`` (keys and values
+    paged by token and, where quantized, their scales) and ``"state"`` (the
+    ``by_slot`` entries)."""
+    kv = sum(int(pool[name].nbytes) for name in ("k", "v", "k_scale", "v_scale") if name in pool)
+    return {"kv": kv, "state": sum(int(pool[name].nbytes) for name in layout["by_slot"])}
 
 
 def pool_is_quantized(pool) -> bool:

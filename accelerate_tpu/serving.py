@@ -114,7 +114,8 @@ import jax.numpy as jnp
 
 from .generation import _unwrap, left_align, mask_positions
 from .ops.int8 import quantize_kv
-from .ops.paged_attention import gather_block_mask, gather_view, init_kv_pool
+from .ops.paged_attention import (cache_layout, gather_block_mask, gather_view, init_kv_pool,
+                                  pool_bytes)
 from .utils.environment import safe_donate_argnums
 from .utils.transfer import host_fetch
 
@@ -125,8 +126,9 @@ _SERVING_SPEC_METRICS = None
 
 
 def _serving_counters():
-    """(submitted, completed, tokens) telemetry counters — the per-request
-    paths pay only the .inc() (cached_handles hoists the registry lookup)."""
+    """(submitted, completed, tokens, prefix lookups refused) telemetry
+    counters — the per-request paths pay only the .inc() (cached_handles
+    hoists the registry lookup)."""
     global _SERVING_COUNTERS
     if _SERVING_COUNTERS is None:
         from .telemetry.metrics import cached_handles
@@ -142,12 +144,17 @@ def _serving_counters():
             registry.counter(
                 "accelerate_serving_tokens_total", "Tokens generated by the engine"
             ),
+            registry.counter(
+                "accelerate_serving_prefix_lookups_refused_total",
+                "Prefix-cache lookups refused because the model carries recurrent state",
+            ),
         ))
     return _SERVING_COUNTERS()
 
 
 def _slo_metrics():
-    """(ttft_hist, tpot_hist, blocks_free_gauge, pool_util_gauge) — the
+    """(ttft_hist, tpot_hist, blocks_free_gauge, pool_util_gauge,
+    cache_bytes_gauge) — the
     serving SLO/telemetry handles (docs/observability.md), hoisted like the
     request counters so the per-request paths pay only the observe/set."""
     global _SERVING_SLO_METRICS
@@ -170,6 +177,12 @@ def _slo_metrics():
             registry.gauge(
                 "accelerate_serving_kv_pool_utilization",
                 "Allocated fraction of the paged KV pool's blocks",
+            ),
+            registry.gauge(
+                "accelerate_serving_cache_bytes",
+                "Persistent device bytes of the paged cache by kind: kv (blocks paged by "
+                "token, scales included) and state (recurrent state held by slot)",
+                labelnames=("kind",),
             ),
         ))
     return _SERVING_SLO_METRICS()
@@ -357,6 +370,23 @@ class ContinuousBatcher:
         if kv_quant not in (None, "int8"):
             raise ValueError(f"kv_quant must be None or 'int8', got {kv_quant!r}")
         self.kv_quant = kv_quant
+        # What the model's cache holds (ops/paged_attention.py cache_layout):
+        # entries paged by token, entries held by slot (a recurrent state),
+        # and whether its chains must stay dense. State held by slot cannot
+        # be shared or truncated block by block, so prefix aliasing stands
+        # down and speculative decoding is refused for such a model.
+        self._layout = cache_layout(module)
+        self._stateful = bool(self._layout["by_slot"])
+        if self._stateful and not paged:
+            raise ValueError(
+                f"{type(module).__name__} carries recurrent state held by slot "
+                f"({', '.join(self._layout['by_slot'])}): it serves through the paged "
+                "engine only; pass paged=True")
+        if self._stateful and self.speculative_k:
+            raise ValueError(
+                f"speculative decoding rolls a rejected draft back by block-table "
+                f"truncation, which cannot roll back the recurrent state "
+                f"{type(module).__name__} holds by slot; run it with speculative_k=0")
         # ---------------------------------------------------- paged KV mode
         # paged=True swaps the contiguous (B, max_cache_len) cache for a
         # block pool (ops/paged_attention.py): `num_blocks` blocks of
@@ -529,6 +559,24 @@ class ContinuousBatcher:
         self.stream = None
         self._streamed: dict[int, int] = {}
         self.reset()
+        if self.paged and trace_requests:
+            self._record_cache_layout()
+
+    def _record_cache_layout(self):
+        """One ``serve.cache_layout`` record at engine start: what a token
+        costs in keys and values, what a slot costs in recurrent state, and
+        how many layers hold each (docs/observability.md)."""
+        from .telemetry.spans import record_span
+
+        pool, now = self._pool, time.perf_counter()
+        held = [pool[name] for name in self._layout["by_slot"]]
+        record_span(
+            "serve.cache_layout", now, now,
+            kv_bytes_per_token=self._pool_bytes["kv"] // (pool["mask"].shape[0] * self.block_size),
+            state_bytes_per_slot=self._pool_bytes["state"] // self.B,
+            kv_layers=int(pool["k"].shape[0]),
+            state_layers=sum(int(x.shape[0]) for x in held),
+        )
 
     def _build_draft_from_preset(self, preset: str):
         """Materialize the env-named draft model (``ACCELERATE_DRAFT_MODEL``,
@@ -610,8 +658,12 @@ class ContinuousBatcher:
         B = self.B
         self._pool = init_kv_pool(
             self.module, self.num_blocks, self.block_size,
-            dtype=self.cache_dtype, quant=self.kv_quant,
+            dtype=self.cache_dtype, quant=self.kv_quant, slots=B,
         )
+        # The pool's bytes by kind are fixed once it is allocated.
+        self._pool_bytes = pool_bytes(self._pool, self._layout)
+        for kind, nbytes in self._pool_bytes.items():
+            _slo_metrics()[4].set(float(nbytes), kind=kind)
         # The draft pool mirrors the target pool's block geometry (same
         # num_blocks/block_size/max_blocks_per_slot), so a chain's block i
         # holds target KV in self._pool AND draft KV in self._draft_pool
@@ -754,17 +806,17 @@ class ContinuousBatcher:
 
     @property
     def kv_cache_bytes(self) -> int:
-        """Persistent device bytes of the KV store — the contiguous cache's
-        k/v arrays, or the paged pool (trash block included). The denominator
+        """Persistent device bytes of the cache — the contiguous cache's
+        k/v arrays, or the paged pool (trash block included) with, for a model
+        that carries recurrent state, the state it holds by slot
+        (``pool_stats()`` names the two kinds). The denominator
         of the serving bench's admitted-tokens-per-cache-byte capacity
         metric, and the quantity ``accelerate-tpu memcheck --serving`` gates
         against the HBM budget. A quantized pool (``kv_quant="int8"``) prices
         its per-token scale planes too; speculative decoding adds the draft
         pool's blocks — both layouts the memcheck gate must cover."""
-        store = self._pool if self.paged else self._cache
-        total = int(store["k"].nbytes + store["v"].nbytes)
-        if "k_scale" in store:
-            total += int(store["k_scale"].nbytes + store["v_scale"].nbytes)
+        total = (sum(self._pool_bytes.values()) if self.paged
+                 else pool_bytes(self._cache)["kv"])
         draft = getattr(self, "_draft_pool", None)
         if draft is not None:
             total += int(draft["k"].nbytes + draft["v"].nbytes)
@@ -792,6 +844,12 @@ class ContinuousBatcher:
             "shared_blocks": len(self._block_key),
             "max_blocks_per_slot": self.max_blocks_per_slot,
             "pool_bytes": self.kv_cache_bytes,
+            # The two kinds of bytes (target pool): blocks paged by token,
+            # and recurrent state held by slot (0 for a model without one).
+            "kv_bytes": self._pool_bytes["kv"],
+            "state_bytes": self._pool_bytes["state"],
+            "state_slots_in_use": (
+                sum(m != "free" for m in self._slot_mode) if self._stateful else 0),
             "kv_quant": self.kv_quant,
             "speculative_k": self.speculative_k,
             "draft_pool_bytes": (
@@ -1104,7 +1162,9 @@ class ContinuousBatcher:
         ``"v"`` of (L, B, write_cols, Hkv, D), ``"kv_mask"``, ``"pos"`` 0) whose
         columns follow the view's: what the program writes lands there, at
         one uniform offset for all slots, and ``_scatter_pool`` takes it as
-        it is. The model forward gets ``{**window, "view": view}`` and
+        it is. For a model that holds state by slot the window also carries
+        ``pool["state"]``, every slot's, and the programs write back the rows
+        they own. The model forward gets ``{**window, "view": view}`` and
         returns the advanced window (``Llama._apply_cached``). The frontier
         comparison masks stale bits of reused (freed→reallocated) blocks, so
         the free-list never needs device-side scrubbing."""
@@ -1136,6 +1196,13 @@ class ContinuousBatcher:
         view = {"k": view_k, "v": view_v, "kv_mask": vmask}
         window = {"k": zeros, "v": zeros, "pos": jnp.int32(0),
                   "kv_mask": jnp.zeros((b, write_cols), jnp.int32)}
+        if self._stateful:
+            # State held by slot rides in the window (it is read AND written);
+            # what a model derives from the view once a program (compressed
+            # keys) is made here, before any step reads it.
+            for name in self._layout["by_slot"]:
+                window[name] = pool[name]
+            view = self.module.prepare_view(view)
         return view, window
 
     def _scatter_pool(self, pool, blk, off, k_new, v_new, mask_new):
@@ -1150,6 +1217,7 @@ class ContinuousBatcher:
             qk, sk = quantize_kv(k_new)
             qv, sv = quantize_kv(v_new)
             return {
+                **pool,
                 "k": pool["k"].at[:, blk, off].set(qk),
                 "v": pool["v"].at[:, blk, off].set(qv),
                 "k_scale": pool["k_scale"].at[:, blk, off].set(sk),
@@ -1157,6 +1225,7 @@ class ContinuousBatcher:
                 "mask": pool["mask"].at[blk, off].set(mask_new),
             }
         return {
+            **pool,
             "k": pool["k"].at[:, blk, off].set(k_new),
             "v": pool["v"].at[:, blk, off].set(v_new),
             "mask": pool["mask"].at[blk, off].set(mask_new),
@@ -1179,6 +1248,7 @@ class ContinuousBatcher:
         pad = self.pad
         bs = self.block_size
         spec = bool(self.speculative_k)
+        by_slot, dense_chain = self._layout["by_slot"], self._layout["dense_chain"]
 
         def body(params, pool, state, tables, lens, slot, chunk_row, mask_row,
                  base_pos, is_final, rid, base_rng, req_max, req_temp, req_eos,
@@ -1187,6 +1257,13 @@ class ContinuousBatcher:
              slot_max, slot_temp, slot_eos) = state
             B = tok.shape[0]
             view, window = self._paged_view_cache(pool, tables, lens, P)
+            for name in by_slot:
+                # A request's first chunk starts from a zero state whatever
+                # the slot's last occupant left (no aliasing: an empty chain
+                # IS the first chunk); the other slots' rows ride along masked.
+                held = window[name]
+                window[name] = held.at[:, slot].set(
+                    jnp.where(lens[slot] > 0, held[:, slot], jnp.zeros_like(held[:, slot])))
             ids = jnp.zeros((B, P), jnp.int32).at[slot].set(chunk_row)
             mask = jnp.zeros((B, P), jnp.int32).at[slot].set(mask_row)
             # Token positions continue the slot's REAL-token count (holes
@@ -1196,13 +1273,22 @@ class ContinuousBatcher:
             out = module.apply(params, input_ids=ids, attention_mask=mask,
                                cache={**window, "view": view},
                                positions=mask_positions(mask) + base_pos)
-            idx = lens[slot] + jnp.arange(P)
-            blk = tables[slot][idx // bs]
+            if dense_chain:
+                # Real tokens alone join the chain, in order, so that a key's
+                # column is its token's position; bucket padding goes to the
+                # trash block.
+                idx = lens[slot] + jnp.cumsum(mask_row) - 1
+                blk = jnp.where(mask_row > 0, tables[slot][idx // bs], 0)
+            else:
+                idx = lens[slot] + jnp.arange(P)
+                blk = tables[slot][idx // bs]
             off = idx % bs
             pool = self._scatter_pool(
                 pool, blk, off, out["cache"]["k"][:, slot], out["cache"]["v"][:, slot],
                 jnp.where(blk != 0, mask_row, 0),
             )
+            for name in by_slot:  # the prefilled slot's state alone is written
+                pool[name] = pool[name].at[:, slot].set(out["cache"][name][:, slot])
             if spec:
                 # Speculative mode: the draft model prefills the SAME chunk
                 # into its mirrored pool inside this program, so every
@@ -1317,6 +1403,7 @@ class ContinuousBatcher:
         pad = self.pad
         bs = self.block_size
         w = self.sync_every
+        by_slot, counted = self._layout["by_slot"], self._layout["attended_keys"]
 
         def serve_decode_window(params, pool, tables, lens, commit, force_stop, state):
             (tok, pos, n_out, active, out_buf, keys,
@@ -1333,7 +1420,7 @@ class ContinuousBatcher:
             def one_step(carry, _):
                 # The carry is the window alone; the view is closed over and
                 # constant across the steps.
-                window, state = carry
+                window, state = carry[:2]
                 (tok, pos, n_out, active, out_buf, keys,
                  slot_max, slot_temp, slot_eos) = state
                 col = window["pos"]  # window column this step writes
@@ -1359,9 +1446,18 @@ class ContinuousBatcher:
                 still = active & (nxt != slot_eos) & (n_out < slot_max)
                 state = (nxt, pos + 1, n_out, still, out_buf, keys,
                          slot_max, slot_temp, slot_eos)
+                if counted:
+                    # Keys attended and keys in context, over the rows that
+                    # decode this step (a block-sparse model reports them).
+                    seen = carry[2] + jnp.stack([
+                        jnp.sum(jnp.where(active, out[name], 0.0))
+                        for name in ("attended_keys", "context_keys")])
+                    return (window2, state, seen), None
                 return (window2, state), None
 
-            (window, state), _ = jax.lax.scan(one_step, (window, state), None, length=w)
+            carry = (window, state) + ((jnp.zeros((2,), jnp.float32),) if counted else ())
+            carry, _ = jax.lax.scan(one_step, carry, None, length=w)
+            window, state = carry[:2]
             # Persist the window: committed slots append their written
             # columns (valid or holed — the per-slot chain mirrors the
             # contiguous scheme's unconditional global advance); everything
@@ -1378,7 +1474,13 @@ class ContinuousBatcher:
                 pool, blk, off, window["k"], window["v"],
                 jnp.where(blk != 0, window["kv_mask"], 0),
             )
-            report = jax.lax.optimization_barrier((state[3], state[2], state[4]))
+            for name in by_slot:
+                # Rows that decode keep their stepped state; every other row
+                # (free, or mid-prefill) keeps what it had, bit for bit.
+                own = commit.reshape((1, B) + (1,) * (pool[name].ndim - 2))
+                pool[name] = jnp.where(own, window[name], pool[name])
+            report = jax.lax.optimization_barrier(
+                (state[3], state[2], state[4]) + tuple(carry[2:]))
             return pool, state, report
 
         effective_donate = safe_donate_argnums((1, 6))
@@ -1789,7 +1891,7 @@ class ContinuousBatcher:
                 if row.size > 1:
                     times["tpot"] = (times["finish"] - ft) / (row.size - 1)
                     tpot_hist.observe(max(0.0, times["tpot"]))
-        _, completed, tokens = _serving_counters()
+        _, completed, tokens, _ = _serving_counters()
         completed.inc()
         tokens.inc(int(row.size))
         if self.tracer is not None:
@@ -1830,6 +1932,11 @@ class ContinuousBatcher:
         cross-request prefix sharing as refcounted aliasing. Capped one token
         short of the whole prompt so the final token always runs through a
         prefill chunk (its logits seed the first sampled token)."""
+        if self._stateful:
+            # A resident chain's blocks say nothing of the recurrent state
+            # that went with them: no sharing for a model that carries one.
+            _serving_counters()[3].inc()
+            return []
         bs = self.block_size
         blocks = []
         for k in range(1, (prompt.size - 1) // bs + 1):
@@ -1895,7 +2002,7 @@ class ContinuousBatcher:
         writer wins: a key already mapping to another chain's block leaves
         this chain's copy private."""
         bs = self.block_size
-        if c0 % bs or p % bs:
+        if c0 % bs or p % bs or self._stateful:
             return
         toks = self._slot_tokens[s]
         for j in range(p // bs):
@@ -1935,7 +2042,7 @@ class ContinuousBatcher:
     def _publish_pool_gauges(self):
         if not self.paged:
             return
-        _, _, free_gauge, util_gauge = _slo_metrics()
+        _, _, free_gauge, util_gauge, _ = _slo_metrics()
         free_gauge.set(float(len(self._free_blocks)))
         util_gauge.set(1.0 - len(self._free_blocks) / max(1, self.num_blocks))
 
@@ -2094,7 +2201,9 @@ class ContinuousBatcher:
             self.tracer.prefill_chunk(req.rid, p, final)
         if not final:
             self._register_shared(s, c0, p)
-        self._slot_len[s] += p
+        # A dense chain took the real tokens alone (its frontier is the
+        # token count); otherwise the bucket's padding holds columns too.
+        self._slot_len[s] += int(chunk.size) if self._layout["dense_chain"] else p
         self._slot_base[s] += int(chunk.size)
         if final:
             self._slot_mode[s] = "decode"
@@ -2174,9 +2283,14 @@ class ContinuousBatcher:
         ``serve.report_wait`` is that first read alone: how long the host
         was blocked on the device (its end is the moment the window's report
         became ready, where the host waited at all)."""
-        report, req_map = report
+        report, req_map, dispatched = report
         with self._span("serve.report_wait"):
             active_np = host_fetch(report[0]).copy()
+        if len(report) > 3:
+            # What the window attended, on the span that dispatched it (the
+            # ring holds the record itself): read here, with the report.
+            seen = host_fetch(report[3])
+            dispatched.attrs.update(attended_keys=float(seen[0]), context_keys=float(seen[1]))
         with self._span("serve.process_report") as rec:
             rec.attrs["tokens"], rec.attrs["finished"] = self._consume_report(
                 report, req_map, active_np, force_stop)
@@ -2262,7 +2376,8 @@ class ContinuousBatcher:
                 if chunk_slot is not None:
                     chunk_p, tokens, final = self._next_chunk(chunk_slot)
                     with self._span("serve.dispatch_chunk", rid=self._slot_req[chunk_slot].rid,
-                                    p=chunk_p, tokens=tokens, final=final):
+                                    p=chunk_p, tokens=tokens, final=final,
+                                    rows_computed=self.B * chunk_p):
                         state = self._dispatch_chunk(chunk_slot, state)
                 decoding = sum(m == "decode" for m in self._slot_mode)
                 turn.attrs.update(
@@ -2272,8 +2387,9 @@ class ContinuousBatcher:
                 new_pending = None
                 if decoding:
                     with self._span("serve.dispatch_decode", decoding=decoding,
-                                    slots=self.B, window=window):
+                                    slots=self.B, window=window) as rec:
                         state, new_pending = self._dispatch_decode(state, force_stop)
+                    new_pending += (rec,)
                     force_stop[:] = False
                     t = time.monotonic()
                     if last_dispatch_t is not None:

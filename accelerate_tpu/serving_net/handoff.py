@@ -171,6 +171,11 @@ def export_chain(engine, rid: int, endpoint: str | None = None,
     ``handed_off``."""
     if not engine.paged:
         raise ValueError("chain export requires a paged engine")
+    if engine._stateful:
+        raise ValueError(
+            f"chain export ships a request's key-value blocks; "
+            f"{type(engine.module).__name__} also carries recurrent state held by slot, "
+            "which the handoff payload does not hold: serve it on one tier (role 'unified')")
     s = next(
         (s for s in range(engine.B)
          if engine._slot_req[s] is not None and engine._slot_req[s].rid == rid),
