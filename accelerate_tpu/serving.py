@@ -86,9 +86,11 @@ HBM is consumed per *chain*, not per ``B x max_cache_len`` rectangle:
 - **The decode/chunk/verify programs** gather each slot's chain once into a
   contiguous READ-ONLY view and give the model forward a two-part cache:
   that view plus an empty write window the size of what the program writes
-  (``_paged_view_cache``). Every layer attends both parts under one softmax
-  and writes the window alone (``ops/attention.py`` ``cached_attention(prefix=
-  ...)``); the decode window's scan carries the window, never the view, and
+  (``_paged_view_cache``). A chunk program belongs to one slot and runs at
+  batch 1: that slot's chain, state and row, no other. Every layer attends
+  both parts under one softmax and writes the window alone
+  (``ops/attention.py`` ``cached_attention(prefix=...)``); the decode
+  window's scan carries the window, never the view, and
   the window is scattered onto chain tails as it is — nothing the size of
   the view is copied per step. The engine loop runs one window AHEAD of its sync:
   each window's (active, n_out, out_buf) report is read only after the next
@@ -1155,16 +1157,20 @@ class ContinuousBatcher:
     def _paged_view_cache(self, pool, tables, lens, write_cols: int):
         """The two-part cache of a paged program, as ``(view, window)``.
 
-        ``view`` (``{"k", "v"}`` of (L, B, T, Hkv, D) and a ``"kv_mask"`` of
-        (B, T)) is every slot's block chain gathered once, and is read-only:
-        a program closes over it, every layer attends it, nothing writes it.
-        ``window`` is an ordinary empty cache ``write_cols`` wide (``"k"``,
-        ``"v"`` of (L, B, write_cols, Hkv, D), ``"kv_mask"``, ``"pos"`` 0) whose
-        columns follow the view's: what the program writes lands there, at
-        one uniform offset for all slots, and ``_scatter_pool`` takes it as
-        it is. For a model that holds state by slot the window also carries
-        ``pool["state"]``, every slot's, and the programs write back the rows
-        they own. The model forward gets ``{**window, "view": view}`` and
+        ``B`` is the number of rows of ``tables`` and ``lens``: every slot
+        for the decode window and the verify round, the one slot that
+        prefills for a chunk program. ``view`` (``{"k", "v"}`` of
+        (L, B, T, Hkv, D) and a ``"kv_mask"`` of (B, T)) is those rows' block
+        chains gathered once, and is read-only: a program closes over it,
+        every layer attends it, nothing writes it. ``window`` is an ordinary
+        empty cache ``write_cols`` wide (``"k"``, ``"v"`` of
+        (L, B, write_cols, Hkv, D), ``"kv_mask"``, ``"pos"`` 0) whose columns
+        follow the view's: what the program writes lands there, at one
+        uniform offset for all rows, and ``_scatter_pool`` takes it as it
+        is. For a model that holds state by slot the window also carries
+        ``pool["state"]`` as handed in (the caller gives the state of the
+        same rows as ``tables``), and the programs write back the rows they
+        own. The model forward gets ``{**window, "view": view}`` and
         returns the advanced window (``Llama._apply_cached``). The frontier
         comparison masks stale bits of reused (freed→reallocated) blocks, so
         the free-list never needs device-side scrubbing."""
@@ -1233,14 +1239,16 @@ class ContinuousBatcher:
 
     def _chunk_fn(self, P: int):
         """Compiled prefill of ONE ``P``-token chunk of one slot's prompt
-        against the paged pool: gather the slot chains, run the whole (B, P)
-        chunk (shapes stay request-independent — rows other than the target
-        slot ride along masked), scatter the target slot's written columns
-        onto its chain tail, and on the FINAL chunk sample the request's
-        first token and arm the slot for decode. One program per chunk
-        bucket, shared by mid-prompt and final chunks (``is_final`` is a
-        traced scalar; the state writes are harmless for mid chunks — the
-        slot stays inactive and the final chunk rewrites them)."""
+        against the paged pool, at batch 1: gather the target slot's chain
+        alone (``slot`` is a traced scalar, so shapes stay
+        request-independent), run the (1, P) chunk against that one-row
+        view, scatter the written columns onto the slot's chain tail, and on
+        the FINAL chunk sample the request's first token and arm the slot
+        for decode. No other slot's chain or state is read or computed. One
+        program per chunk bucket, shared by mid-prompt and final chunks
+        (``is_final`` is a traced scalar; the state writes are harmless for
+        mid chunks — the slot stays inactive and the final chunk rewrites
+        them)."""
         if P in self._chunk_fns:
             return self._chunk_fns[P]
         module = self.module
@@ -1255,17 +1263,17 @@ class ContinuousBatcher:
                  d_params=None, d_pool=None):
             (tok, pos, n_out, active, out_buf, keys,
              slot_max, slot_temp, slot_eos) = state
-            B = tok.shape[0]
-            view, window = self._paged_view_cache(pool, tables, lens, P)
-            for name in by_slot:
-                # A request's first chunk starts from a zero state whatever
-                # the slot's last occupant left (no aliasing: an empty chain
-                # IS the first chunk); the other slots' rows ride along masked.
-                held = window[name]
-                window[name] = held.at[:, slot].set(
-                    jnp.where(lens[slot] > 0, held[:, slot], jnp.zeros_like(held[:, slot])))
-            ids = jnp.zeros((B, P), jnp.int32).at[slot].set(chunk_row)
-            mask = jnp.zeros((B, P), jnp.int32).at[slot].set(mask_row)
+            # The slot's own row of the host's tables and lengths: the view,
+            # the write window and the forward below are all batch 1.
+            table, length = tables[slot], lens[slot]
+            # A request's first chunk starts from a zero state whatever the
+            # slot's last occupant left (no aliasing: an empty chain IS the
+            # first chunk).
+            own = {name: jnp.where(length > 0, pool[name][:, slot], 0)[:, None]
+                   for name in by_slot}
+            view, window = self._paged_view_cache(
+                {**pool, **own}, table[None], length[None], P)
+            ids, mask = chunk_row[None], mask_row[None]
             # Token positions continue the slot's REAL-token count (holes
             # from bucket padding never shift positions), so rope/wpe are
             # exact across chunk boundaries and identical to a monolithic
@@ -1277,32 +1285,33 @@ class ContinuousBatcher:
                 # Real tokens alone join the chain, in order, so that a key's
                 # column is its token's position; bucket padding goes to the
                 # trash block.
-                idx = lens[slot] + jnp.cumsum(mask_row) - 1
-                blk = jnp.where(mask_row > 0, tables[slot][idx // bs], 0)
+                idx = length + jnp.cumsum(mask_row) - 1
+                blk = jnp.where(mask_row > 0, table[idx // bs], 0)
             else:
-                idx = lens[slot] + jnp.arange(P)
-                blk = tables[slot][idx // bs]
+                idx = length + jnp.arange(P)
+                blk = table[idx // bs]
             off = idx % bs
             pool = self._scatter_pool(
-                pool, blk, off, out["cache"]["k"][:, slot], out["cache"]["v"][:, slot],
+                pool, blk, off, out["cache"]["k"][:, 0], out["cache"]["v"][:, 0],
                 jnp.where(blk != 0, mask_row, 0),
             )
             for name in by_slot:  # the prefilled slot's state alone is written
-                pool[name] = pool[name].at[:, slot].set(out["cache"][name][:, slot])
+                pool[name] = pool[name].at[:, slot].set(out["cache"][name][:, 0])
             if spec:
                 # Speculative mode: the draft model prefills the SAME chunk
                 # into its mirrored pool inside this program, so every
                 # resident chain (including aliased shared-prefix blocks,
                 # which are written exactly once, here) carries draft KV by
                 # the time the first verify round needs it.
-                d_view, d_window = self._paged_view_cache(d_pool, tables, lens, P)
+                d_view, d_window = self._paged_view_cache(
+                    d_pool, table[None], length[None], P)
                 d_out = d_module.apply(
                     d_params, input_ids=ids, attention_mask=mask,
                     cache={**d_window, "view": d_view},
                     positions=mask_positions(mask) + base_pos)
                 d_pool = self._scatter_pool(
                     d_pool, blk, off,
-                    d_out["cache"]["k"][:, slot], d_out["cache"]["v"][:, slot],
+                    d_out["cache"]["k"][:, 0], d_out["cache"]["v"][:, 0],
                     jnp.where(blk != 0, mask_row, 0),
                 )
             real = jnp.sum(mask_row).astype(jnp.int32)
@@ -1312,7 +1321,7 @@ class ContinuousBatcher:
             slot_temp = slot_temp.at[slot].set(req_temp)
             slot_eos = slot_eos.at[slot].set(req_eos)
             first = self._sample_rows(
-                out["logits"][slot, -1][None], key[None],
+                out["logits"][0, -1][None], key[None],
                 jnp.zeros((1,), jnp.int32), req_temp[None],
             )[0]
             tok = tok.at[slot].set(first)
@@ -2377,7 +2386,7 @@ class ContinuousBatcher:
                     chunk_p, tokens, final = self._next_chunk(chunk_slot)
                     with self._span("serve.dispatch_chunk", rid=self._slot_req[chunk_slot].rid,
                                     p=chunk_p, tokens=tokens, final=final,
-                                    rows_computed=self.B * chunk_p):
+                                    rows_computed=chunk_p):
                         state = self._dispatch_chunk(chunk_slot, state)
                 decoding = sum(m == "decode" for m in self._slot_mode)
                 turn.attrs.update(

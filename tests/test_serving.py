@@ -6,7 +6,9 @@ independent — and sampled outputs depend only on (engine rng, request id),
 not on traffic or slot assignment. Exceeds the reference, which serves whole
 batches through ``model.generate`` with head-of-line blocking."""
 
+import collections
 import re
+import time
 
 import numpy as np
 import pytest
@@ -17,6 +19,7 @@ import jax.numpy as jnp
 from accelerate_tpu.generation import generate
 from accelerate_tpu.models import GPT2, GPT2Config, Llama, LlamaConfig
 from accelerate_tpu.serving import ContinuousBatcher, SLOTargets
+from accelerate_tpu.telemetry import get_span_ring, reset_spans
 
 
 @pytest.fixture(scope="module")
@@ -941,9 +944,10 @@ def test_paged_programs_never_rewrite_the_gathered_view(llama, program):
         fn, args, wide = engine._decode(), engine._decode_args(), 3
     layers, _, bs, hkv, d = engine._pool["k"].shape
     t = engine.max_blocks_per_slot * bs
-    per_layer = {(engine.B, cols, hkv, d) for cols in (t, t + wide)}
+    rows = 1 if program == "prefill_chunk" else engine.B  # a chunk gathers its own slot alone
+    per_layer = {(rows, cols, hkv, d) for cols in (t, t + wide)}
     view_shapes = per_layer | {(layers,) + shape for shape in per_layer}
-    view_size = layers * engine.B * t * hkv * d
+    view_size = layers * rows * t * hkv * d
 
     rewrites = {"concatenate", "dynamic_update_slice", "copy", "pad", "scatter"}
     scans = 0
@@ -963,3 +967,129 @@ def test_paged_programs_never_rewrite_the_gathered_view(llama, program):
         op = re.search(r"= \S+ (copy|concatenate|dynamic-update-slice|pad)\(", line)
         if op:
             assert not re.search(pattern, line), line.strip()[:200]
+
+
+# ------------------------------------------------ the chunk program at batch 1
+CHUNKED = dict(batch_slots=3, max_new_tokens=6, bucket_sizes=(8,), prefill_chunk=8,
+               max_tokens_per_request=64)
+
+
+def _chunked_case(name):
+    """(model, paged-engine overrides) of a family that serves paged."""
+    tiny = dict(num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
+    if name == "gpt2":
+        return _two_part_case("gpt2")[0], dict(max_cache_len=192)
+    model = Llama(LlamaConfig.tiny(qk_norm=name == "qk_norm", **tiny))
+    model.init_params(jax.random.key(0))
+    if name == "kv_quant":
+        return model, dict(kv_quant="int8")
+    if name == "speculative":
+        draft = Llama(LlamaConfig.tiny(**tiny))
+        draft.init_params(jax.random.key(7))  # other weights: it mispredicts
+        return model, dict(speculative_k=2, draft_model=draft)
+    return model, {}
+
+
+@pytest.mark.parametrize("case", ["llama", "qk_norm", "gpt2", "kv_quant", "speculative"])
+def test_chunked_prefill_beside_occupied_slots_serves_each_request_s_solo_tokens(case):
+    """The chunk program computes its own slot's row and no other: four
+    requests of unequal prompt length (1 to 4 chunks of 8) over three slots,
+    so that chunks run while other slots prefill and decode, and every
+    request's tokens are those of its solo generation. An int8 pool is lossy
+    against ``generate()``'s float cache, so its solo is the same engine
+    serving the request alone."""
+    model, overrides = _chunked_case(case)
+    rng = np.random.default_rng(411)
+    prompts = [rng.integers(1, 120, (n,)).astype(np.int32) for n in (21, 5, 30, 13)]
+    engine = _paged(model, **CHUNKED, **overrides)
+    reset_spans()
+    served = _serve(engine, prompts, None)
+    turns = [r.attrs for r in get_span_ring().snapshot() if r.name == "serve.iteration"]
+    chunks = [e for e in engine._dispatch_log if e.startswith("chunk:")]
+    assert chunks == ["chunk:8"] * 10  # 3 + 1 + 4 + 2
+    assert sum(t["chunk"] > 0 and t["decoding"] + t["prefilling"] >= 2 for t in turns) >= 6
+    assert any(t["chunk"] > 0 and t["decoding"] >= 1 for t in turns)
+    for prompt, got in zip(prompts, served):
+        if case == "kv_quant":
+            (want,) = _serve(_paged(model, **CHUNKED, **overrides), [prompt], None)
+        else:
+            want = _solo(model, prompt, 6)
+        np.testing.assert_array_equal(got, want)
+    stats = engine.pool_stats()
+    assert stats["blocks_free"] == stats["num_blocks"]
+
+
+@pytest.mark.parametrize("pool_kind", ["float", "int8"])
+def test_a_chunk_dispatch_writes_its_own_slot_s_chain_tail_and_nothing_else(llama, pool_kind):
+    """One chunk dispatch changes the ``p`` columns at the target slot's chain
+    tail: every other pool row (other slots' chains, free blocks, the trash
+    block, the chain's earlier columns), their masks and scales, and every
+    other slot's row of the decode state stay bit for bit as they were — over
+    a pool filled with noise, for a first chunk (an empty view) and for a
+    later one (its own chain in the view)."""
+    quant = dict(kv_quant="int8") if pool_kind == "int8" else {}
+    engine = _paged(llama, **CHUNKED, **quant)
+    rng = np.random.default_rng(97)
+    for n in (21, 13, 30):
+        engine.submit(rng.integers(1, 256, (n,)).astype(np.int32))
+    engine._admit_paged(time.monotonic())
+    assert engine._slot_mode == ["prefill"] * 3
+    noise = {}
+    for name, held in engine._pool.items():
+        if name == "mask":
+            noise[name] = jnp.asarray(rng.integers(0, 2, held.shape), held.dtype)
+        elif jnp.issubdtype(held.dtype, jnp.integer):
+            noise[name] = jnp.asarray(rng.integers(-127, 128, held.shape), held.dtype)
+        else:
+            noise[name] = jnp.asarray(rng.standard_normal(held.shape), held.dtype)
+    engine._pool = noise
+    state = engine._state_tuple()
+    bs = engine.block_size
+    for slot in (1, 1, 0):
+        before = {k: np.asarray(v) for k, v in engine._pool.items()}
+        state_before = [np.asarray(leaf) for leaf in state[:5]]
+        start = int(engine._slot_len[slot])
+        p, tokens, _ = engine._next_chunk(slot)
+        state = engine._dispatch_chunk(slot, state)
+        after = {k: np.asarray(v) for k, v in engine._pool.items()}
+        written = np.zeros(before["mask"].shape, bool)
+        for col in range(start, start + p):
+            written[engine._slot_blocks[slot][col // bs], col % bs] = True
+        assert p == 8 and written.sum() == p and int(engine._slot_len[slot]) == start + p
+        assert after["mask"][written].sum() == tokens  # a bucket's padding stays a hole
+        np.testing.assert_array_equal(after["mask"][~written], before["mask"][~written])
+        for name in set(before) - {"mask"}:  # (L, blocks, block_size, ...)
+            assert not np.array_equal(after[name][:, written], before[name][:, written])
+            np.testing.assert_array_equal(after[name][:, ~written], before[name][:, ~written])
+        others = [s for s in range(engine.B) if s != slot]
+        for was, now in zip(state_before, state[:5]):
+            np.testing.assert_array_equal(np.asarray(now)[others], was[others])
+
+
+@pytest.mark.parametrize("mode", ["plain", "speculative"])
+def test_the_chunk_program_hands_the_model_one_row(llama, mode):
+    """In the lowered ``serve_prefill_chunk_<p>`` the model sees ``input_ids``
+    of ``(1, P)``: no array of shape ``(batch_slots, P, ...)`` and no view with
+    a leading ``batch_slots`` exists anywhere in the program, for the target
+    and under ``speculative_k`` for the draft alike; the one-row forms do."""
+    spec = dict(speculative_k=2, draft_model=llama) if mode == "speculative" else {}
+    engine = _paged(llama, batch_slots=3, max_new_tokens=6, bucket_sizes=(16,), **spec)
+    slots, p = engine.B, 16
+    fn, args = engine._chunk_fn(p), engine._chunk_args(p)
+    assert fn.lower(*args).as_text().startswith(f"module @jit_serve_prefill_chunk_{p}")
+    layers, _, bs, hkv, d = engine._pool["k"].shape
+    t = engine.max_blocks_per_slot * bs
+    hidden = llama.config.hidden_size
+    assert slots > 1 and p not in (engine.max_new, engine.max_blocks_per_slot)
+    seen = collections.Counter()
+    for eqn in _walk_eqns(fn._audit_meta["jaxpr_thunk"](*args).jaxpr):
+        for v in list(eqn.invars) + list(eqn.outvars):
+            shape = tuple(getattr(v.aval, "shape", ()))
+            assert shape[:2] != (slots, p), (eqn.primitive.name, shape)
+            assert shape[:2] != (layers, slots) or len(shape) < 5, (eqn.primitive.name, shape)
+            seen[shape] += 1
+    copies = 2 if mode == "speculative" else 1
+    assert seen[(1, p, hidden)] >= copies                # the residual stream, one row
+    assert seen[(layers, 1, t, hkv, d)] >= 2 * copies    # the view's K and V
+    assert seen[(layers, 1, p, hkv, d)] >= 2 * copies    # the write window's
+    assert seen[(1, t)] >= copies and not seen[(slots, t)]  # the view's mask
