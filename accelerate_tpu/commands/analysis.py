@@ -387,7 +387,7 @@ def _build_serving_artifact(slots: int, blocks: int, block_size: int,
     engine = ContinuousBatcher(
         model, batch_slots=slots, max_new_tokens=32,
         max_cache_len=blocks * block_size, bucket_sizes=(16, 32, 64),
-        sync_every=4, paged=True, block_size=block_size, num_blocks=blocks,
+        sync_every=4, block_size=block_size, num_blocks=blocks,
         kv_quant=kv_quant, speculative_k=speculative_k,
     )
     if role == "prefill":

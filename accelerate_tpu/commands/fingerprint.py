@@ -64,9 +64,10 @@ _TRAIN_CONFIGS = {
 _KERNEL_CONFIGS = ("step_zero_kernel", "decode_paged_kernel",
                    "decode_paged_int8", "spec_verify")
 
-CONFIG_NAMES = tuple(_TRAIN_CONFIGS) + ("decode", "decode_paged",
-                                        "decode_paged_kernel", "prefill_paged",
-                                        "decode_paged_int8", "spec_verify")
+_SERVING_CONFIGS = ("decode_paged", "decode_paged_kernel", "prefill_paged",
+                    "decode_paged_int8", "spec_verify")
+
+CONFIG_NAMES = tuple(_TRAIN_CONFIGS) + _SERVING_CONFIGS
 
 
 def _reset_singletons():
@@ -126,7 +127,7 @@ def _train_fingerprint(name: str):
         _reset_singletons()
 
 
-def _decode_fingerprint(name: str = "decode"):
+def _decode_fingerprint(name: str = "decode_paged"):
     import jax
 
     from ..models import Llama, LlamaConfig
@@ -139,16 +140,13 @@ def _decode_fingerprint(name: str = "decode"):
     )
     model = Llama(cfg)
     model.init_params(jax.random.key(0))
-    kwargs = {}
-    if name in ("decode_paged", "decode_paged_kernel", "prefill_paged",
-                "decode_paged_int8", "spec_verify"):
-        # The paged decode window: its committed golden pins the block-table
-        # gather inventory and the pool+state donation contract, so the
-        # ROADMAP item 3 kernel swap (or any regression in the gather
-        # lowering) classifies as deliberate drift, not silence. The
-        # `_kernel` variant runs the Pallas chain-walk assembly
-        # (op `paged_gather`) and pins its pallas_call inventory instead.
-        kwargs = dict(paged=True, block_size=4)
+    # The paged decode window: its committed golden pins the block-table
+    # gather inventory and the pool+state donation contract, so the
+    # ROADMAP item 3 kernel swap (or any regression in the gather
+    # lowering) classifies as deliberate drift, not silence. The
+    # `_kernel` variant runs the Pallas chain-walk assembly
+    # (op `paged_gather`) and pins its pallas_call inventory instead.
+    kwargs = dict(block_size=4)
     if name == "decode_paged_int8":
         # int8 KV pool: the golden pins the dequant-in-DMA gather kernel
         # (`paged_gather_dequant_kernel`) plus the per-block scale plumbing.
@@ -192,8 +190,7 @@ def extract_config(name: str):
     else:
         os.environ.pop(ENV_KERNELS, None)
     try:
-        if name in ("decode", "decode_paged", "decode_paged_kernel",
-                    "prefill_paged", "decode_paged_int8", "spec_verify"):
+        if name in _SERVING_CONFIGS:
             return _decode_fingerprint(name)
         if name not in _TRAIN_CONFIGS:
             raise SystemExit(
@@ -321,9 +318,6 @@ def fingerprint_command(args) -> None:
 
     if args.list_configs:
         for name in CONFIG_NAMES:
-            if name == "decode":
-                print(f"{name}: ContinuousBatcher sync_every-token decode window")
-                continue
             if name == "decode_paged":
                 print(f"{name}: paged ContinuousBatcher decode window "
                       "(block-table gather + pool scatter)")

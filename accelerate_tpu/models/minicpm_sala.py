@@ -449,8 +449,8 @@ class MiniCPMSALA(Module):
         view = cache.get("view")
         if view is None:
             raise NotImplementedError(
-                "MiniCPMSALA serves through the paged engine's two-part cache "
-                "(ContinuousBatcher(paged=True)); a plain contiguous cache is not implemented")
+                "MiniCPMSALA serves through the engine's two-part cache "
+                "(ContinuousBatcher); a plain one-part cache is not implemented")
         if "kbar" not in view:
             view = self.prepare_view(view)
         b, s = input_ids.shape

@@ -1,6 +1,6 @@
 """Paged (block-table) KV-cache attention — reference lowering + pool helpers.
 
-The serving engine's paged mode (``serving.ContinuousBatcher(paged=True)``)
+The serving engine (``serving.ContinuousBatcher``)
 keeps each layer's KV cache as a **block pool**: a device-resident
 ``(num_blocks, block_size, kv_heads, head_dim)`` array per layer plus per-slot
 **block tables** mapping a request's logical token chain onto pool blocks

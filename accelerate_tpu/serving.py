@@ -1,4 +1,4 @@
-"""Continuous batching — a slot-based serving engine over the KV-cache path.
+"""Continuous batching — a slot-based serving engine over a paged KV cache.
 
 The reference serves through transformers' ``generate`` one batch at a time:
 a batch runs until its LAST row finishes, so short requests pay for long ones
@@ -6,74 +6,36 @@ a batch runs until its LAST row finishes, so short requests pay for long ones
 decoding together and refills a slot the moment its sequence finishes — the
 scheduling idea of vLLM/Orca, shaped for XLA's static-compilation model:
 
-- **One decode program plus one admit program per prompt-length bucket**:
-  the decode step covers all B slots at once, and an admit prefills one
-  slot's prompt while the others' state rides along untouched. No shape ever
-  depends on which requests are in flight, so nothing recompiles as traffic
-  changes.
-- **One global write offset, per-slot validity** — the same trick as batched
-  speculative decoding (``generation._assisted_generate_batched``): every
-  cache write lands at the global offset for ALL slots and rows that didn't
-  really produce a token simply mask the slot out of their ``kv_mask``.
-  Attention needs only slot-causality + validity, both hole-tolerant; rope
-  positions ride the separate per-row ``positions`` channel, so absolute- and
-  rotary-position models are exact.
-- The cost of that simplicity is cache capacity: slots consume global cache
-  columns even while other rows hole them out. ``compact()`` reclaims the
-  holes — a stable full-cache gather pulls each row's valid columns to the
-  front, drops retired requests' columns, and rewinds the write offset —
-  and runs automatically at the backpressure point, so ``max_cache_len``
-  sizes to the working set of concurrently LIVE tokens, not the whole
-  queue. A genuinely-too-small cache still raises an actionable error
-  instead of corrupting state.
-
-**Prefix caching** (``set_prefix``): a prompt prefix shared by every request
-(system prompt, few-shot block, a long document) is prefilled ONCE into the
-head of the cache and stays valid for all slots across evictions — requests
-then submit only their suffixes. Prefill compute and cache columns for the
-prefix are paid once per wave instead of once per request.
-
-**Per-request generation controls** (``submit`` kwargs): each request may
-carry its own ``max_new_tokens``, ``temperature``, ``eos_token_id``, and
-``stop_sequences``, heterogeneously within one wave. Per-slot scalars ride the
-engine state through the same compiled programs — nothing recompiles as the
-mix changes. Length/temperature/eos act on-device per slot; multi-token stop
-sequences are detected host-side at the sync cadence (the slot frees at most
-``sync_every - 1`` steps late) and the OUTPUT is truncated exactly at the
-first stop occurrence, so results never depend on cadence.
-
-Correctness contract (pinned by tests/test_serving.py): in greedy mode each
-request's output is EXACTLY ``generate(model, prompt, temperature=0)`` for
-that prompt alone (with a prefix set: for ``prefix + suffix``), regardless of
-how requests interleave. In sampling mode
-each request draws from its own stream — ``fold_in(engine_rng, request_id)``
-folded again by step index — so a request's sampled tokens depend only on
-(engine rng, request id), not on traffic or slot assignment; they are
-reproducible but not bit-equal to a solo ``generate()`` (whose split chain
-differs).
-
-Sliding-window models serve exactly: ``cached_attention`` measures windows in
-VALID-slot distance, so the slot scheme's masked holes don't stretch the
-window (ops/attention.py — on the contiguous solo cache the two distances
-coincide, which is what makes engine output == solo output).
-
-**Paged KV mode** (``paged=True`` — the production deployment shape,
-docs/serving.md): the contiguous per-slot cache is replaced by a block pool
-(ops/paged_attention.py) — ``num_blocks`` blocks of ``block_size`` token
-slots shared by every slot through per-slot block tables of static
-``max_blocks_per_slot`` width, so every program stays compiled-once while
-HBM is consumed per *chain*, not per ``B x max_cache_len`` rectangle:
-
+- **One decode program plus one prefill program per chunk bucket**: the
+  decode window covers all B slots at once, and a chunk program prefills one
+  slot's prompt chunk at batch 1. No shape ever depends on which requests
+  are in flight, so nothing recompiles as traffic changes.
+- **A block pool** (ops/paged_attention.py): ``num_blocks`` blocks of
+  ``block_size`` token slots shared by every slot through per-slot block
+  tables of static ``max_blocks_per_slot`` width, so HBM is consumed per
+  *chain* and ``max_cache_len`` (the pool's token capacity) sizes to the
+  working set of concurrently LIVE tokens, not the whole queue. A
+  genuinely-too-small pool raises an actionable error instead of corrupting
+  state.
+- **Per-row validity**: rows that didn't really produce a token mask the
+  written slot out of their ``kv_mask``. Attention needs only
+  slot-causality + validity, both hole-tolerant; rope positions ride the
+  separate per-row ``positions`` channel, so absolute- and rotary-position
+  models are exact.
 - **Allocation is host free-list surgery**: a request reserves its whole
   worst-case chain at admission (the only capacity decision point), and a
-  retired request's chain frees at collect — compaction without a device
-  permutation. Stale bits of reused blocks are masked by a chain-frontier
-  comparison, so the free list never needs device-side scrubbing.
-- **Cross-request prefix sharing** generalizes ``set_prefix``: hole-free
-  full blocks are indexed by their chain-prefix tokens and any request whose
-  prompt starts with an indexed chain ALIASES those blocks (refcounted) —
-  K/V are pure functions of (params, token prefix) because rope/wpe ride the
-  position channel, which is exactly what makes the bits shareable.
+  retired request's chain frees when its report is read — no device
+  permutation ever runs. Stale bits of reused blocks are masked by a
+  chain-frontier comparison, so the free list never needs device-side
+  scrubbing.
+- **Cross-request prefix sharing**: hole-free full blocks are indexed by
+  their chain-prefix tokens and any request whose prompt starts with an
+  indexed chain ALIASES those blocks (refcounted) — K/V are pure functions
+  of (params, token prefix) because rope/wpe ride the position channel,
+  which is exactly what makes the bits shareable. ``set_prefix`` stores a
+  prompt prefix shared by every request (system prompt, few-shot block, a
+  long document): requests then submit only their suffixes, the first one
+  prefills the prefix's blocks and the later ones alias them.
 - **Chunked prefill** interleaves with decode: ``submit()`` splits prompts
   into ``prefill_chunk``-token chunks and each engine iteration dispatches at
   most ONE chunk between decode windows, bounding per-step decode stall by a
@@ -97,9 +59,29 @@ HBM is consumed per *chain*, not per ``B x max_cache_len`` rectangle:
   window is dispatched, so the steady-state loop performs zero blocking
   transfers (pinned by tests).
 
-The greedy correctness contract is unchanged and mode-independent: paged
-outputs are bit-identical to the contiguous engine and to per-request
-``generate()``.
+**Per-request generation controls** (``submit`` kwargs): each request may
+carry its own ``max_new_tokens``, ``temperature``, ``eos_token_id``, and
+``stop_sequences``, heterogeneously within one wave. Per-slot scalars ride the
+engine state through the same compiled programs — nothing recompiles as the
+mix changes. Length/temperature/eos act on-device per slot; multi-token stop
+sequences are detected host-side at the sync cadence (the slot frees at most
+``sync_every - 1`` steps late) and the OUTPUT is truncated exactly at the
+first stop occurrence, so results never depend on cadence.
+
+Correctness contract (pinned by tests/test_serving.py): in greedy mode each
+request's output is EXACTLY ``generate(model, prompt, temperature=0)`` for
+that prompt alone (with a prefix set: for ``prefix + suffix``), regardless of
+how requests interleave. In sampling mode
+each request draws from its own stream — ``fold_in(engine_rng, request_id)``
+folded again by step index — so a request's sampled tokens depend only on
+(engine rng, request id), not on traffic or slot assignment; they are
+reproducible but not bit-equal to a solo ``generate()`` (whose split chain
+differs).
+
+Sliding-window models serve exactly: ``cached_attention`` measures windows in
+VALID-slot distance, so masked holes don't stretch the window
+(ops/attention.py — on the solo cache of ``generate()`` the two distances
+coincide, which is what makes engine output == solo output).
 """
 
 from __future__ import annotations
@@ -282,9 +264,12 @@ class ContinuousBatcher:
         outputs = engine.run()                           # {rid: np.ndarray}
 
     ``run()`` drives admits + decode steps until every submitted request has
-    finished; ``submit`` may be called again afterwards (slots and the cache
-    are re-usable until ``max_cache_len`` is exhausted; ``reset()`` reclaims
-    everything).
+    finished; ``submit`` may be called again afterwards, wave after wave: a
+    finished request's blocks return to the free list as its report is read.
+    ``max_cache_len`` is the pool's token capacity (``num_blocks`` defaults
+    to ``max_cache_len // block_size``); ``reset()`` drops every resident
+    block. ``paged`` selects nothing: ``True`` is accepted because the
+    benchmark's configuration files still pass it, ``False`` is refused.
     """
 
     def __init__(
@@ -304,7 +289,7 @@ class ContinuousBatcher:
         cache_dtype=jnp.bfloat16,
         bucket_sizes: tuple = (16, 32, 64, 128, 256, 512, 1024),
         sync_every: int = 8,
-        paged: bool = False,
+        paged: bool = True,
         block_size: int = 16,
         num_blocks: int | None = None,
         prefill_chunk: int | None = None,
@@ -317,6 +302,10 @@ class ContinuousBatcher:
         matmul_precision: str | None = None,
         trace_requests: bool = True,
     ):
+        if paged is not True:
+            raise ValueError(
+                "paged=False: the contiguous engine is gone; ContinuousBatcher serves "
+                "through the paged KV pool alone (drop the argument)")
         module, mparams = _unwrap(model)
         # Weight-quantized serving (opt-in dtype policy): swap the model's
         # matmul primitive for the kernel-backed int8 path (ops/int8.py) via a
@@ -379,26 +368,19 @@ class ContinuousBatcher:
         # down and speculative decoding is refused for such a model.
         self._layout = cache_layout(module)
         self._stateful = bool(self._layout["by_slot"])
-        if self._stateful and not paged:
-            raise ValueError(
-                f"{type(module).__name__} carries recurrent state held by slot "
-                f"({', '.join(self._layout['by_slot'])}): it serves through the paged "
-                "engine only; pass paged=True")
         if self._stateful and self.speculative_k:
             raise ValueError(
                 f"speculative decoding rolls a rejected draft back by block-table "
                 f"truncation, which cannot roll back the recurrent state "
                 f"{type(module).__name__} holds by slot; run it with speculative_k=0")
-        # ---------------------------------------------------- paged KV mode
-        # paged=True swaps the contiguous (B, max_cache_len) cache for a
-        # block pool (ops/paged_attention.py): `num_blocks` blocks of
+        # ----------------------------------------------------- the KV pool
+        # A block pool (ops/paged_attention.py): `num_blocks` blocks of
         # `block_size` token slots shared by all slots via per-slot block
         # tables (static max_blocks_per_slot, so every program stays
-        # compiled-once). `max_cache_len` is reinterpreted as the POOL's
-        # total token capacity (num_blocks defaults to max_cache_len //
-        # block_size); `prefill_chunk` bounds each prefill dispatch so long
-        # prompts interleave with decode instead of stalling it.
-        self.paged = bool(paged)
+        # compiled-once). `max_cache_len` is the pool's total token capacity
+        # (num_blocks defaults to max_cache_len // block_size);
+        # `prefill_chunk` bounds each prefill dispatch so long prompts
+        # interleave with decode instead of stalling it.
         self.block_size = int(block_size)
         if slo is None:
             # The launcher's SLO env contract reaches a serving tier with
@@ -408,65 +390,54 @@ class ContinuousBatcher:
 
             slo = serving_slo_from_env()
         self.slo = slo
-        if self.paged:
-            if self.block_size < 1:
-                raise ValueError(f"block_size must be >= 1, got {block_size}")
-            if num_blocks is None:
-                num_blocks = max(1, self.C // self.block_size)
-            self.num_blocks = int(num_blocks)
-            if prefill_chunk is None:
-                # Largest block-aligned chunk within the biggest bucket: full
-                # (non-final) chunks stay hole-free and block-aligned, which
-                # is what makes their blocks registrable for cross-request
-                # sharing. Clamped to the largest bucket for the degenerate
-                # block_size > buckets[-1] case (chunks then just aren't
-                # block-aligned, so they skip share registration).
-                prefill_chunk = min(self.buckets[-1], max(
-                    self.block_size,
-                    (self.buckets[-1] // self.block_size) * self.block_size,
-                ))
-            self.prefill_chunk = int(prefill_chunk)
-            if self.prefill_chunk < 1 or self.prefill_chunk > self.buckets[-1]:
-                raise ValueError(
-                    f"prefill_chunk must be in [1, largest bucket "
-                    f"{self.buckets[-1]}], got {prefill_chunk}"
-                )
-            # Per-request token ceiling (prompt incl. any shared prefix +
-            # output). Sizes the static per-slot block table: the chain may
-            # additionally hold the final chunk's bucket padding and up to
-            # ~3 windows of post-finish slack (finish detection + the
-            # one-window sync lookahead), all block-rounded.
-            if max_tokens_per_request is None:
-                max_tokens_per_request = self.buckets[-1] + self.max_new
-            self.max_tokens_per_request = int(max_tokens_per_request)
-            # The final chunk is BUCKET-padded, and _bucket rounds a
-            # <=prefill_chunk remainder up to at most _bucket(prefill_chunk)
-            # (coarse bucket lists round far past prefill_chunk itself), so
-            # that is the padding the static table must budget for.
-            # Spec-decode verify rounds write (k+1)-token windows instead of
-            # sync_every-token ones, so the post-finish slack is measured in
-            # the LARGER of the two window widths.
-            self._decode_slack = 3 * max(self.sync_every, self.speculative_k + 1)
-            worst_chain = (
-                self.max_tokens_per_request + self._bucket(self.prefill_chunk)
-                + self._decode_slack
+        if self.block_size < 1:
+            raise ValueError(f"block_size must be >= 1, got {block_size}")
+        if num_blocks is None:
+            num_blocks = max(1, self.C // self.block_size)
+        self.num_blocks = int(num_blocks)
+        if prefill_chunk is None:
+            # Largest block-aligned chunk within the biggest bucket: full
+            # (non-final) chunks stay hole-free and block-aligned, which
+            # is what makes their blocks registrable for cross-request
+            # sharing. Clamped to the largest bucket for the degenerate
+            # block_size > buckets[-1] case (chunks then just aren't
+            # block-aligned, so they skip share registration).
+            prefill_chunk = min(self.buckets[-1], max(
+                self.block_size,
+                (self.buckets[-1] // self.block_size) * self.block_size,
+            ))
+        self.prefill_chunk = int(prefill_chunk)
+        if self.prefill_chunk < 1 or self.prefill_chunk > self.buckets[-1]:
+            raise ValueError(
+                f"prefill_chunk must be in [1, largest bucket "
+                f"{self.buckets[-1]}], got {prefill_chunk}"
             )
-            self.max_blocks_per_slot = -(-worst_chain // self.block_size)
-        else:
-            for name, value in (("num_blocks", num_blocks),
-                                ("prefill_chunk", prefill_chunk),
-                                ("max_tokens_per_request", max_tokens_per_request)):
-                if value is not None:
-                    raise ValueError(f"{name} requires paged=True")
-            if self.speculative_k:
-                raise ValueError("speculative_k requires paged=True")
-            if self.kv_quant:
-                raise ValueError("kv_quant requires paged=True")
+        # Per-request token ceiling (prompt incl. any shared prefix +
+        # output). Sizes the static per-slot block table: the chain may
+        # additionally hold the final chunk's bucket padding and up to
+        # ~3 windows of post-finish slack (finish detection + the
+        # one-window sync lookahead), all block-rounded.
+        if max_tokens_per_request is None:
+            max_tokens_per_request = self.buckets[-1] + self.max_new
+        self.max_tokens_per_request = int(max_tokens_per_request)
+        # The final chunk is BUCKET-padded, and _bucket rounds a
+        # <=prefill_chunk remainder up to at most _bucket(prefill_chunk)
+        # (coarse bucket lists round far past prefill_chunk itself), so
+        # that is the padding the static table must budget for.
+        # Spec-decode verify rounds write (k+1)-token windows instead of
+        # sync_every-token ones, so the post-finish slack is measured in
+        # the LARGER of the two window widths.
+        self._decode_slack = 3 * max(self.sync_every, self.speculative_k + 1)
+        worst_chain = (
+            self.max_tokens_per_request + self._bucket(self.prefill_chunk)
+            + self._decode_slack
+        )
+        self.max_blocks_per_slot = -(-worst_chain // self.block_size)
         # Pallas kernel-layer spec for the engine's compiled programs
         # (ops/registry.py; docs/kernels.md): None = the launcher contract
         # (ACCELERATE_KERNELS) resolved at trace time; an explicit string
         # (e.g. "pallas" / "paged_gather=off") pins the engine regardless of
-        # env. The paged mode's chain-view assembly dispatches through op
+        # env. The chain-view assembly dispatches through op
         # ``paged_gather`` — the Pallas chain-walk skips bucket-padded slots
         # and never materializes the intermediate (B, M, bs, ...) gather;
         # token output is bit-identical either way (tests/test_kernels.py).
@@ -501,17 +472,14 @@ class ContinuousBatcher:
         self._queue: deque[_Request] = deque()
         self._next_rid = 0
         self._results: dict[int, np.ndarray] = {}
-        self._admit_fns: dict[tuple, object] = {}
-        self._prefix_fns: dict[int, object] = {}
         self._chunk_fns: dict[int, object] = {}
         self._decode_fn = None
         self._verify_fn = None
-        self._compact_fn = None
         # Cumulative speculative-decoding ledger (host side, both exposed via
         # spec_report() and the accelerate_spec_* metrics handles).
         self._spec_proposed = 0
         self._spec_accepted = 0
-        # SLO/throughput accounting (both modes): per-request wall-clock
+        # SLO/throughput accounting: per-request wall-clock
         # marks and the admission loop's decision tallies. Both ring-bounded
         # (_SLO_HISTORY): a long-lived engine serves unbounded requests, and
         # the histograms already hold the full distribution — the dicts only
@@ -522,17 +490,12 @@ class ContinuousBatcher:
             "escalated_monolithic": 0, "aliased_blocks": 0,
         }
         self._peak_consumed_slots = 0
-        # Host-side trace of paged dispatches ("chunk:<P>" / "decode"):
+        # Host-side trace of dispatches ("chunk:<P>" / "decode"):
         # the structural evidence behind the bounded-stall contract (tests
         # pin that no two prefill chunks ever run back-to-back while a
         # decoder is active, and that every chunk is <= prefill_chunk's
         # bucket — so a decode step waits on at most one chunk's compute).
         self._dispatch_log: list[str] = []
-        # Compaction reclaims columns only when something RETIRED since the
-        # last compact (retirement is what creates dead columns); keying the
-        # auto-trigger on this flag — not on position movement — keeps
-        # sustained backpressure from re-gathering the cache every window.
-        self._retired_since_compact = False
         self._prefix_tokens: np.ndarray | None = None
         # Per-request lifecycle tracing (telemetry/requests.py): every hook
         # fires from host bookkeeping the loop performs anyway, so tracing
@@ -561,7 +524,7 @@ class ContinuousBatcher:
         self.stream = None
         self._streamed: dict[int, int] = {}
         self.reset()
-        if self.paged and trace_requests:
+        if trace_requests:
             self._record_cache_layout()
 
     def _record_cache_layout(self):
@@ -606,12 +569,15 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------- lifecycle
     def reset(self, keep_prefix: bool = True):
-        """Fresh cache and slot state. Queued (not-yet-admitted) requests and
-        already-finished results survive; in-flight slots are wiped — the
-        capacity-error path re-queues them first, so catch + ``reset()`` +
-        ``run()`` retries everything. A shared prefix (``set_prefix``) is
-        re-prefilled automatically so the retry flow stays exact; pass
-        ``keep_prefix=False`` to drop it."""
+        """Fresh pool, tables, free-list, and slot state. Queued
+        (not-yet-admitted) requests and already-finished results survive;
+        in-flight slots are wiped — the capacity-error path re-queues them
+        first, so catch + ``reset()`` + ``run()`` retries everything. The
+        shared-prefix TOKENS (``set_prefix``) survive ``keep_prefix=True`` so
+        the retry flow stays exact (prefix caching is lazy: the first request
+        of the next wave re-prefills the prefix blocks and later requests
+        alias them), but all resident blocks are dropped; pass
+        ``keep_prefix=False`` to drop the prefix too."""
         B = self.B
         self._streamed.clear()
         if self.tracer is not None:
@@ -620,44 +586,6 @@ class ContinuousBatcher:
             for req in getattr(self, "_slot_req", []):
                 if req is not None:
                     self.tracer.cancel(req.rid)
-        if self.paged:
-            self._reset_paged(keep_prefix)
-            return
-        self._cache = self.module.init_cache(B, self.C, dtype=self.cache_dtype)
-        self._tok = jnp.full((B,), self.pad, jnp.int32)
-        self._pos = jnp.zeros((B,), jnp.int32)  # next rope position per slot
-        self._n_out = jnp.zeros((B,), jnp.int32)
-        self._active = jnp.zeros((B,), bool)
-        self._out_buf = jnp.full((B, self.max_new), self.pad, jnp.int32)
-        self._keys = jnp.broadcast_to(self._rng, (B,))
-        # Per-slot generation controls (heterogeneous per request; traced
-        # values, so the compiled programs are shared across any mix).
-        self._slot_max = jnp.full((B,), self.max_new, jnp.int32)
-        self._slot_temp = jnp.full((B,), float(self.temperature or 0.0), jnp.float32)
-        self._slot_eos = jnp.full((B,), self.eos, jnp.int32)
-        self._slot_req: list[_Request | None] = [None] * B
-        # Host-side mirror of cache["pos"]: it advances deterministically
-        # (+bucket per admit, +sync_every per decode window; compact() rewinds
-        # it from the one readback it already pays), so capacity checks never
-        # need a device readback.
-        self._host_pos = 0
-        self._retired_since_compact = False
-        # Shared-prefix state: number of leading cache columns holding the
-        # common prefix (valid for every slot, never evicted).
-        self._pfx = 0
-        if keep_prefix and self._prefix_tokens is not None:
-            tokens, self._prefix_tokens = self._prefix_tokens, None
-            self.set_prefix(tokens)
-        elif not keep_prefix:
-            self._prefix_tokens = None
-
-    def _reset_paged(self, keep_prefix: bool = True):
-        """Paged-mode ``reset()``: fresh pool, tables, free-list, and slot
-        state. The shared-prefix TOKENS survive ``keep_prefix=True`` (paged
-        prefix caching is lazy: the first request of the next wave re-prefills
-        the prefix blocks and later requests alias them — see
-        ``set_prefix``), but all resident blocks are dropped."""
-        B = self.B
         self._pool = init_kv_pool(
             self.module, self.num_blocks, self.block_size,
             dtype=self.cache_dtype, quant=self.kv_quant, slots=B,
@@ -682,11 +610,13 @@ class ContinuousBatcher:
         self._active = jnp.zeros((B,), bool)
         self._out_buf = jnp.full((B, self.max_new), self.pad, jnp.int32)
         self._keys = jnp.broadcast_to(self._rng, (B,))
+        # Per-slot generation controls (heterogeneous per request; traced
+        # values, so the compiled programs are shared across any mix).
         self._slot_max = jnp.full((B,), self.max_new, jnp.int32)
         self._slot_temp = jnp.full((B,), float(self.temperature or 0.0), jnp.float32)
         self._slot_eos = jnp.full((B,), self.eos, jnp.int32)
         self._slot_req: list[_Request | None] = [None] * B
-        # Host-side paged bookkeeping. Block 0 is the reserved trash block
+        # Host-side pool bookkeeping. Block 0 is the reserved trash block
         # (ops/paged_attention.py): never allocated, never mask-valid.
         self._tables_np = np.zeros((B, self.max_blocks_per_slot), np.int32)
         self._slot_len = np.zeros((B,), np.int64)      # chain slots (incl holes)
@@ -699,146 +629,79 @@ class ContinuousBatcher:
         self._block_ref = np.zeros((self.num_blocks + 1,), np.int64)
         self._share_index: dict[bytes, int] = {}
         self._block_key: dict[int, bytes] = {}
-        self._host_pos = 0
-        self._pfx = 0
-        self._retired_since_compact = False
         if not keep_prefix:
             self._prefix_tokens = None
 
     def set_prefix(self, prefix_ids) -> int:
-        """Shared-prefix caching: prefill ONE copy of a prompt prefix common to
-        every request (a system prompt, few-shot examples, a long document)
-        into the head of the cache, valid for all slots. Subsequent
-        ``submit()`` calls pass only each request's *suffix*; outputs are
-        exactly ``generate(model, prefix + suffix)`` per request (pinned by
-        tests/test_serving.py). The prefix occupies its length ONCE instead of
-        once per admitted request — the capacity (and prefill-compute) win of
-        vLLM-style prompt caching, shaped for the static slot scheme: prefix
-        columns sit below every admit's write offset, so slot-causal attention
-        sees them and eviction never touches them.
+        """Shared-prefix caching: store a prompt prefix common to every
+        request (a system prompt, few-shot examples, a long document).
+        Subsequent ``submit()`` calls pass only each request's *suffix*;
+        outputs are exactly ``generate(model, prefix + suffix)`` per request
+        (pinned by tests/test_serving.py). A special case of cross-request
+        block aliasing: the stored prefix is prepended to every submit()'s
+        prompt, the FIRST request prefills it into blocks, and every later
+        request whose chain starts with those full blocks aliases them
+        (refcounted — they stay resident while any chain uses them), so the
+        prefix's full blocks are held, and prefilled, once instead of once
+        per admitted request.
 
-        Must be called on a fresh cache (right after construction or
-        ``reset()``); ``reset()`` re-prefills it automatically so the
+        Must be called on a fresh engine (right after construction or
+        ``reset(keep_prefix=False)``); ``reset()`` keeps the tokens so the
         capacity-retry flow stays exact. Returns the prefix length."""
         prefix = np.asarray(prefix_ids, np.int32).reshape(-1)
         if prefix.size == 0:
             raise ValueError("empty prefix")
-        if self.paged:
-            # Paged prefix caching is a special case of cross-request block
-            # aliasing: the stored prefix is prepended to every submit()'s
-            # prompt, the FIRST request prefills it into blocks, and every
-            # later request whose chain starts with those full blocks aliases
-            # them (refcounted — they stay resident while any chain uses
-            # them). No eager broadcast prefill, no reserved cache head.
-            if any(m != "free" for m in self._slot_mode) or self._prefix_tokens is not None:
-                raise RuntimeError(
-                    "set_prefix needs a fresh cache (no admitted requests, no "
-                    "prior prefix): call reset(keep_prefix=False) first."
-                )
-            P = int(prefix.size)
-            if P + self.buckets[0] + self.max_new > self.max_tokens_per_request:
-                raise ValueError(
-                    f"prefix length {P} leaves no room for even one "
-                    f"smallest-bucket request within max_tokens_per_request="
-                    f"{self.max_tokens_per_request}"
-                )
-            self._prefix_tokens = prefix
-            self._pfx = P
-            return P
-        if self._host_pos != 0 or any(r is not None for r in self._slot_req):
+        if any(m != "free" for m in self._slot_mode) or self._prefix_tokens is not None:
             raise RuntimeError(
                 "set_prefix needs a fresh cache (no admitted requests, no "
                 "prior prefix): call reset(keep_prefix=False) first."
             )
         P = int(prefix.size)
-        if P + self.buckets[0] + self.max_new + self.sync_every - 1 > self.C:
+        if P + self.buckets[0] + self.max_new > self.max_tokens_per_request:
             raise ValueError(
                 f"prefix length {P} leaves no room for even one "
-                f"smallest-bucket request within max_cache_len={self.C}"
+                f"smallest-bucket request within max_tokens_per_request="
+                f"{self.max_tokens_per_request}"
             )
-        if P not in self._prefix_fns:
-            module = self.module
-            cache_dtype = self.cache_dtype
-
-            def fill(params, cache, ids):
-                # Prefill ONE row against a throwaway batch-1 cache of exactly
-                # the prefix length, then broadcast the resulting KV columns
-                # into every slot's row — identical state to a B-row prefill
-                # at 1/B the FLOPs (the rows would be bitwise copies).
-                mask = jnp.ones(ids.shape, jnp.int32)
-                small = module.init_cache(1, P, dtype=cache_dtype)
-                out = module.apply(params, input_ids=ids, attention_mask=mask,
-                                   cache=small, positions=mask_positions(mask))
-                sk, sv = out["cache"]["k"], out["cache"]["v"]
-                B = cache["kv_mask"].shape[0]
-                wide = lambda t: jnp.broadcast_to(t, (t.shape[0], B) + t.shape[2:])
-                return {
-                    **cache,
-                    "k": cache["k"].at[:, :, :P].set(wide(sk)),
-                    "v": cache["v"].at[:, :, :P].set(wide(sv)),
-                    "pos": cache["pos"] + P,
-                    "kv_mask": cache["kv_mask"].at[:, :P].set(1),
-                }
-
-            fill.__name__ = f"serve_prefix_fill_{P}"  # the program's name in a profile
-            self._prefix_fns[P] = jax.jit(fill, donate_argnums=safe_donate_argnums((1,)))
-        self._cache = self._prefix_fns[P](self.params, self._cache,
-                                          jnp.asarray(prefix)[None])
-        self._host_pos = P
-        self._pfx = P
         self._prefix_tokens = prefix
         return P
 
     @property
     def cache_columns_used(self) -> int:
-        """Global cache columns consumed so far this wave (prefix + admits +
-        decode windows, out of ``max_cache_len``) — the capacity a ``reset()``
-        reclaims. Public mirror of the engine's host-side position counter.
-        In paged mode: pool token-slots currently allocated to chains."""
-        if self.paged:
-            return self.blocks_in_use * self.block_size
-        return self._host_pos
+        """Pool token-slots currently allocated to chains (out of
+        ``num_blocks * block_size``); they return to the free list as
+        requests finish."""
+        return self.blocks_in_use * self.block_size
 
     @property
     def blocks_in_use(self) -> int:
-        """Paged mode: pool blocks currently owned by at least one chain."""
-        if not self.paged:
-            return 0
+        """Pool blocks currently owned by at least one chain."""
         return self.num_blocks - len(self._free_blocks)
 
     @property
     def kv_cache_bytes(self) -> int:
-        """Persistent device bytes of the cache — the contiguous cache's
-        k/v arrays, or the paged pool (trash block included) with, for a model
-        that carries recurrent state, the state it holds by slot
-        (``pool_stats()`` names the two kinds). The denominator
-        of the serving bench's admitted-tokens-per-cache-byte capacity
+        """Persistent device bytes of the cache — the pool (trash block
+        included) with, for a model that carries recurrent state, the state
+        it holds by slot (``pool_stats()`` names the two kinds). The
+        denominator of the serving bench's admitted-tokens-per-cache-byte capacity
         metric, and the quantity ``accelerate-tpu memcheck --serving`` gates
         against the HBM budget. A quantized pool (``kv_quant="int8"``) prices
         its per-token scale planes too; speculative decoding adds the draft
         pool's blocks — both layouts the memcheck gate must cover."""
-        total = (sum(self._pool_bytes.values()) if self.paged
-                 else pool_bytes(self._cache)["kv"])
-        draft = getattr(self, "_draft_pool", None)
-        if draft is not None:
-            total += int(draft["k"].nbytes + draft["v"].nbytes)
+        total = sum(self._pool_bytes.values())
+        if self._draft_pool is not None:
+            total += int(self._draft_pool["k"].nbytes + self._draft_pool["v"].nbytes)
         return total
 
     @property
     def kv_consumed_slots_peak(self) -> int:
-        """Peak token-slots of KV storage the wave actually consumed:
-        ``B x max(cache_columns_used)`` for the contiguous scheme (every slot
-        holds every global column) vs peak allocated pool slots for the paged
-        scheme (chains only) — the apples-to-apples capacity comparison
-        (bytes per slot are identical across modes)."""
+        """Peak token-slots of KV storage the engine has had allocated to
+        chains at once."""
         return self._peak_consumed_slots
 
     def pool_stats(self) -> dict:
-        """Host-side paged-pool snapshot (no device readback)."""
-        if not self.paged:
-            return {"paged": False}
+        """Host-side pool snapshot (no device readback)."""
         return {
-            "paged": True,
             "block_size": self.block_size,
             "num_blocks": self.num_blocks,
             "blocks_free": len(self._free_blocks),
@@ -895,88 +758,17 @@ class ContinuousBatcher:
             "requests": len(self._req_times),
         }
 
-    def compact(self) -> int:
-        """Reclaim holed cache columns: gather each row's VALID slots to the
-        front (stable, so relative order is preserved) and rewind the global
-        write offset to the longest row's valid count. Returns the number of
-        columns freed.
-
-        Why this is exact (pinned by tests): rope/wpe rotations are baked
-        into K at write time and ride the gather unchanged; causal masking
-        needs only slot ORDER (every valid key lands below the new write
-        offset); sliding windows measure valid-slot distance, which a
-        permutation of holes cannot change; and the shared prefix — valid in
-        every row, first in every row's order — keeps columns [0, pfx).
-        In-flight slot state (rope positions, output buffers) is untouched.
-
-        Cost: one full-cache gather (O(L·B·C·H·D) bytes), so it runs when
-        capacity pressure makes the alternative a dead-end — ``run()``
-        triggers it automatically on backpressure — or explicitly between
-        waves. This is the compaction step the r5 utilization measurement
-        motivated (PERF.md): a wave of heterogeneous lengths reclaims the
-        ~90% of consumed area that holes occupy instead of requiring
-        ``reset()``."""
-        if self.paged:
-            # Paged compaction is block-table surgery and happens eagerly:
-            # a retired request's chain is refcount-freed at collect time, so
-            # there is never a device permutation to run and nothing left to
-            # reclaim here. Kept callable so wave-boundary compact() calls
-            # are mode-agnostic.
-            return 0
-        if self._host_pos == 0:
-            return 0
-        if self._compact_fn is None:
-            def serve_compact(cache, dead, pfx):
-                km = cache["kv_mask"]
-                # A retired request's columns stay valid until its slot is
-                # re-admitted (eviction is lazy); compaction is exactly when
-                # they die — their output is already collected. Prefix
-                # columns survive (valid for every future occupant).
-                col = jnp.arange(km.shape[1])[None]
-                km = jnp.where(dead[:, None] & (col >= pfx), 0, km)
-                # Stable argsort of (1 - valid): valid slots first, in order.
-                perm = jnp.argsort(1 - km, axis=1, stable=True)  # (B, C)
-                pk = perm[None, :, :, None, None]
-                return {
-                    "k": jnp.take_along_axis(cache["k"], pk, axis=2),
-                    "v": jnp.take_along_axis(cache["v"], pk, axis=2),
-                    "kv_mask": jnp.take_along_axis(km, perm, axis=1),
-                    "pos": jnp.max(jnp.sum(km, axis=1)).astype(cache["pos"].dtype),
-                }
-
-            self._compact_fn = jax.jit(serve_compact, donate_argnums=safe_donate_argnums((0,)))
-        dead = jnp.asarray([r is None for r in self._slot_req])
-        self._cache = self._compact_fn(self._cache, dead, jnp.int32(self._pfx))
-        new_pos = int(host_fetch(self._cache["pos"]))  # the one readback compact pays
-        freed = self._host_pos - new_pos
-        self._host_pos = new_pos
-        self._retired_since_compact = False
-        return freed
-
     @property
     def cache_utilization(self) -> float:
-        """Fraction of the consumed cache area (B rows × ``cache_columns_used``
-        columns) whose slots are valid for their row — the engine's capacity
-        honesty metric. Holes from eviction, retired requests, and
-        inactive-row decode writes all count against it, so under
-        heterogeneous lengths this decays across a wave until ``compact()``
-        (auto-triggered at backpressure, or explicit) reclaims the holes;
-        the r5 measured decay that motivated compaction is recorded in
-        PERF.md."""
-        if self.paged:
-            # Valid tokens over allocated pool slots: holes are only bucket
-            # padding in final prefill chunks + masked inactive-step decode
-            # writes, and whole chains free at retirement — which is why the
-            # paged scheme wins on exactly this metric.
-            used = sorted(set(range(1, self.num_blocks + 1)) - set(self._free_blocks))
-            if not used:
-                return 1.0
-            mask = host_fetch(self._pool["mask"])
-            return float(mask[np.asarray(used, np.int64)].mean())
-        if self._host_pos == 0:
+        """Valid tokens over allocated pool slots — the engine's capacity
+        honesty metric. Holes are only bucket padding in final prefill
+        chunks and masked inactive-step decode writes, and whole chains free
+        at retirement, so it does not decay across waves."""
+        used = sorted(set(range(1, self.num_blocks + 1)) - set(self._free_blocks))
+        if not used:
             return 1.0
-        km = host_fetch(self._cache["kv_mask"])[:, : self._host_pos]
-        return float(km.mean())
+        mask = host_fetch(self._pool["mask"])
+        return float(mask[np.asarray(used, np.int64)].mean())
 
     def submit(
         self,
@@ -1012,27 +804,21 @@ class ContinuousBatcher:
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
-        if self.paged:
-            # Chunked prefill lifts the one-bucket prompt bound: the chain
-            # just has to fit the per-request token ceiling (prompt incl.
-            # prefix + output buffer). The prefix is prepended HERE so the
-            # whole downstream path sees one logical token stream — block
-            # aliasing then recovers the shared-prefix capacity win.
-            if self._prefix_tokens is not None:
-                prompt = np.concatenate([self._prefix_tokens, prompt])
-            limit = self.max_tokens_per_request - (
-                self.max_new if max_new_tokens is None else int(max_new_tokens)
-            )
-            if prompt.size > limit:
-                raise ValueError(
-                    f"prompt length {prompt.size} (incl. prefix) exceeds "
-                    f"max_tokens_per_request={self.max_tokens_per_request} "
-                    f"minus the output reservation; raise max_tokens_per_request."
-                )
-        elif prompt.size > self.buckets[-1]:
+        # Chunked prefill lifts the one-bucket prompt bound: the chain
+        # just has to fit the per-request token ceiling (prompt incl.
+        # prefix + output buffer). The prefix is prepended HERE so the
+        # whole downstream path sees one logical token stream — block
+        # aliasing then recovers the shared-prefix capacity win.
+        if self._prefix_tokens is not None:
+            prompt = np.concatenate([self._prefix_tokens, prompt])
+        limit = self.max_tokens_per_request - (
+            self.max_new if max_new_tokens is None else int(max_new_tokens)
+        )
+        if prompt.size > limit:
             raise ValueError(
-                f"prompt length {prompt.size} exceeds the largest bucket "
-                f"{self.buckets[-1]}; raise bucket_sizes."
+                f"prompt length {prompt.size} (incl. prefix) exceeds "
+                f"max_tokens_per_request={self.max_tokens_per_request} "
+                f"minus the output reservation; raise max_tokens_per_request."
             )
         max_new = self.max_new if max_new_tokens is None else int(max_new_tokens)
         if not (1 <= max_new <= self.max_new):
@@ -1099,61 +885,6 @@ class ContinuousBatcher:
         return jnp.where(temps > 0.0, sampled, greedy)
 
     # ------------------------------------------------------------- compiled
-    def _admit_fn(self, P: int):
-        """Compiled prefill of ONE slot's prompt (bucket length P): the whole
-        (B, P) chunk runs so shapes stay request-independent; rows other than
-        the target slot carry a zero attention mask, so their kv_mask stays
-        invalid for the written block automatically. Keyed on (P, prefix
-        columns): with a shared prefix, eviction spares the prefix region and
-        token positions start past the prefix."""
-        pfx = self._pfx
-        if (P, pfx) in self._admit_fns:
-            return self._admit_fns[(P, pfx)]
-        module = self.module
-        pad = self.pad
-
-        def run(params, cache, state, slot, prompt_row, mask_row, rid, base_rng,
-                req_max, req_temp, req_eos):
-            (tok, pos, n_out, active, out_buf, keys,
-             slot_max, slot_temp, slot_eos) = state
-            B = tok.shape[0]
-            # evict the slot's previous occupant: its KV must stop being
-            # attendable before the new prompt writes into the same row —
-            # but the shared-prefix columns stay valid for every occupant
-            cache = {**cache, "kv_mask": cache["kv_mask"].at[slot, pfx:].set(0)}
-            ids = jnp.zeros((B, P), jnp.int32).at[slot].set(prompt_row)
-            mask = jnp.zeros((B, P), jnp.int32).at[slot].set(mask_row)
-            out = module.apply(params, input_ids=ids, attention_mask=mask,
-                               cache=cache, positions=mask_positions(mask) + pfx)
-            real_len = jnp.sum(mask_row).astype(jnp.int32) + pfx
-            key = jax.random.fold_in(base_rng, rid)  # the request's own stream
-            keys = keys.at[slot].set(key)
-            slot_max = slot_max.at[slot].set(req_max)
-            slot_temp = slot_temp.at[slot].set(req_temp)
-            slot_eos = slot_eos.at[slot].set(req_eos)
-            first = self._sample_rows(
-                out["logits"][slot, -1][None], key[None],
-                jnp.zeros((1,), jnp.int32), req_temp[None],
-            )[0]
-            tok = tok.at[slot].set(first)
-            pos = pos.at[slot].set(real_len)
-            n_out = n_out.at[slot].set(1)
-            # even an immediate eos is emitted (HF convention); the slot stays
-            # active only if there is room and the first token wasn't eos
-            out_buf = out_buf.at[slot].set(jnp.full((self.max_new,), pad, jnp.int32))
-            out_buf = out_buf.at[slot, 0].set(first)
-            done0 = (first == req_eos) | (req_max <= 1)
-            active = active.at[slot].set(~done0)
-            state = (tok, pos, n_out, active, out_buf, keys,
-                     slot_max, slot_temp, slot_eos)
-            return out["cache"], state, done0
-
-        run.__name__ = f"serve_admit_{P}"
-        fn = jax.jit(run, donate_argnums=safe_donate_argnums((1, 2)))
-        self._admit_fns[(P, pfx)] = fn
-        return fn
-
-    # ------------------------------------------------------- compiled (paged)
     def _paged_view_cache(self, pool, tables, lens, write_cols: int):
         """The two-part cache of a paged program, as ``(view, window)``.
 
@@ -1338,7 +1069,7 @@ class ContinuousBatcher:
             return pool, state
 
         if spec:
-            def run(params, d_params, pool, d_pool, state, tables, lens, slot,
+            def chunk(params, d_params, pool, d_pool, state, tables, lens, slot,
                     chunk_row, mask_row, base_pos, is_final, rid, base_rng,
                     req_max, req_temp, req_eos):
                 return body(params, pool, state, tables, lens, slot, chunk_row,
@@ -1352,7 +1083,7 @@ class ContinuousBatcher:
                 + len(jax.tree_util.tree_leaves(self._state_tuple()))
             )
         else:
-            def run(params, pool, state, tables, lens, slot, chunk_row,
+            def chunk(params, pool, state, tables, lens, slot, chunk_row,
                     mask_row, base_pos, is_final, rid, base_rng, req_max,
                     req_temp, req_eos):
                 return body(params, pool, state, tables, lens, slot, chunk_row,
@@ -1364,8 +1095,8 @@ class ContinuousBatcher:
                 jax.tree_util.tree_leaves(self._state_tuple())
             )
         effective_donate = safe_donate_argnums(donations)
-        run.__name__ = f"serve_prefill_chunk_{P}"
-        fn = jax.jit(run, donate_argnums=effective_donate)
+        chunk.__name__ = f"serve_prefill_chunk_{P}"
+        fn = jax.jit(chunk, donate_argnums=effective_donate)
         param_leaves = jax.tree_util.tree_leaves(self.params)
         from .ops.registry import resolved_backends
 
@@ -1390,13 +1121,13 @@ class ContinuousBatcher:
             "donation_dropped_by_policy": not effective_donate,
             "kernels": {"spec": self.kernels,
                         "backends": resolved_backends(self.kernels)},
-            "jaxpr_thunk": lambda *a, **k: jax.make_jaxpr(run)(*a, **k),
+            "jaxpr_thunk": lambda *a, **k: jax.make_jaxpr(chunk)(*a, **k),
             "memory_classes": memory_classes,
         }
         self._chunk_fns[P] = fn
         return fn
 
-    def _decode_paged(self):
+    def _decode(self):
         """Compiled ``sync_every``-token window over block tables: ONE gather
         of every slot's chain (the reference block-table lowering —
         ops/paged_attention.py), a ``lax.scan`` of decode steps writing into
@@ -1419,8 +1150,7 @@ class ContinuousBatcher:
              slot_max, slot_temp, slot_eos) = state
             B = tok.shape[0]
             # Host-side stop-sequence verdicts from the previous window's
-            # report land here (the paged analog of the contiguous loop's
-            # in-place active flip).
+            # report land here.
             active = active & ~force_stop
             state = (tok, pos, n_out, active, out_buf, keys,
                      slot_max, slot_temp, slot_eos)
@@ -1468,8 +1198,8 @@ class ContinuousBatcher:
             carry, _ = jax.lax.scan(one_step, carry, None, length=w)
             window, state = carry[:2]
             # Persist the window: committed slots append their written
-            # columns (valid or holed — the per-slot chain mirrors the
-            # contiguous scheme's unconditional global advance); everything
+            # columns (valid or holed: a committed chain advances by the
+            # whole window whatever its row produced); everything
             # else lands in the trash block with a forced-zero mask, so
             # block 0 is provably never attendable.
             idx = lens[:, None] + jnp.arange(w)[None]
@@ -1546,10 +1276,9 @@ class ContinuousBatcher:
         bit-identical to non-speculative BY CONSTRUCTION, and sampled output
         stays traffic-independent (tests/test_speculative.py pins both).
 
-        Rejection is block-table truncation, the same surgery compaction
-        uses: rejected window columns' writes land in the trash block with a
-        zero mask and the host simply does not advance the chain frontier
-        past them — no device scrub. Returns ``(pool, d_pool, state,
+        Rejection is block-table truncation: rejected window columns' writes
+        land in the trash block with a zero mask and the host simply does not
+        advance the chain frontier past them — no device scrub. Returns ``(pool, d_pool, state,
         produced, report)``: ``produced`` (tokens committed per slot, current
         + accepted drafts) is fetched eagerly — the one blocking (B,)
         readback a verify round pays for k-fold fewer target passes —
@@ -1692,79 +1421,6 @@ class ContinuousBatcher:
         }
         return self._verify_fn
 
-    def _decode(self):
-        """Compiled ``sync_every``-token window for all B slots — ONE program
-        dispatch per host check (a ``lax.scan`` over steps), so the host's
-        dispatch overhead is paid per window and not per token. Inactive
-        rows feed pads and their freshly written cache columns are
-        invalidated."""
-        if self.paged:
-            return self._decode_paged()
-        if self._decode_fn is not None:
-            return self._decode_fn
-        module = self.module
-        pad = self.pad
-
-        def serve_decode_contiguous(params, cache, state):
-            def one_step(carry, _):
-                cache, state = carry
-                (tok, pos, n_out, active, out_buf, keys,
-                 slot_max, slot_temp, slot_eos) = state
-                B = tok.shape[0]
-                col = cache["pos"]  # global slot this step writes
-                feed = jnp.where(active, tok, pad)
-                out = module.apply(params, input_ids=feed[:, None], cache=cache,
-                                   positions=pos[:, None])
-                nxt = self._sample_rows(out["logits"][:, -1], keys, n_out, slot_temp)
-                nxt = jnp.where(active, nxt, pad)
-                cache2 = out["cache"]
-                # hole out the column for rows that didn't produce a token
-                cache2 = {
-                    **cache2,
-                    "kv_mask": cache2["kv_mask"].at[:, col].set(
-                        jnp.where(active, cache2["kv_mask"][:, col], 0)
-                    ),
-                }
-                emit_idx = jnp.clip(n_out, 0, self.max_new - 1)
-                cur = out_buf[jnp.arange(B), emit_idx]
-                out_buf = out_buf.at[jnp.arange(B), emit_idx].set(
-                    jnp.where(active, nxt, cur)
-                )
-                n_out = n_out + active.astype(jnp.int32)
-                still = active & (nxt != slot_eos) & (n_out < slot_max)
-                state = (nxt, pos + 1, n_out, still, out_buf, keys,
-                         slot_max, slot_temp, slot_eos)
-                return (cache2, state), None
-
-            (cache, state), _ = jax.lax.scan(
-                one_step, (cache, state), None, length=self.sync_every
-            )
-            return cache, state
-
-        # Donating cache+state halves the live KV footprint (the cache is the
-        # engine's dominant allocation and is dead after each window).
-        effective_donate = safe_donate_argnums((1, 2))
-        self._decode_fn = jax.jit(serve_decode_contiguous, donate_argnums=effective_donate)
-        # Builder metadata for the auditor/fingerprint (the serving analog of
-        # Accelerator._builder_audit_meta): the donation contract over
-        # cache+state and the params' compute dtype. Leaf counts read the
-        # LIVE cache/state fields, whose structure is fixed at __init__.
-        donated_leaves = len(jax.tree_util.tree_leaves(self._cache)) + len(
-            jax.tree_util.tree_leaves(self._state_tuple())
-        )
-        param_leaves = jax.tree_util.tree_leaves(self.params)
-        compute_dtype = (
-            str(np.dtype(param_leaves[0].dtype).name) if param_leaves else None
-        )
-        self._decode_fn._audit_meta = {
-            "builder": "serving_decode",
-            "compute_dtype": compute_dtype,
-            "expected_donations": (1, 2),
-            "expected_donated_leaves": donated_leaves,
-            "donation_dropped_by_policy": not effective_donate,
-        }
-        return self._decode_fn
-
     # ---------------------------------------------------------------- audit
     def _state_tuple(self):
         return (self._tok, self._pos, self._n_out, self._active, self._out_buf,
@@ -1775,26 +1431,24 @@ class ContinuousBatcher:
         CURRENT cache/state — what audit_decode/fingerprint_decode lower
         with. Program contracts are value-independent, so live host
         bookkeeping values are fine."""
-        if self.paged:
-            return (
-                self.params, self._pool, jnp.asarray(self._tables_np),
-                jnp.asarray(self._slot_len, dtype=jnp.int32),
-                jnp.asarray([m == "decode" for m in self._slot_mode]),
-                jnp.zeros((self.B,), bool), self._state_tuple(),
-            )
-        return (self.params, self._cache, self._state_tuple())
+        return (
+            self.params, self._pool, jnp.asarray(self._tables_np),
+            jnp.asarray(self._slot_len, dtype=jnp.int32),
+            jnp.asarray([m == "decode" for m in self._slot_mode]),
+            jnp.zeros((self.B,), bool), self._state_tuple(),
+        )
 
     def audit_decode(self, **kwargs):
         """Statically audit the compiled ``sync_every``-token decode window
         (analysis/audit.py) against the engine's current cache/state:
-        collective inventory, donation aliasing (cache+state are donated —
+        collective inventory, donation aliasing (pool+state are donated —
         the KV-footprint halving must actually alias), host callbacks.
         Lowers and compiles but never decodes a token."""
         from .analysis import audit_built
 
         return audit_built(self._decode(), *self._decode_args(), **kwargs)
 
-    def fingerprint_decode(self, config: str = "decode", **kwargs):
+    def fingerprint_decode(self, config: str = "decode_paged", **kwargs):
         """Canonical :class:`~.analysis.fingerprint.ProgramFingerprint` of
         the compiled decode window — the serving entry in the drift-gate
         matrix (``accelerate-tpu fingerprint``). Lowers and compiles but
@@ -1843,8 +1497,6 @@ class ContinuousBatcher:
         """The ``P``-token chunk program's full argument tuple against the
         engine's current pool/state — what the prefill-tier audit/fingerprint
         lower with (value-independent, like ``_decode_args``)."""
-        if not self.paged:
-            raise ValueError("the chunk program exists only in paged mode")
         tail = (
             jnp.asarray(self._tables_np),
             jnp.asarray(self._slot_len, dtype=jnp.int32), jnp.int32(0),
@@ -1878,11 +1530,10 @@ class ContinuousBatcher:
         raise AssertionError  # guarded in submit()
 
     def _finish(self, req: _Request, row: np.ndarray):
-        """Bank one finished request's output (shared by both cache modes):
-        exact eos/stop truncation — tokens decoded past the stop (host scan
-        lags by the sync cadence) are discarded, so output is
-        cadence-independent — plus completion counters and the TTFT/TPOT
-        histogram observations."""
+        """Bank one finished request's output: exact eos/stop truncation —
+        tokens decoded past the stop (host scan lags by the sync cadence) are
+        discarded, so output is cadence-independent — plus completion
+        counters and the TTFT/TPOT histogram observations."""
         row = row.copy()
         if req.eos >= 0 and (row == req.eos).any():
             row = row[: int(np.argmax(row == req.eos)) + 1]
@@ -1921,21 +1572,10 @@ class ContinuousBatcher:
         except Exception:
             pass
 
-    def _collect(self, s: int, active_np):
-        req = self._slot_req[s]
-        if req is None or active_np[s]:
-            return
-        row = host_fetch(self._out_buf[s])
-        n = int(host_fetch(self._n_out[s]))
-        self._finish(req, row[:n])
-        self._slot_req[s] = None
-        self._retired_since_compact = True  # its columns are now reclaimable
-
     def _sync(self, state):
         (self._tok, self._pos, self._n_out, self._active, self._out_buf,
          self._keys, self._slot_max, self._slot_temp, self._slot_eos) = state
 
-    # ------------------------------------------------------------ paged loop
     def _alias_lookup(self, prompt: np.ndarray):
         """Longest resident block chain whose tokens prefix ``prompt``:
         cross-request prefix sharing as refcounted aliasing. Capped one token
@@ -1962,8 +1602,6 @@ class ContinuousBatcher:
         prompt's chain prefix and routes to the longest match, so cache-hit
         routing is a host-side lookup, never a device touch). A configured
         shared prefix counts exactly as submit() would prepend it."""
-        if not self.paged:
-            return 0
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         if self._prefix_tokens is not None:
             prompt = np.concatenate([self._prefix_tokens, prompt])
@@ -1981,8 +1619,6 @@ class ContinuousBatcher:
         keeps the chain resident until the importer acks, then releases it
         here; a failed handoff releases it too, so pool blocks never leak.
         Idempotent — returns False when ``rid`` no longer holds a slot."""
-        if not self.paged:
-            return False
         s = next(
             (s for s in range(self.B)
              if self._slot_req[s] is not None and self._slot_req[s].rid == rid),
@@ -2024,9 +1660,9 @@ class ContinuousBatcher:
 
     def _free_chain(self, s: int):
         """Retire slot ``s``'s chain: refcount-decrement every block, return
-        rc-0 blocks to the free list (unregistering their share keys). This
-        IS paged compaction — block-table surgery instead of the contiguous
-        scheme's device-wide gather."""
+        rc-0 blocks to the free list (unregistering their share keys):
+        reclaiming a finished request's cache is block-table surgery on the
+        host, no device program."""
         for blk in self._slot_blocks[s]:
             self._block_ref[blk] -= 1
             if self._block_ref[blk] == 0:
@@ -2049,8 +1685,6 @@ class ContinuousBatcher:
             del self._dispatch_log[:_SLO_HISTORY]
 
     def _publish_pool_gauges(self):
-        if not self.paged:
-            return
         _, _, free_gauge, util_gauge, _ = _slo_metrics()
         free_gauge.set(float(len(self._free_blocks)))
         util_gauge.set(1.0 - len(self._free_blocks) / max(1, self.num_blocks))
@@ -2358,194 +1992,76 @@ class ContinuousBatcher:
         self._publish_pool_gauges()
         return emitted, finished
 
-    def _run_paged(self) -> dict[int, np.ndarray]:
-        """The paged engine loop: per iteration, admit; dispatch at most ONE
+    def run(self) -> dict[int, np.ndarray]:
+        """Drive admits + decode until the queue drains and all slots finish.
+        Returns THIS wave's results only: {request_id: generated token ids
+        (eos included, no pads)} for every request finished during the call.
+
+        The engine loop: per iteration, admit; dispatch at most ONE
         prefill chunk; dispatch one decode window; then process the
         PREVIOUS window's report — a one-window lookahead, so the window
         just dispatched overlaps all host work including the report fetch
         (zero blocking transfers in steady state, pinned by tests). Decode
         stall per iteration is bounded by one chunk's compute instead of one
-        prompt's — the chunked-prefill contract. Each turn is one
-        ``serve.iteration`` span whose children are the steps above."""
-        state = self._state_tuple()
-        pending = None
-        force_stop = np.zeros((self.B,), bool)
-        last_dispatch_t = None
-        window_pace = None
-        window = (self.speculative_k + 1) if self.speculative_k else self.sync_every
-        while True:
-            with self._span("serve.iteration") as turn:
-                now = time.monotonic()
-                with self._span("serve.admit") as rec:
-                    admitted = self._slo_decisions["admitted"]
-                    self._admit_paged(now)
-                    rec.attrs["admitted"] = self._slo_decisions["admitted"] - admitted
-                chunk_slot = self._pick_chunk_slot(now, window_pace)
-                chunk_p = 0
-                if chunk_slot is not None:
-                    chunk_p, tokens, final = self._next_chunk(chunk_slot)
-                    with self._span("serve.dispatch_chunk", rid=self._slot_req[chunk_slot].rid,
-                                    p=chunk_p, tokens=tokens, final=final,
-                                    rows_computed=chunk_p):
-                        state = self._dispatch_chunk(chunk_slot, state)
-                decoding = sum(m == "decode" for m in self._slot_mode)
-                turn.attrs.update(
-                    chunk=chunk_p, decoding=decoding,
-                    prefilling=sum(m == "prefill" for m in self._slot_mode),
-                    queued=len(self._queue), free_blocks=len(self._free_blocks))
-                new_pending = None
-                if decoding:
-                    with self._span("serve.dispatch_decode", decoding=decoding,
-                                    slots=self.B, window=window) as rec:
-                        state, new_pending = self._dispatch_decode(state, force_stop)
-                    new_pending += (rec,)
-                    force_stop[:] = False
-                    t = time.monotonic()
-                    if last_dispatch_t is not None:
-                        dt = t - last_dispatch_t
-                        window_pace = dt if window_pace is None else 0.5 * window_pace + 0.5 * dt
-                    last_dispatch_t = t
-                if pending is not None:
-                    self._process_report(pending, force_stop)
-                pending = new_pending
-                if pending is None and chunk_slot is None and not decoding:
-                    if self._queue:
-                        if any(m != "free" for m in self._slot_mode):
-                            continue
-                        raise RuntimeError(
-                            f"KV pool capacity exhausted ({len(self._free_blocks)} of "
-                            f"{self.num_blocks} blocks free; the next request needs "
-                            "more); raise max_cache_len/num_blocks, or catch this, "
-                            "reset(), and run() again."
-                        )
-                    if all(m == "free" for m in self._slot_mode):
-                        break
-        self._sync(state)
-        self._publish_pool_gauges()
-        wave, self._results = self._results, {}
-        return {rid: wave[rid] for rid in sorted(wave)}
-
-    def run(self) -> dict[int, np.ndarray]:
-        """Drive admits + decode until the queue drains and all slots finish.
-        Returns THIS wave's results only: {request_id: generated token ids
-        (eos included, no pads)} for every request finished during the call."""
-        with self._span("serve.run") as rec:
-            wave = self._run_paged() if self.paged else self._run_contiguous()
-            rec.attrs["finished"] = len(wave)
-        return wave
-
-    def _run_contiguous(self) -> dict[int, np.ndarray]:
-        state = (self._tok, self._pos, self._n_out, self._active, self._out_buf,
-                 self._keys, self._slot_max, self._slot_temp, self._slot_eos)
-        while True:
-            self._sync(state)  # _collect reads the instance fields
-            # Counted fetch + writable copy: the stop scan flips entries.
-            active_np = host_fetch(state[3]).copy()
-            # Host-side stop-sequence scan: frees a matched slot at the sync
-            # cadence (<= sync_every - 1 steps late; the OUTPUT is truncated
-            # exactly in _collect, so only slot-turnaround timing varies).
-            stop_slots = [
-                s for s in range(self.B)
-                if active_np[s] and self._slot_req[s] is not None and self._slot_req[s].stop
-            ]
-            if stop_slots:
-                out_np = host_fetch(state[4])
-                n_np = host_fetch(state[2])
-                new_active = state[3]
-                for s in stop_slots:
-                    row = out_np[s][: int(n_np[s])]
-                    if _first_stop_end(row, self._slot_req[s].stop) is not None:
-                        new_active = new_active.at[s].set(False)
-                        active_np[s] = False
-                state = state[:3] + (new_active,) + state[4:]
-                self._sync(state)
-            for s in range(self.B):
-                self._collect(s, active_np)
-            # Capacity reservation must cover the LONGEST remaining run among
-            # active slots, not just the incoming request's own max_new:
-            # decode windows consume global columns until the longest-running
-            # request finishes, so a short admit reserving only its own
-            # length would let a long-running neighbor push cache['pos'] past
-            # max_cache_len with no runtime guard (the clamped writes would
-            # silently corrupt the last column). r5 review finding.
-            n_np = host_fetch(state[2])
-            max_remaining = max(
-                (self._slot_req[s].max_new - int(n_np[s])
-                 for s in range(self.B)
-                 if self._slot_req[s] is not None and active_np[s]),
-                default=0,
-            )
-            free = [s for s in range(self.B) if self._slot_req[s] is None]
-            while free and self._queue:
-                req = self._queue.popleft()
-                s = free.pop(0)
-                P = self._bucket(req.prompt.size)
-                reserve = max(req.max_new, max_remaining)
-                need = P + reserve + self.sync_every - 1
-                if self._host_pos + need > self.C and self._retired_since_compact:
-                    # Capacity pressure + something retired since the last
-                    # compact: reclaim its columns before deferring or
-                    # dead-ending. The retirement flag (not position
-                    # movement) gates this, so sustained backpressure while
-                    # one long request runs never re-gathers the cache.
-                    self.compact()
-                if self._host_pos + need > self.C:
-                    self._queue.appendleft(req)
-                    if any(r is not None for r in self._slot_req):
-                        # Backpressure, not failure: let the in-flight slots
-                        # finish (each decode window frees capacity pressure
-                        # by retiring requests) and retry the admit later.
-                        break
-                    # Nothing in flight and still no room: a true dead end.
-                    # Re-queue is already done, so catch + reset() + run()
-                    # retries everything (finished results stay banked).
-                    raise RuntimeError(
-                        f"cache capacity exhausted (pos={self._host_pos}, "
-                        f"need {P + reserve} more of {self.C}); raise "
-                        "max_cache_len, or catch this, reset(), and run() again."
-                    )
-                row = np.full((P,), self.pad, np.int32)
-                mrow = np.zeros((P,), np.int32)
-                row[: req.prompt.size] = req.prompt
-                mrow[: req.prompt.size] = 1
-                # left-align inside the bucket so the last real token sits at P-1
-                row_j, mrow_j = left_align(row[None], mrow[None])
-                self._cache, state, _fin0 = self._admit_fn(P)(
-                    self.params, self._cache, state, s, row_j[0], mrow_j[0],
-                    jnp.int32(req.rid), self._rng,
-                    jnp.int32(req.max_new), jnp.float32(req.temperature),
-                    jnp.int32(req.eos),
-                )
-                self._host_pos += P
-                admit_t = time.monotonic()
-                self._req_times.setdefault(req.rid, {"submit": req.submit_t})[
-                    "first_token"
-                ] = admit_t
-                if self.tracer is not None:
-                    # Contiguous admits prefill AND sample the first token in
-                    # one dispatch: admission and first-token coincide.
-                    self.tracer.admit(req.rid)
-                    self.tracer.first_token(req.rid, at=admit_t)
-                self._peak_consumed_slots = max(
-                    self._peak_consumed_slots, self.B * self._host_pos
-                )
-                # Keep the instance fields pointing at LIVE buffers: the admit
-                # donated the previous ones, and a capacity raise later in
-                # this pass must leave the engine in a clean recoverable state.
-                self._sync(state)
-                self._slot_req[s] = req
-                max_remaining = max(max_remaining, req.max_new)
-                # (an immediate-eos slot is collected at the next loop-top
-                # check — no blocking readback of the admit result here)
-            if not self._queue and not any(r is not None for r in self._slot_req):
-                break
-            # ONE dispatch advances all slots by sync_every tokens; the
-            # np.asarray at the loop top is the only blocking host round-trip.
-            self._cache, state = self._decode()(self.params, self._cache, state)
-            self._host_pos += self.sync_every
-            self._peak_consumed_slots = max(
-                self._peak_consumed_slots, self.B * self._host_pos
-            )
-        self._sync(state)
-        wave, self._results = self._results, {}
-        return {rid: wave[rid] for rid in sorted(wave)}
+        prompt's — the chunked-prefill contract. The wave is one
+        ``serve.run`` span and each turn one ``serve.iteration`` span whose
+        children are the steps above."""
+        with self._span("serve.run") as wave:
+            state = self._state_tuple()
+            pending = None
+            force_stop = np.zeros((self.B,), bool)
+            last_dispatch_t = None
+            window_pace = None
+            window = (self.speculative_k + 1) if self.speculative_k else self.sync_every
+            while True:
+                with self._span("serve.iteration") as turn:
+                    now = time.monotonic()
+                    with self._span("serve.admit") as rec:
+                        admitted = self._slo_decisions["admitted"]
+                        self._admit_paged(now)
+                        rec.attrs["admitted"] = self._slo_decisions["admitted"] - admitted
+                    chunk_slot = self._pick_chunk_slot(now, window_pace)
+                    chunk_p = 0
+                    if chunk_slot is not None:
+                        chunk_p, tokens, final = self._next_chunk(chunk_slot)
+                        with self._span("serve.dispatch_chunk", rid=self._slot_req[chunk_slot].rid,
+                                        p=chunk_p, tokens=tokens, final=final,
+                                        rows_computed=chunk_p):
+                            state = self._dispatch_chunk(chunk_slot, state)
+                    decoding = sum(m == "decode" for m in self._slot_mode)
+                    turn.attrs.update(
+                        chunk=chunk_p, decoding=decoding,
+                        prefilling=sum(m == "prefill" for m in self._slot_mode),
+                        queued=len(self._queue), free_blocks=len(self._free_blocks))
+                    new_pending = None
+                    if decoding:
+                        with self._span("serve.dispatch_decode", decoding=decoding,
+                                        slots=self.B, window=window) as rec:
+                            state, new_pending = self._dispatch_decode(state, force_stop)
+                        new_pending += (rec,)
+                        force_stop[:] = False
+                        t = time.monotonic()
+                        if last_dispatch_t is not None:
+                            dt = t - last_dispatch_t
+                            window_pace = dt if window_pace is None else 0.5 * window_pace + 0.5 * dt
+                        last_dispatch_t = t
+                    if pending is not None:
+                        self._process_report(pending, force_stop)
+                    pending = new_pending
+                    if pending is None and chunk_slot is None and not decoding:
+                        if self._queue:
+                            if any(m != "free" for m in self._slot_mode):
+                                continue
+                            raise RuntimeError(
+                                f"KV pool capacity exhausted ({len(self._free_blocks)} of "
+                                f"{self.num_blocks} blocks free; the next request needs "
+                                "more); raise max_cache_len/num_blocks, or catch this, "
+                                "reset(), and run() again."
+                            )
+                        if all(m == "free" for m in self._slot_mode):
+                            break
+            self._sync(state)
+            self._publish_pool_gauges()
+            results, self._results = self._results, {}
+            wave.attrs["finished"] = len(results)
+        return {rid: results[rid] for rid in sorted(results)}

@@ -138,10 +138,8 @@ def iter_sse(fp):
 class ServingFrontend:
     """The /v1/* provider for one engine + role; see module docstring.
 
-    ``engine`` is a paged-or-contiguous ``ContinuousBatcher`` (paged required
-    for ``prefill``/``decode`` roles — disaggregation is chain surgery);
-    ``role`` defaults to the launcher env contract
-    (:func:`~.roles.resolve_serving_role`)."""
+    ``engine`` is a ``ContinuousBatcher``; ``role`` defaults to the launcher
+    env contract (:func:`~.roles.resolve_serving_role`)."""
 
     # How a worker_kill chaos fault dies: "process" is the real thing
     # (os._exit mid-stream — launcher drills; exit code 0 so the gang
@@ -160,11 +158,6 @@ class ServingFrontend:
         if not self.role.runs_engine:
             raise ValueError(
                 "the router role runs no engine; use serving_net.Router"
-            )
-        if self.role.name in ("prefill", "decode") and not engine.paged:
-            raise ValueError(
-                f"serving role {self.role.name!r} requires a paged engine "
-                "(disaggregation is block-chain surgery)"
             )
         self.engine = engine
         self.stream_timeout_s = float(stream_timeout_s)

@@ -119,8 +119,6 @@ def run_prefill_only(engine, rid: int) -> None:
     admitted requests' chunks interleave in submit order exactly as the
     unified loop would run them, so prefill-host chunk traces match the
     single-host dispatch discipline."""
-    if not engine.paged:
-        raise ValueError("disaggregated prefill requires a paged engine")
     state = engine._state_tuple()
     while True:
         target = next(
@@ -169,8 +167,6 @@ def export_chain(engine, rid: int, endpoint: str | None = None,
     leaves the chain intact for re-handoff to a surviving decode host. The
     tracer books the ``out`` leg either way, closing this tier's record as
     ``handed_off``."""
-    if not engine.paged:
-        raise ValueError("chain export requires a paged engine")
     if engine._stateful:
         raise ValueError(
             f"chain export ships a request's key-value blocks; "
@@ -267,8 +263,6 @@ def import_chain(engine, payload: dict, endpoint: str | None = None) -> int:
     with the shipped decode state. After this, ``engine.run()`` decodes the
     request exactly as if the prefill had happened locally. Returns the rid
     (unchanged — router-assigned ids survive every hop)."""
-    if not engine.paged:
-        raise ValueError("chain import requires a paged engine")
     if payload.get("version") != PAYLOAD_VERSION:
         raise ValueError(
             f"handoff payload version {payload.get('version')!r} != "

@@ -95,7 +95,7 @@ def _engine(model):
     return ContinuousBatcher(
         model, batch_slots=2, max_new_tokens=MAX_NEW, max_cache_len=1024,
         cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-        paged=True, block_size=4, prefill_chunk=CHUNK,
+        block_size=4, prefill_chunk=CHUNK,
         max_tokens_per_request=48,
     )
 

@@ -65,8 +65,8 @@ import numpy as np
 # identity, not just to flag settings.
 # v9 = serving lever (BENCH_SERVING=1): detail.serving on every line — the
 # serving decode wave's attribution (benchmarks/serving_decode_profile.py):
-# paged-vs-contiguous effective batch capacity (admitted tokens per KV slot)
-# at verified-identical outputs, chunked-vs-monolithic prefill max decode
+# admitted tokens per KV slot of a mixed wave whose outputs are verified
+# against solo generate, chunked-vs-monolithic prefill max decode
 # stall, per-request TTFT/TPOT, and the op-level paged-gather overhead the
 # ROADMAP item 3 Pallas kernel will be measured against. Absent otherwise.
 # v10 = Pallas kernel lever (ROADMAP item 3 shipped): BENCH_KERNELS sets the
@@ -687,7 +687,7 @@ def run_one(mode: str):
     # Serving lever (schema v9): BENCH_SERVING=1 runs the serving decode
     # attribution wave (its own fixed shapes — benchmarks/
     # serving_decode_profile.py; BENCH_PROFILE_SMALL shrinks it) and embeds
-    # the summary, so the paged-capacity and chunked-stall ratios travel in
+    # the summary, so the wave's capacity and the chunked-stall ratios travel in
     # the same trajectory as the training MFU headline.
     serving_summary = None
     if os.environ.get("BENCH_SERVING", "0") == "1":

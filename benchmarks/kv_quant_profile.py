@@ -109,7 +109,7 @@ def probe_wave(model, s, quant: bool):
     engine = ContinuousBatcher(
         model, batch_slots=s["slots"], max_new_tokens=s["max_new"],
         max_cache_len=4096 if not SMALL else 1024, cache_dtype=jnp.float32,
-        bucket_sizes=s["buckets"], sync_every=s["sync"], paged=True,
+        bucket_sizes=s["buckets"], sync_every=s["sync"],
         block_size=s["block"], kv_quant="int8" if quant else None,
     )
     rng = np.random.default_rng(7)

@@ -10,11 +10,9 @@ ROADMAP item 3) is attributed instead of guessed:
   block tables (``paged_attention``'s reference gather lowering) at the same
   logical shape. The gap between these two IS the gather tax the Pallas
   kernel exists to kill.
-- ``wave_{contiguous,paged}``: a mixed-length wave through
-  ``ContinuousBatcher`` in each cache mode at identical outputs —
-  tokens/s, observed TTFT/TPOT, and **effective batch capacity** (admitted
-  tokens per consumed KV slot; slot bytes are identical across modes), whose
-  ratio is the >= 1.3x acceptance gate.
+- ``wave_paged``: a mixed-length wave through ``ContinuousBatcher``, each
+  request's tokens held to solo ``generate()`` — tokens/s, observed
+  TTFT/TPOT, and admitted tokens per consumed KV slot.
 - ``prefill_{monolithic,chunked}``: a long prompt admitted mid-wave, with
   the max gap between consecutive decode windows recorded — chunked prefill
   must bound per-step decode stall by one chunk's compute (the <= 2x
@@ -139,20 +137,18 @@ def probe_decode_attention(s):
     }
 
 
-def probe_wave(model, s, paged: bool):
-    """A mixed-length wave through one cache mode: throughput, latency
-    accounting, and consumed-capacity; returns outputs for the parity join."""
+def probe_wave(model, s):
+    """A mixed-length wave through the engine: throughput, latency
+    accounting, and consumed-capacity; returns the prompts and outputs for
+    the parity join."""
     import jax.numpy as jnp
 
     from accelerate_tpu.serving import ContinuousBatcher
 
-    kw = dict(batch_slots=s["slots"], max_new_tokens=s["max_new"],
-              max_cache_len=4096 if not SMALL else 1024,
-              cache_dtype=jnp.float32, bucket_sizes=s["buckets"],
-              sync_every=s["sync"])
-    if paged:
-        kw.update(paged=True, block_size=s["block"])
-    engine = ContinuousBatcher(model, **kw)
+    engine = ContinuousBatcher(
+        model, batch_slots=s["slots"], max_new_tokens=s["max_new"],
+        max_cache_len=4096 if not SMALL else 1024, cache_dtype=jnp.float32,
+        bucket_sizes=s["buckets"], sync_every=s["sync"], block_size=s["block"])
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, s["vocab"], (n,)).astype(np.int32)
                for n in s["prompt_lens"]]
@@ -164,7 +160,6 @@ def probe_wave(model, s, paged: bool):
     admitted = gen + sum(p.size for p in prompts)
     report = engine.slo_report()
     return {
-        "mode": "paged" if paged else "contiguous",
         "wall_s": round(dt, 4),
         "tokens_per_sec": round(gen / dt, 1),
         "admitted_tokens": admitted,
@@ -174,10 +169,10 @@ def probe_wave(model, s, paged: bool):
         "ttft_s": [round(x, 5) for x in report["ttft_s"]],
         "tpot_s": [round(x, 6) for x in report["tpot_s"]],
         # Per-request lifecycle summary (telemetry/requests.py): TTFT/TPOT
-        # quantiles + the slowest-request table — summarize() hoists the
-        # paged wave's copy to detail.serving.requests (schema v11).
+        # quantiles + the slowest-request table — summarize() hoists it
+        # to detail.serving.requests (schema v11).
         "requests": engine.tracer.summary() if engine.tracer is not None else None,
-    }, [outs[r] for r in rids]
+    }, prompts, [outs[r] for r in rids]
 
 
 def probe_prefill_stall(model, s, mode: str):
@@ -196,8 +191,7 @@ def probe_prefill_stall(model, s, mode: str):
     engine = ContinuousBatcher(
         model, batch_slots=s["slots"], max_new_tokens=s["max_new"],
         max_cache_len=4096 if not SMALL else 1024, cache_dtype=jnp.float32,
-        bucket_sizes=buckets, sync_every=s["sync"], paged=True,
-        block_size=s["block"],
+        bucket_sizes=buckets, sync_every=s["sync"], block_size=s["block"],
         prefill_chunk=s["chunk"] if chunked else s["mono_bucket"],
         max_tokens_per_request=s["mono_bucket"] + s["max_new"] + s["chunk"],
     )
@@ -230,19 +224,21 @@ def summarize(model=None):
         model = _build_model(s)
     out = {"small": SMALL, "sync_every": s["sync"], "block_size": s["block"]}
     out.update(probe_decode_attention(s))
-    wave_c, outs_c = probe_wave(model, s, paged=False)
-    wave_p, outs_p = probe_wave(model, s, paged=True)
-    identical = all(np.array_equal(a, b) for a, b in zip(outs_c, outs_p))
+    import jax.numpy as jnp
+
+    from accelerate_tpu.generation import generate
+
+    wave_p, prompts, outs_p = probe_wave(model, s)
+    identical = all(
+        np.array_equal(got, np.asarray(generate(
+            model, prompt[None], max_new_tokens=s["max_new"], temperature=0.0,
+            include_prompt=False, cache_dtype=jnp.float32))[0])
+        for prompt, got in zip(prompts, outs_p))
     # The request-trace summary rides once at the top level (schema v11
-    # detail.serving.requests) — the paged wave is the production shape.
-    wave_c.pop("requests", None)
+    # detail.serving.requests).
     out["requests"] = wave_p.pop("requests", None)
-    out["wave_contiguous"] = wave_c
     out["wave_paged"] = wave_p
     out["outputs_identical"] = bool(identical)
-    out["effective_capacity_x"] = round(
-        wave_p["tokens_per_kv_slot"] / wave_c["tokens_per_kv_slot"], 2
-    )
     none, _ = probe_prefill_stall(model, s, mode="none")
     mono, _ = probe_prefill_stall(model, s, mode="monolithic")
     chk, _ = probe_prefill_stall(model, s, mode="chunked")
@@ -266,13 +262,12 @@ def main():
     for key in ("decode_attention_contiguous_ms", "decode_attention_paged_ms",
                 "gather_overhead_x"):
         print(json.dumps({"probe": key, "value": summary[key]}))
-    for key in ("wave_contiguous", "wave_paged", "prefill_no_admit",
+    for key in ("wave_paged", "prefill_no_admit",
                 "prefill_monolithic", "prefill_chunked"):
         print(json.dumps({"probe": key, **summary[key]}))
     print(json.dumps({
         "probe": "headline",
         "outputs_identical": summary["outputs_identical"],
-        "effective_capacity_x": summary["effective_capacity_x"],
         "stall_ratio_chunked_vs_monolithic":
             summary["stall_ratio_chunked_vs_monolithic"],
         "stall_ratio_chunked_vs_no_admit":

@@ -90,7 +90,7 @@ def _engine(model, s):
     return ContinuousBatcher(
         model, batch_slots=s["slots"], max_new_tokens=s["max_new"],
         max_cache_len=s["cache"], cache_dtype=jnp.float32,
-        bucket_sizes=s["buckets"], sync_every=s["sync"], paged=True,
+        bucket_sizes=s["buckets"], sync_every=s["sync"],
         block_size=s["block"], prefill_chunk=s["chunk"],
         max_tokens_per_request=max(s["prompt_lens"]) + s["max_new"] + s["chunk"],
     )

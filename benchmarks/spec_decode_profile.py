@@ -94,7 +94,7 @@ def probe_wave(model, s, k: int = 0, draft=None):
     kw = dict(batch_slots=s["slots"], max_new_tokens=s["max_new"],
               max_cache_len=4096 if not SMALL else 1024,
               cache_dtype=jnp.float32, bucket_sizes=s["buckets"],
-              sync_every=s["sync"], paged=True, block_size=s["block"])
+              sync_every=s["sync"], block_size=s["block"])
     if k:
         kw.update(speculative_k=k, draft_model=draft)
     engine = ContinuousBatcher(model, **kw)

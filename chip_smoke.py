@@ -289,7 +289,7 @@ def phase_serve(cfg: dict, seed: int, model, params, on_tpu: bool):
     phase = Phase("serve")
     max_new = 64
     engine = ContinuousBatcher(
-        model, params=params, paged=True, batch_slots=cfg["slots"], block_size=16,
+        model, params=params, batch_slots=cfg["slots"], block_size=16,
         max_new_tokens=max_new, max_cache_len=2048,
     )
     server = MetricsServer(0, host="127.0.0.1")

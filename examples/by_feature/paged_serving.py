@@ -1,6 +1,6 @@
 """Feature: paged KV-cache serving (see docs/serving.md).
 
-`ContinuousBatcher(paged=True)` end-to-end on a tiny Llama: a block pool with
+`ContinuousBatcher` end-to-end on a tiny Llama: a block pool with
 per-slot block tables, refcounted cross-request prefix sharing (set_prefix is
 just the degenerate case), chunked prefill interleaved with decode windows,
 and SLO-aware admission with per-request TTFT/TPOT accounting. The script
@@ -53,7 +53,6 @@ def main():
         cache_dtype=jnp.float32,
         bucket_sizes=(8, 16),
         sync_every=2,
-        paged=True,
         block_size=args.block_size,
         prefill_chunk=args.prefill_chunk,
         max_tokens_per_request=64,
@@ -90,7 +89,7 @@ def main():
     print("admission ledger:", json.dumps(report["decisions"]))
     print("pool:", json.dumps(engine.pool_stats()))
     print(f"peak consumed KV slots: {engine.kv_consumed_slots_peak} "
-          f"(contiguous equivalent would hold {args.slots} x every global column)")
+          f"of {engine.num_blocks * engine.block_size}")
     if report["ttft_s"]:
         print(f"TTFT p50 ~ {sorted(report['ttft_s'])[len(report['ttft_s']) // 2]:.4f}s "
               f"over {len(report['ttft_s'])} requests")

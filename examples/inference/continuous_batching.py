@@ -2,9 +2,10 @@
 
 The reference serves through ``model.generate`` one batch at a time — short
 requests wait for the longest row. ``ContinuousBatcher`` keeps a fixed set of
-decode slots, refills a slot the moment its sequence finishes, and (here) a
-system prompt shared by every request is prefilled ONCE via ``set_prefix`` —
-its prefill compute and cache columns are paid per wave, not per request.
+decode slots over a paged KV pool, refills a slot the moment its sequence
+finishes, and (here) a system prompt shared by every request is stored via
+``set_prefix`` — the first request prefills its blocks and the others alias
+them, so its prefill compute and pool blocks are paid once, not per request.
 
 Outputs stay exactly what solo ``generate(prefix + suffix)`` would produce,
 however requests interleave (pinned by tests/test_serving.py).
@@ -36,16 +37,18 @@ def main():
         model,
         batch_slots=2,              # decode this many requests concurrently
         max_new_tokens=8,
-        max_cache_len=512,          # total columns per wave (prefix + admits)
+        max_cache_len=512,          # the pool's token capacity
+        block_size=8,               # tokens per pool block
+        max_tokens_per_request=64,  # prefix + turn + completion
         eos_token_id=None,
-        bucket_sizes=(8, 16),       # admit programs compile per bucket
+        bucket_sizes=(8, 16),       # prefill-chunk programs compile per bucket
         sync_every=4,               # decode steps per host check
         cache_dtype=jnp.float32,
     )
 
     rng = np.random.default_rng(0)
     system_prompt = rng.integers(1, cfg.vocab_size, 24).astype(np.int32)
-    engine.set_prefix(system_prompt)  # prefilled once, shared by every slot
+    engine.set_prefix(system_prompt)  # prefilled once, its blocks shared by every request
 
     # Six ragged user turns; each submits only its suffix — and each may carry
     # its OWN generation controls (length / temperature / eos / stop
@@ -64,8 +67,10 @@ def main():
 
     for rid, turn in zip(rids, turns):
         print(f"request {rid}: {len(turn)}-token turn -> {outputs[rid].tolist()}")
-    print(f"cache columns used: {engine.cache_columns_used} "
-          f"(prefix paid once: {len(system_prompt)}); "
+    print(f"peak pool slots in use: {engine.kv_consumed_slots_peak} "
+          f"of {engine.num_blocks * engine.block_size} "
+          f"(prefix blocks aliased: {engine.slo_report()['decisions']['aliased_blocks']}); "
+          f"after the wave: {engine.cache_columns_used} in use, "
           f"utilization {engine.cache_utilization:.2f}")
 
 

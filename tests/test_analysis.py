@@ -236,30 +236,6 @@ def test_audit_attributes_collectives_to_axes():
     assert "fsdp" in by_axis and "dp" not in by_axis
 
 
-def test_serving_decode_audits_without_callbacks():
-    """The serving decode window is a built artifact too: no host callbacks,
-    and the cache/state donation the engine's memory story depends on is
-    visible to the auditor."""
-    from accelerate_tpu.serving import ContinuousBatcher
-
-    AcceleratorState._reset_state(reset_partial_state=True)
-    GradientState._reset_state()
-    cfg = LlamaConfig.tiny(
-        vocab_size=64, hidden_size=32, intermediate_size=64,
-        num_attention_heads=2, num_key_value_heads=2, num_hidden_layers=1,
-    )
-    model = Llama(cfg)
-    model.init_params(jax.random.key(0))
-    engine = ContinuousBatcher(
-        model, batch_slots=2, max_new_tokens=4, max_cache_len=64,
-        bucket_sizes=(8,), sync_every=2,
-    )
-    report = engine.audit_decode()
-    assert report.builder == "serving_decode"
-    assert report.host_callbacks == []
-    assert report.dp_allgathers == []
-
-
 def test_paged_serving_decode_audits_clean_with_pool_memory():
     """The PAGED decode window audits clean too (no host callbacks, no
     unclaimed dp collectives), its pool+state donation contract is visible,
@@ -277,7 +253,7 @@ def test_paged_serving_decode_audits_clean_with_pool_memory():
     model.init_params(jax.random.key(0))
     engine = ContinuousBatcher(
         model, batch_slots=2, max_new_tokens=4, max_cache_len=64,
-        bucket_sizes=(8,), sync_every=2, paged=True, block_size=4,
+        bucket_sizes=(8,), sync_every=2, block_size=4,
     )
     report = engine.audit_decode()
     assert report.builder == "serving_decode_paged"

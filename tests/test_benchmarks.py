@@ -78,10 +78,10 @@ def test_plan_step_time_benchmark_pp_not_slower_than_fsdp():
 
 
 def test_serving_decode_profile_smoke():
-    """The serving attribution harness (paged vs contiguous wave, chunked vs
-    monolithic prefill, op-level gather seam) runs end-to-end in small mode,
-    emits parseable probe lines, and its parity join really verified
-    identical outputs across cache modes. Ratios are recorded, not asserted —
+    """The serving attribution harness (a mixed wave against solo generate,
+    chunked vs monolithic prefill, op-level gather seam) runs end-to-end in
+    small mode, emits parseable probe lines, and its parity join really
+    verified each request's tokens against solo generate. Ratios are recorded, not asserted —
     small-mode wall times are dispatch/compile-dominated; the numbers mean
     something on a real chip (BENCH_SERVING=1)."""
     proc = subprocess.run(
@@ -97,9 +97,7 @@ def test_serving_decode_profile_smoke():
     records = [json.loads(line) for line in proc.stdout.strip().splitlines()]
     by_probe = {r["probe"]: r for r in records}
     assert by_probe["headline"]["outputs_identical"] is True
-    assert by_probe["headline"]["effective_capacity_x"] >= 1.3
-    assert by_probe["wave_paged"]["consumed_kv_slots_peak"] < \
-        by_probe["wave_contiguous"]["consumed_kv_slots_peak"]
+    assert by_probe["wave_paged"]["tokens_per_kv_slot"] > 0
     assert by_probe["prefill_chunked"]["prefill_dispatches"] > \
         by_probe["prefill_monolithic"]["prefill_dispatches"]
     assert by_probe["prefill_no_admit"]["prefill_dispatches"] == 1  # short only

@@ -5,7 +5,9 @@ Each example runs in its own subprocess on the virtual 8-device CPU mesh —
 pinned via ``jax.config`` inside the child (the env var alone is overridden by
 the TPU plugin at import time, see ``conftest.py``). Checkpoint-resume is
 exercised through ``complete_nlp_example`` and ``accelerate-tpu launch``
-through the flagship example.
+through the flagship example. The ``by_feature`` scripts run from
+``test_examples_by_feature.py``: the suite hands a file to one worker, and
+the two files take about the same time.
 """
 
 import os
@@ -79,48 +81,6 @@ def test_complete_cv_example_step_checkpointing(tmp_path):
         "--output_dir", out,
     )
     assert any(d.startswith("step_") for d in os.listdir(out)), os.listdir(out)
-
-
-@pytest.mark.parametrize(
-    "script,args",
-    [
-        ("by_feature/gradient_accumulation.py", []),
-        ("by_feature/checkpointing.py", []),
-        ("by_feature/tracking.py", []),
-        ("by_feature/profiler.py", []),
-        ("by_feature/cross_validation.py", ["--num_epochs", 2, "--num_folds", 2]),
-        ("by_feature/memory.py", []),
-        ("by_feature/early_stopping.py", []),
-        ("by_feature/multi_process_metrics.py", []),
-        ("by_feature/local_sgd.py", []),
-        ("by_feature/automatic_gradient_accumulation.py", []),
-        ("by_feature/schedule_free.py", ["--num_epochs", 8]),
-        ("by_feature/gradient_accumulation_for_autoregressive_models.py", ["--num_windows", 4]),
-        ("by_feature/megatron_style_gpt_pretraining.py", ["--tp", 2, "--pp", 2, "--num_steps", 6]),
-        ("by_feature/fsdp_with_peak_mem_tracking.py", ["--num_epochs", 4]),
-        ("by_feature/pipeline_training.py", ["--pp", 2, "--microbatches", 4, "--num_steps", 4]),
-        ("by_feature/pipeline_training.py", ["--pp", 2, "--microbatches", 4, "--num_steps", 4,
-                                             "--schedule", "1f1b"]),
-        ("by_feature/multi_slice_dcn.py", ["--slices", 2, "--tp", 2, "--num_steps", 4]),
-        # default --prefetch covers the toy epoch: the compute-free demo model
-        # gives the producer no device time to hide uploads in, so a shallower
-        # depth re-arms the example's h2d_blocking==0 assert as a load flake.
-        ("by_feature/dispatch_amortized_training.py", ["--window", 4]),
-        ("by_feature/elastic_training.py", []),
-        ("by_feature/paged_serving.py", ["--requests", 6]),
-    ],
-)
-def test_by_feature_examples(script, args, tmp_path):
-    extra = []
-    if "checkpointing" in script:
-        extra = ["--output_dir", str(tmp_path / "ckpt")]
-    elif "elastic" in script:
-        extra = ["--project_dir", str(tmp_path / "elastic")]
-    elif "tracking" in script:
-        extra = ["--project_dir", str(tmp_path / "proj")]
-    elif "profiler" in script:
-        extra = ["--trace_dir", str(tmp_path / "trace")]
-    run_example(script, *args, *extra)
 
 
 @pytest.mark.parametrize(

@@ -402,17 +402,17 @@ def test_cli_check_exits_1_on_tampered_golden(tmp_path, capsys):
 
 
 def test_cli_check_fails_on_missing_golden(tmp_path):
-    results, failures = run_fingerprints(["decode"], str(tmp_path), update=False)
-    assert results["decode"]["verdict"] == "missing-golden"
+    results, failures = run_fingerprints(["decode_paged"], str(tmp_path), update=False)
+    assert results["decode_paged"]["verdict"] == "missing-golden"
     assert failures and "--update" in failures[0]
 
 
 def test_cli_update_roundtrips(tmp_path):
-    results, failures = run_fingerprints(["decode"], str(tmp_path), update=True)
-    assert not failures and results["decode"]["verdict"] == "updated"
-    again, failures2 = run_fingerprints(["decode"], str(tmp_path), update=False)
-    assert not failures2 and again["decode"]["verdict"] == "match"
-    assert again["decode"]["hash"] == results["decode"]["hash"]
+    results, failures = run_fingerprints(["decode_paged"], str(tmp_path), update=True)
+    assert not failures and results["decode_paged"]["verdict"] == "updated"
+    again, failures2 = run_fingerprints(["decode_paged"], str(tmp_path), update=False)
+    assert not failures2 and again["decode_paged"]["verdict"] == "match"
+    assert again["decode_paged"]["hash"] == results["decode_paged"]["hash"]
 
 
 def test_goldens_committed_for_full_matrix():
@@ -429,9 +429,8 @@ def test_goldens_committed_for_full_matrix():
     assert _golden("step_zero")["zero"]["declared"] is True
     assert _golden("window4")["builder"] == "build_train_window"
     assert _golden("step_fsdp8")["mesh_axes"]["fsdp"] == 8
-    assert _golden("decode")["builder"] == "serving_decode"
-    # The paged decode window is drift-gated separately: its golden pins the
-    # block-table gather program and the pool+state donation contract.
+    # The decode window's golden pins the block-table gather program and the
+    # pool+state donation contract.
     assert _golden("decode_paged")["builder"] == "serving_decode_paged"
     assert _golden("decode_paged")["donation"]["expected_argnums"] == [1, 6]
     # The int8-pool decode golden pins the dequant-in-DMA kernel inventory —

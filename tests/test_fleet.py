@@ -298,7 +298,7 @@ def test_fleet_two_process_launcher_drill():
 def _paged(model, **overrides):
     kw = dict(batch_slots=2, max_new_tokens=8, max_cache_len=512,
               cache_dtype=jnp.float32, bucket_sizes=(8,), sync_every=2,
-              paged=True, block_size=4)
+              block_size=4)
     kw.update(overrides)
     return ContinuousBatcher(model, **kw)
 
@@ -392,20 +392,6 @@ def test_request_tracer_defer_and_cancel(llama):
     engine.reset()
     record = {r["rid"]: r for r in engine.tracer.records()}[rid]
     assert record["state"] == "cancelled"
-
-
-def test_contiguous_mode_traces_too(llama):
-    """The contiguous engine records admit (== first token) and finish."""
-    engine = ContinuousBatcher(llama, batch_slots=1, max_new_tokens=4,
-                               max_cache_len=128, cache_dtype=jnp.float32,
-                               bucket_sizes=(8,))
-    rid = engine.submit(np.arange(1, 6, dtype=np.int32))
-    engine.run()
-    record = {r["rid"]: r for r in engine.tracer.records()}[rid]
-    assert record["state"] == "finished"
-    assert record["decision"] == "admit"
-    assert record["ttft_s"] is not None
-    assert record["tokens_out"] == 4
 
 
 def test_traced_steady_state_loop_stays_nonblocking(llama):

@@ -462,29 +462,27 @@ def _llama_for_serving():
 
 def test_paged_serving_token_identity_on_kernel_backend(monkeypatch):
     """ACCELERATE_KERNELS=pallas (interpret on this rig): a mixed-length wave
-    through the paged engine stays token-identical to the contiguous engine,
-    and the decode program's audit inventory names the gather kernel."""
+    through the engine stays token-identical to solo generate(), and the
+    decode program's audit inventory names the gather kernel."""
     monkeypatch.setenv("ACCELERATE_KERNELS", "pallas")
+    from accelerate_tpu.generation import generate
     from accelerate_tpu.serving import ContinuousBatcher
 
     model = _llama_for_serving()
     rng = np.random.default_rng(200)
     prompts = [rng.integers(1, 256, (n,)).astype(np.int32)
                for n in (5, 9, 3, 12, 7, 4)]
-    contiguous = ContinuousBatcher(
-        model, batch_slots=2, max_new_tokens=8, max_cache_len=512,
-        cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-    )
     paged = ContinuousBatcher(
         model, batch_slots=2, max_new_tokens=8, max_cache_len=512,
         cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-        paged=True, block_size=4,
+        block_size=4,
     )
-    rc = [contiguous.submit(p) for p in prompts]
-    rp = [paged.submit(p) for p in prompts]
-    oc, op = contiguous.run(), paged.run()
-    for a, b in zip(rc, rp):
-        np.testing.assert_array_equal(op[b], oc[a])
+    rids = [paged.submit(p) for p in prompts]
+    outs = paged.run()
+    for rid, p in zip(rids, prompts):
+        solo = generate(model, p[None], max_new_tokens=8, temperature=0.0,
+                        cache_dtype=jnp.float32, include_prompt=False)
+        np.testing.assert_array_equal(outs[rid], np.asarray(solo)[0])
     report = paged.audit_decode()
     counts = report.kernel_counts()
     assert counts.get("paged_gather_kernel", 0) >= 2  # k and v assemblies
@@ -501,7 +499,7 @@ def test_paged_serving_explicit_off_stays_reference(monkeypatch):
     engine = ContinuousBatcher(
         model, batch_slots=2, max_new_tokens=4, max_cache_len=128,
         cache_dtype=jnp.float32, bucket_sizes=(8,), sync_every=2,
-        paged=True, block_size=4, kernels="off",
+        block_size=4, kernels="off",
     )
     assert engine.audit_decode().kernel_counts() == {}
 

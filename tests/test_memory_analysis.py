@@ -381,7 +381,6 @@ def test_memcheck_cli_serving_mode(tmp_path):
     assert payload["fits"] is True
     assert payload["kv_pool_bytes_per_device"] > 0
     assert payload["per_device_bytes"]["kv_pool"] == payload["kv_pool_bytes_per_device"]
-    assert payload["pool"]["paged"] is True
     assert payload["pool"]["num_blocks"] == 64
     starved = subprocess.run(
         base + ["--budget-gib", "0.0005"], capture_output=True, text=True, env=env,

@@ -53,7 +53,7 @@ def small():
 
 
 def engine_for(model, params, **overrides):
-    kw = dict(params=params, batch_slots=3, max_new_tokens=24, max_cache_len=3 * 1536, paged=True,
+    kw = dict(params=params, batch_slots=3, max_new_tokens=24, max_cache_len=3 * 1536,
               block_size=64, prefill_chunk=128, max_tokens_per_request=1400,
               cache_dtype=jnp.float32, bucket_sizes=(16, 32, 64, 128))
     kw.update(overrides)
@@ -454,14 +454,12 @@ def test_prefix_sharing_stands_down_for_a_model_that_carries_state(small):
     assert gaps.max() < 1e-4
 
 
-def test_speculative_decoding_chain_export_and_the_contiguous_engine_are_refused_in_words(small):
+def test_speculative_decoding_and_chain_export_are_refused_in_words(small):
     from accelerate_tpu.serving_net.handoff import export_chain
 
     model, params, _ = small
     with pytest.raises(ValueError, match="cannot roll back the recurrent state"):
         engine_for(model, params, speculative_k=2, draft_model=model)
-    with pytest.raises(ValueError, match="serves through the paged\\s+engine only"):
-        ContinuousBatcher(model, params=params, batch_slots=2, max_new_tokens=4, max_cache_len=256)
     engine = engine_for(model, params)
     engine.submit(prompts_of(20)[0], max_new_tokens=4)
     with pytest.raises(ValueError, match="carries recurrent state held by slot"):
@@ -469,7 +467,7 @@ def test_speculative_decoding_chain_export_and_the_contiguous_engine_are_refused
 
 
 # ---------------------------------------------------------- (g) fingerprints
-@pytest.mark.parametrize("config", ["decode", "decode_paged", "prefill_paged"])
+@pytest.mark.parametrize("config", ["decode_paged", "decode_paged_int8", "prefill_paged"])
 def test_the_llama_programs_fingerprints_did_not_move(config):
     from accelerate_tpu.analysis.fingerprint import canonical_json
     from accelerate_tpu.commands.fingerprint import extract_config

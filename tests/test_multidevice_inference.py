@@ -174,7 +174,7 @@ def test_continuous_batcher_sharded_matches_solo(llama):
     pmodel = _shard(llama, tp_size=2, fsdp_size=2)
     engine = ContinuousBatcher(pmodel, batch_slots=2, max_new_tokens=6,
                                max_cache_len=512, cache_dtype=jnp.float32,
-                               bucket_sizes=(8, 16), sync_every=2)
+                               bucket_sizes=(8, 16), sync_every=2, block_size=4)
     rids = [engine.submit(p) for p in prompts]
     outs = engine.run()
     for rid, ref in zip(rids, solos):

@@ -1,6 +1,6 @@
 """Continuous batching (``serving.ContinuousBatcher``): slot-refill serving
-over the KV cache. Correctness contract: greedy outputs are EXACTLY the solo
-``generate()`` output for each prompt, however requests interleave — the
+over the paged KV pool. Correctness contract: greedy outputs are EXACTLY the
+solo ``generate()`` output for each prompt, however requests interleave — the
 per-slot kv-mask holes and the rope/wpe position channel keep rows
 independent — and sampled outputs depend only on (engine rng, request id),
 not on traffic or slot assignment. Exceeds the reference, which serves whole
@@ -18,15 +18,34 @@ import jax.numpy as jnp
 
 from accelerate_tpu.generation import generate
 from accelerate_tpu.models import GPT2, GPT2Config, Llama, LlamaConfig
+from accelerate_tpu.models.gptx import GPTX, GPTXConfig
 from accelerate_tpu.serving import ContinuousBatcher, SLOTargets
 from accelerate_tpu.telemetry import get_span_ring, reset_spans
 
 
+TINY = dict(num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
+
+
 @pytest.fixture(scope="module")
 def llama():
-    model = Llama(LlamaConfig.tiny(num_hidden_layers=2, num_attention_heads=4,
-                                   num_key_value_heads=2))
+    model = Llama(LlamaConfig.tiny(**TINY))
     model.init_params(jax.random.key(0))
+    return model
+
+
+def _family(name):
+    """A tiny initialised model of one cached decoder family: rotary
+    (``llama``), rotary with a sliding window (``windowed``), learned absolute
+    positions (``gpt2``), parallel-residual rotary (``gptx``)."""
+    if name == "gpt2":
+        model, key = GPT2(GPT2Config.tiny()), 3
+    elif name == "gptx":
+        model, key = GPTX(GPTXConfig.tiny()), 5
+    elif name == "windowed":
+        model, key = Llama(LlamaConfig.tiny(sliding_window=4, **TINY)), 11
+    else:
+        model, key = Llama(LlamaConfig.tiny(**TINY)), 0
+    model.init_params(jax.random.key(key))
     return model
 
 
@@ -37,22 +56,78 @@ def _solo(model, prompt, max_new, eos=None):
     ))[0]
 
 
-@pytest.mark.parametrize("sync_every", [1, 4])
-def test_continuous_batching_matches_solo_greedy(llama, sync_every):
-    """6 ragged requests through 2 slots: each output token-identical to the
-    solo greedy decode, with slot refill mid-flight — at every host-sync
-    cadence (async decode windows change only hole placement)."""
+def _paged(model, **overrides):
+    kw = dict(batch_slots=2, max_new_tokens=8, max_cache_len=512,
+              cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
+              block_size=4)
+    kw.update(overrides)
+    return ContinuousBatcher(model, **kw)
+
+
+def _free_list_is_full(engine):
+    stats = engine.pool_stats()
+    return stats["blocks_free"] == stats["num_blocks"] and engine.cache_columns_used == 0
+
+
+def test_paged_false_is_refused_in_words(llama):
+    """The constructor still takes ``paged`` (the benchmark's configuration
+    files pass ``"paged": true``) and takes nothing but ``True``."""
+    for value in (False, None, 0):
+        with pytest.raises(ValueError, match="contiguous engine is gone"):
+            ContinuousBatcher(llama, batch_slots=1, max_new_tokens=4, max_cache_len=64,
+                              paged=value)
+
+
+def test_paged_true_and_its_absence_build_the_same_engine(llama):
+    """``paged=True`` selects nothing: geometry, pool and the decode
+    program's lowering are those of an engine built without the keyword, and
+    no ``paged`` attribute is left to ask about."""
+    kw = dict(batch_slots=2, max_new_tokens=4, max_cache_len=64, bucket_sizes=(8,),
+              cache_dtype=jnp.float32)
+    with_kw, without = ContinuousBatcher(llama, paged=True, **kw), ContinuousBatcher(llama, **kw)
+    assert with_kw.pool_stats() == without.pool_stats()
+    assert not hasattr(with_kw, "paged") and "paged" not in with_kw.pool_stats()
+    lowered = [e._decode().lower(*e._decode_args()).as_text() for e in (with_kw, without)]
+    assert lowered[0] == lowered[1]
+
+
+# One wave per family: prompt lengths, tokens to generate, engine geometry.
+SOLO_WAVES = {
+    "llama": ((5, 9, 3, 12, 7, 4), 8, dict(batch_slots=2, max_cache_len=512)),
+    # one slot: every request is admitted behind another's chain positions,
+    # and must still see learned positions 0..len-1
+    "gpt2": ((6, 3, 5), 5, dict(batch_slots=1, max_cache_len=128, bucket_sizes=(8,))),
+    "gptx": ((5, 9, 3, 12), 6, dict(batch_slots=2, max_cache_len=256)),
+    "windowed": ((7, 4, 9, 5), 6, dict(batch_slots=2, max_cache_len=512)),
+}
+
+
+@pytest.mark.parametrize("family,sync_every,block_size", [
+    ("llama", 1, 4), ("llama", 4, 4), ("llama", 1, 16), ("llama", 4, 16),
+    ("gpt2", 2, 4), ("gpt2", 2, 16), ("gptx", 2, 4),
+    ("windowed", 2, 4), ("windowed", 2, 16),
+])
+def test_continuous_batching_matches_solo_greedy(family, sync_every, block_size):
+    """Ragged requests through fewer slots than requests: each output is
+    token-identical to the solo greedy decode, with slot refill mid-flight —
+    at every host-sync cadence (decode windows change only hole placement)
+    and block size (block tables, gather views and scatter writes are pure
+    layout, never numerics; blocks of 16 hold a whole 8-token bucket and its
+    padding). GPT-2 pins the learned-position path (positions ride the
+    token-position channel, never the chain-slot index); sliding windows
+    measure valid-slot distance across the gathered view, so bucket-padding
+    holes inside chains never stretch the window."""
+    lengths, max_new, geometry = SOLO_WAVES[family]
+    model = _family(family)
     rng = np.random.default_rng(80)
-    prompts = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (5, 9, 3, 12, 7, 4)]
-    engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=8,
-                               max_cache_len=512, cache_dtype=jnp.float32,
-                               bucket_sizes=(8, 16), sync_every=sync_every)
+    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in lengths]
+    engine = _paged(model, max_new_tokens=max_new, sync_every=sync_every,
+                    block_size=block_size, **geometry)
     rids = [engine.submit(p) for p in prompts]
     outs = engine.run()
     for rid, p in zip(rids, prompts):
-        ref = _solo(llama, p, 8)
-        np.testing.assert_array_equal(outs[rid], ref[: len(outs[rid])], err_msg=f"rid {rid}")
-        assert all(x == 0 for x in ref[len(outs[rid]):])
+        np.testing.assert_array_equal(outs[rid], _solo(model, p, max_new), err_msg=f"rid {rid}")
+    assert _free_list_is_full(engine)
 
 
 def test_continuous_batching_eos_frees_slots_early(llama):
@@ -74,27 +149,8 @@ def test_continuous_batching_eos_frees_slots_early(llama):
     assert any((outs[r] == eos).any() for r in rids)  # early stop exercised
 
 
-def test_continuous_batching_gpt2_absolute_positions():
-    """GPT-2's learned wpe is the hard case: a request admitted mid-stream at
-    a large global cache offset must still see positions 0..len-1."""
-    model = GPT2(GPT2Config(vocab_size=128, hidden_size=32, num_hidden_layers=2,
-                            num_attention_heads=2, max_position_embeddings=64))
-    model.init_params(jax.random.key(3))
-    rng = np.random.default_rng(82)
-    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 3, 5)]
-    engine = ContinuousBatcher(model, batch_slots=1, max_new_tokens=5,
-                               max_cache_len=64, cache_dtype=jnp.float32,
-                               bucket_sizes=(8,))
-    rids = [engine.submit(p) for p in prompts]
-    outs = engine.run()
-    for rid, p in zip(rids, prompts):
-        np.testing.assert_array_equal(
-            outs[rid], _solo(model, p, 5)[: len(outs[rid])], err_msg=f"rid {rid}"
-        )
-
-
 def test_continuous_batching_no_recompile_across_requests(llama):
-    """Shapes never depend on traffic: one decode program, one admit program
+    """Shapes never depend on traffic: one decode program, one chunk program
     per bucket, regardless of how many requests flow through."""
     engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=4,
                                max_cache_len=512, cache_dtype=jnp.float32,
@@ -103,38 +159,9 @@ def test_continuous_batching_no_recompile_across_requests(llama):
     for _ in range(5):
         engine.submit(rng.integers(1, 256, (5,)).astype(np.int32))
     engine.run()
-    assert list(engine._admit_fns) == [(8, 0)]  # (bucket, prefix columns)
-    admit_compiles = engine._admit_fns[(8, 0)]._cache_size()
-    decode_compiles = engine._decode_fn._cache_size()
-    assert admit_compiles == 1 and decode_compiles == 1
-
-
-def test_continuous_batching_capacity_compaction_and_guards(llama):
-    """Auto-compaction: the retired first request's columns are reclaimed at
-    the backpressure point, so a cache sized for ONE request serves a queue
-    of them in a single run() (this scenario raised and required reset()
-    before r5's compact()). A cache too small for even one request still
-    dead-ends loudly — compaction has nothing to reclaim there."""
-    engine = ContinuousBatcher(llama, batch_slots=1, max_new_tokens=8,
-                               max_cache_len=16, cache_dtype=jnp.float32,
-                               bucket_sizes=(8,), sync_every=1)
-    p = np.arange(1, 6, dtype=np.int32)
-    r1 = engine.submit(p)
-    r2 = engine.submit(p)  # only fits after r1's columns are compacted away
-    outs = engine.run()
-    assert set(outs) == {r1, r2}
-    np.testing.assert_array_equal(outs[r1], outs[r2])  # same prompt
-    np.testing.assert_array_equal(outs[r1], _solo(llama, p, 8)[: len(outs[r1])])
-    with pytest.raises(ValueError, match="bucket"):
-        engine.submit(np.arange(1, 11, dtype=np.int32))  # > largest bucket
-    tiny = ContinuousBatcher(llama, batch_slots=1, max_new_tokens=8,
-                             max_cache_len=12, cache_dtype=jnp.float32,
-                             bucket_sizes=(8,), sync_every=1)
-    tiny.submit(p)
-    with pytest.raises(RuntimeError, match="capacity"):
-        tiny.run()
-    # (sliding-window models are no longer rejected — valid-slot-distance
-    # windows serve them exactly: test_windowed_model_serves_exactly)
+    assert list(engine._chunk_fns) == [8]  # the one bucket
+    assert engine._chunk_fns[8]._cache_size() == 1
+    assert engine._decode_fn._cache_size() == 1
 
 
 def test_continuous_batching_sampled_streams_are_traffic_independent(llama):
@@ -160,60 +187,62 @@ def test_continuous_batching_sampled_streams_are_traffic_independent(llama):
     assert any(not np.array_equal(a[r], c[r]) for r in rids)
 
 
-@pytest.mark.parametrize("family", ["llama", "gpt2"])
-def test_prefix_caching_matches_solo_concat(llama, family):
+@pytest.mark.parametrize("family,block_size", [
+    ("llama", 4), ("llama", 16), ("gpt2", 4), ("gptx", 4)])
+def test_prefix_caching_matches_solo_concat(family, block_size):
     """set_prefix: requests submit only suffixes, and each greedy output is
-    token-identical to solo generate(prefix + suffix). GPT-2 pins the
-    absolute-position (wpe) path; slot refills cross the eviction path, so
-    exactness also proves eviction spares the prefix columns."""
-    if family == "llama":
-        model = llama
-    else:
-        model = GPT2(GPT2Config.tiny(num_hidden_layers=2))
-        model.init_params(jax.random.key(3))
+    token-identical to solo generate(prefix + suffix). With blocks of 4 the
+    first request's 8-token chunk fills whole blocks of the 12-token prefix,
+    and staggered admissions REUSE them (the aliased_blocks ledger proves
+    sharing engaged, not just correctness); a block of 16 is never filled by
+    a chunk of 8, so nothing is shared and every request prefills its own
+    copy — same tokens. GPT-2 pins the learned-position path. A second wave
+    through the same engine crosses the free/realloc path and stays exact."""
+    model = _family(family)
     rng = np.random.default_rng(90)
-    prefix = rng.integers(1, 256, (11,)).astype(np.int32)
-    suffixes = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (4, 7, 3, 6, 5)]
-    # GPT-2's learned table caps the cache length at max_position_embeddings.
-    engine = ContinuousBatcher(model, batch_slots=2, max_new_tokens=6,
-                               max_cache_len=512 if family == "llama" else 128,
-                               cache_dtype=jnp.float32,
-                               bucket_sizes=(8,), sync_every=2)
-    assert engine.set_prefix(prefix) == 11
-    assert engine._host_pos == 11  # prefix columns paid once, not per request
-    rids = [engine.submit(s) for s in suffixes]
-    outs = engine.run()
-    for rid, s in zip(rids, suffixes):
-        ref = _solo(model, np.concatenate([prefix, s]), 6)
-        np.testing.assert_array_equal(outs[rid], ref[: len(outs[rid])], err_msg=f"rid {rid}")
-        assert all(x == 0 for x in ref[len(outs[rid]):])
+    prefix = rng.integers(1, 256, (12,)).astype(np.int32)
+    sufs = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (4, 7, 3, 6, 5)]
+    engine = _paged(model, max_new_tokens=6, max_cache_len=256, bucket_sizes=(8,),
+                    block_size=block_size, prefill_chunk=8, max_tokens_per_request=64)
+    assert engine.set_prefix(prefix) == 12
+    for wave in (sufs, sufs[:2]):
+        rids = [engine.submit(s) for s in wave]
+        outs = engine.run()
+        for rid, s in zip(rids, wave):
+            np.testing.assert_array_equal(
+                outs[rid], _solo(model, np.concatenate([prefix, s]), 6), err_msg=f"rid {rid}")
+        assert _free_list_is_full(engine)
+    assert (engine.slo_report()["decisions"]["aliased_blocks"] > 0) == (block_size == 4)
 
 
 def test_prefix_caching_survives_reset_and_guards(llama):
-    """reset() re-prefills the prefix (so the capacity-retry flow stays
-    exact); reset(keep_prefix=False) drops it; set_prefix demands a fresh
+    """reset() keeps the prefix tokens (so the capacity-retry flow stays
+    exact); reset(keep_prefix=False) drops them; set_prefix demands a fresh
     cache and rejects degenerate lengths."""
     rng = np.random.default_rng(91)
     prefix = rng.integers(1, 256, (10,)).astype(np.int32)
     engine = ContinuousBatcher(llama, batch_slots=1, max_new_tokens=4,
                                max_cache_len=128, cache_dtype=jnp.float32,
-                               bucket_sizes=(8,))
+                               bucket_sizes=(8,), max_tokens_per_request=32)
     engine.set_prefix(prefix)
     with pytest.raises(RuntimeError, match="fresh cache"):
         engine.set_prefix(prefix)  # prefix already in place
     suffix = rng.integers(1, 256, (5,)).astype(np.int32)
     r1 = engine.submit(suffix)
     out1 = engine.run()[r1]
-    engine.reset()  # keep_prefix=True default: re-prefilled
-    assert engine._pfx == 10 and engine._host_pos == 10
+    np.testing.assert_array_equal(out1, _solo(llama, np.concatenate([prefix, suffix]), 4))
+    engine.reset()  # keep_prefix=True default: the tokens stay
+    np.testing.assert_array_equal(engine._prefix_tokens, prefix)
     r2 = engine.submit(suffix)
     np.testing.assert_array_equal(engine.run()[r2], out1)
     engine.reset(keep_prefix=False)
-    assert engine._pfx == 0 and engine._host_pos == 0
+    assert engine._prefix_tokens is None
+    r3 = engine.submit(suffix)  # the suffix alone now
+    np.testing.assert_array_equal(engine.run()[r3], _solo(llama, suffix, 4))
     with pytest.raises(ValueError, match="empty"):
         engine.set_prefix(np.zeros((0,), np.int32))
     with pytest.raises(ValueError, match="no room"):
-        engine.set_prefix(np.arange(1, 125, dtype=np.int32))
+        engine.set_prefix(np.arange(1, 30, dtype=np.int32))
 
 
 def test_continuous_batching_waves_return_only_new_results(llama):
@@ -227,6 +256,161 @@ def test_continuous_batching_waves_return_only_new_results(llama):
     second = [engine.submit(rng.integers(1, 256, (5,)).astype(np.int32)) for _ in range(2)]
     w2 = engine.run()
     assert set(w2) == set(second)  # wave 1 results not replayed
+
+
+def _windowed_waves_engine(block_size):
+    """A sliding-window model with a shared 8-token prefix over a pool of 64
+    token slots, about what one wave of two slots reserves: every later wave
+    runs on blocks an earlier one gave back."""
+    model = Llama(LlamaConfig.tiny(sliding_window=5, **TINY))
+    model.init_params(jax.random.key(21))
+    rng = np.random.default_rng(102)
+    prefix = rng.integers(1, 256, (8,)).astype(np.int32)
+    waves = [[rng.integers(1, 256, (n,)).astype(np.int32) for n in lengths]
+             for lengths in ((5, 7, 4, 6), (3, 8, 5), (7, 2, 6, 4, 5))]
+    engine = _paged(model, max_new_tokens=6, max_cache_len=64, bucket_sizes=(8,),
+                    block_size=block_size, prefill_chunk=8, max_tokens_per_request=32)
+    engine.set_prefix(prefix)
+    return model, engine, prefix, waves
+
+
+@pytest.mark.parametrize("block_size", [4, 16])
+def test_waves_on_one_engine_reuse_the_pool_without_reset(block_size):
+    """Three waves of heterogeneous lengths on ONE engine, never reset: a
+    finished request's chain returns to the free list as its report is read,
+    so each wave finds the whole pool again, the blocks it is handed have
+    held other requests' keys (stale mask bits hidden by the chain
+    frontier), and every output is still solo generate(prefix + suffix) —
+    the hardest layout case: rope baked into K, valid-distance windows, a
+    shared prefix whose blocks are aliased (blocks of 4) or private copies
+    (blocks of 16)."""
+    model, engine, prefix, waves = _windowed_waves_engine(block_size)
+    for wave in waves:
+        rids = [engine.submit(s) for s in wave]
+        outs = engine.run()
+        for rid, s in zip(rids, wave):
+            np.testing.assert_array_equal(
+                outs[rid], _solo(model, np.concatenate([prefix, s]), 6), err_msg=f"rid {rid}")
+        assert _free_list_is_full(engine)
+        assert sorted(engine._free_blocks) == list(range(1, engine.num_blocks + 1))
+        assert not engine._share_index and not engine._block_ref.any()
+    # One wave's two slots took most of the pool at once, so the later waves
+    # ran on blocks that had been given back.
+    assert engine.kv_consumed_slots_peak >= 0.75 * engine.num_blocks * block_size
+    assert (engine.slo_report()["decisions"]["aliased_blocks"] > 0) == (block_size == 4)
+
+
+def test_cache_utilization_holds_its_level_across_waves():
+    """``cache_utilization`` is valid tokens over the slots chains hold, read
+    while they hold them (here: at every stream event): what is not a token
+    is a final chunk's bucket padding, a finished row's masked decode writes
+    and the part of a reservation not written yet. Chains free whole, so the
+    level a wave runs at does not sink from wave to wave on the same engine,
+    and an idle engine reads 1.0."""
+    _, engine, _, waves = _windowed_waves_engine(4)
+    seen = []
+    engine.stream = lambda rid, tokens, final: seen.append(engine.cache_utilization)
+    assert engine.cache_utilization == 1.0  # fresh engine
+    levels = []
+    for _ in range(3):
+        for s in waves[0]:  # the same wave each time
+            engine.submit(s)
+        del seen[:]
+        engine.run()
+        assert seen and all(0.0 < u <= 1.0 for u in seen)
+        levels.append(min(seen))
+        assert engine.cache_utilization == 1.0 and engine.cache_columns_used == 0
+    assert levels[1] >= levels[0] and levels[2] >= levels[0], levels
+    assert levels[0] > 0.2, levels  # tokens, not padding, are most of what chains hold
+
+
+def test_peak_consumed_slots_are_the_chains_reservations(llama):
+    """``kv_consumed_slots_peak`` counts what chains reserved at once, not a
+    slots-by-columns rectangle: with four slots over eight mixed requests it
+    is a whole number of blocks, at least the largest single reservation and
+    at most the four largest together (bucketed prompt + max_new - 1 + three
+    windows of slack, rounded up to blocks), and it outlives the wave."""
+    rng = np.random.default_rng(205)
+    prompts = [rng.integers(1, 256, (n,)).astype(np.int32)
+               for n in (5, 14, 3, 12, 7, 4, 9, 6)]
+    engine = _paged(llama, batch_slots=4, max_cache_len=1024)
+    for p in prompts:
+        engine.submit(p)
+    engine.run()
+    bs, slack = engine.block_size, 3 * engine.sync_every
+    reserved = sorted(-(-(engine._bucket(p.size) + 8 - 1 + slack) // bs) * bs for p in prompts)
+    peak = engine.kv_consumed_slots_peak
+    assert peak % bs == 0 and reserved[-1] <= peak <= sum(reserved[-4:])
+    assert engine.cache_columns_used == 0
+
+
+def test_a_dead_end_with_a_prefix_set_recovers_through_reset_and_run(llama):
+    """The loop raises its capacity dead end when a request waits and every
+    slot is free. A request that reaches the queue just after a turn's admit,
+    on an idle engine, is refused that way (a known fault: the queue is read
+    after admission ran). The documented way out holds with a shared prefix
+    in place: the queued request and the prefix tokens survive ``reset()``,
+    and ``run()`` then serves generate(prefix + suffix)."""
+    rng = np.random.default_rng(104)
+    prefix = rng.integers(1, 256, (10,)).astype(np.int32)
+    suffix = rng.integers(1, 256, (5,)).astype(np.int32)
+    engine = _paged(llama, max_new_tokens=6, max_tokens_per_request=32)
+    engine.set_prefix(prefix)
+    admit, arrived = engine._admit_paged, []
+
+    def admit_then_arrive(now):
+        admit(now)
+        if not arrived:
+            arrived.append(engine.submit(suffix))
+
+    engine._admit_paged = admit_then_arrive
+    with pytest.raises(RuntimeError, match="capacity exhausted"):
+        engine.run()
+    engine._admit_paged = admit
+    assert engine.in_flight() == 1  # still queued
+    engine.reset()
+    out = engine.run()[arrived[0]]
+    np.testing.assert_array_equal(out, _solo(llama, np.concatenate([prefix, suffix]), 6))
+    assert _free_list_is_full(engine)
+
+
+def test_submit_refuses_a_prompt_past_the_request_ceiling(llama):
+    """A prompt may exceed the largest bucket (it is chunked) but not
+    ``max_tokens_per_request`` less the output it reserves; a shared prefix
+    counts as part of every prompt, and a smaller per-request
+    ``max_new_tokens`` leaves that much more room."""
+    engine = _paged(llama, max_new_tokens=8, bucket_sizes=(8,), max_tokens_per_request=40)
+    engine.submit(np.arange(1, 33, dtype=np.int32))  # 32 + 8 = 40: four chunks of 8
+    with pytest.raises(ValueError, match="exceeds max_tokens_per_request=40"):
+        engine.submit(np.arange(1, 34, dtype=np.int32))
+    engine.submit(np.arange(1, 34, dtype=np.int32), max_new_tokens=7)
+    engine.reset()
+    engine._queue.clear()
+    engine.set_prefix(np.arange(1, 11, dtype=np.int32))
+    engine.submit(np.arange(1, 23, dtype=np.int32))  # 10 + 22 + 8
+    with pytest.raises(ValueError, match="incl. prefix"):
+        engine.submit(np.arange(1, 24, dtype=np.int32))
+
+
+def test_prefix_match_tokens_reads_the_resident_shared_blocks(llama):
+    """The router's affinity answer is a host-side lookup in the shared-block
+    index: while a request that prefilled the prefix is in flight its whole
+    blocks are resident and a prompt that starts with them matches that many
+    tokens (capped one token short of the prompt, whose last token always
+    runs through a chunk); nothing matches before the first chunk has landed
+    or once the wave is over and the blocks are back on the free list."""
+    rng = np.random.default_rng(105)
+    prefix = rng.integers(1, 256, (8,)).astype(np.int32)
+    engine = _paged(llama, bucket_sizes=(8,), prefill_chunk=8, max_tokens_per_request=32)
+    engine.set_prefix(prefix)
+    seen = []
+    engine.stream = lambda rid, tokens, final: seen.append(
+        (engine.prefix_match_tokens([5, 6, 7]), engine.prefix_match_tokens([])))
+    engine.submit(rng.integers(1, 256, (5,)).astype(np.int32))
+    assert engine.prefix_match_tokens([5, 6, 7]) == 0  # nothing resident yet
+    engine.run()
+    assert seen and all(match == (8, 4) for match in seen), seen
+    assert engine.prefix_match_tokens([5, 6, 7]) == 0 and not engine._share_index
 
 
 # --------------------------------------------------- per-request controls (r5)
@@ -312,47 +496,6 @@ def test_stop_sequences_truncate_exactly(llama, sync_every):
         engine.submit(prompts[0], stop_sequences=[[]])
 
 
-def test_windowed_model_serves_exactly():
-    """Sliding-window models serve exactly: cached_attention measures windows
-    in valid-slot distance, so the slot scheme's holes don't stretch the
-    window (VERDICT r4 missing #3 closed)."""
-    model = Llama(LlamaConfig.tiny(num_hidden_layers=2, num_attention_heads=4,
-                                   num_key_value_heads=2, sliding_window=4))
-    model.init_params(jax.random.key(11))
-    rng = np.random.default_rng(98)
-    prompts = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (7, 4, 9, 5)]
-    engine = ContinuousBatcher(model, batch_slots=2, max_new_tokens=6,
-                               max_cache_len=512, cache_dtype=jnp.float32,
-                               bucket_sizes=(8, 16), sync_every=2)
-    rids = [engine.submit(p) for p in prompts]
-    outs = engine.run()
-    for rid, p in zip(rids, prompts):
-        ref = _solo(model, p, 6)
-        np.testing.assert_array_equal(outs[rid], ref[: len(outs[rid])], err_msg=f"rid {rid}")
-
-
-def test_cache_utilization_decays_across_wave(llama):
-    """The documented capacity trade, now measured: under heterogeneous
-    request lengths the fraction of consumed cache area holding valid tokens
-    decays (holes from eviction + inactive-row writes are never reclaimed
-    until reset()). The number motivates sizing max_cache_len to total wave
-    tokens; see PERF.md for the recorded figure."""
-    rng = np.random.default_rng(99)
-    engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=8,
-                               max_cache_len=1024, cache_dtype=jnp.float32,
-                               bucket_sizes=(8, 16), sync_every=2)
-    assert engine.cache_utilization == 1.0  # fresh engine
-    short = [engine.submit(rng.integers(1, 256, (3,)).astype(np.int32),
-                           max_new_tokens=2) for _ in range(3)]
-    long = [engine.submit(rng.integers(1, 256, (14,)).astype(np.int32))
-            for _ in range(3)]
-    engine.run()
-    u = engine.cache_utilization
-    assert 0.0 < u < 0.9, u  # real decay measured, not a degenerate value
-    engine.reset()
-    assert engine.cache_utilization == 1.0  # reclaimed
-
-
 def test_capacity_reservation_covers_longest_active_request(llama):
     """A short admit must reserve for the LONGEST remaining active run, not
     its own max_new: decode columns are consumed globally until the longest
@@ -401,7 +544,8 @@ def test_prefix_caching_composes_with_per_request_controls(llama):
     solos = [_solo(llama, np.concatenate([prefix, s]), 8) for s in sufs]
     engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=8,
                                max_cache_len=512, cache_dtype=jnp.float32,
-                               bucket_sizes=(8,), sync_every=2)
+                               bucket_sizes=(8,), sync_every=2,
+                               max_tokens_per_request=32)
     engine.set_prefix(prefix)
     r0 = engine.submit(sufs[0], max_new_tokens=3)
     r1 = engine.submit(sufs[1], temperature=0.0)
@@ -418,144 +562,7 @@ def test_prefix_caching_composes_with_per_request_controls(llama):
     np.testing.assert_array_equal(outs[r2], solos[2][: min(ends)])
 
 
-def test_compaction_preserves_exactness_with_prefix_and_windows():
-    """compact() mid-service: outputs stay token-identical to solo decode for
-    a SLIDING-WINDOW model with a shared prefix — the hardest layout case
-    (rope baked into K, valid-distance windows, prefix pinned at the cache
-    head). Three waves through a cache sized for ~one wave."""
-    model = Llama(LlamaConfig.tiny(num_hidden_layers=2, num_attention_heads=4,
-                                   num_key_value_heads=2, sliding_window=5))
-    model.init_params(jax.random.key(21))
-    rng = np.random.default_rng(102)
-    prefix = rng.integers(1, 256, (6,)).astype(np.int32)
-    sufs = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (5, 7, 4, 6, 5, 7)]
-    engine = ContinuousBatcher(model, batch_slots=2, max_new_tokens=6,
-                               max_cache_len=64, cache_dtype=jnp.float32,
-                               bucket_sizes=(8,), sync_every=2)
-    engine.set_prefix(prefix)
-    rids = [engine.submit(s) for s in sufs]
-    outs = engine.run()  # compaction triggers under this capacity
-    for rid, s in zip(rids, sufs):
-        ref = _solo(model, np.concatenate([prefix, s]), 6)
-        np.testing.assert_array_equal(outs[rid], ref[: len(outs[rid])], err_msg=f"rid {rid}")
-    assert engine._pfx == 6  # prefix survived compaction at the cache head
-
-
-def test_explicit_compact_reclaims_columns(llama):
-    """compact() between waves reclaims the holes the utilization metric
-    measures, without reset() (results and queue untouched)."""
-    engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=6,
-                               max_cache_len=512, cache_dtype=jnp.float32,
-                               bucket_sizes=(8, 16), sync_every=2)
-    rng = np.random.default_rng(103)
-    rids = [engine.submit(rng.integers(1, 256, (n,)).astype(np.int32))
-            for n in (5, 12, 7, 4)]
-    engine.run()
-    used_before = engine.cache_columns_used
-    freed = engine.compact()
-    assert freed > 0 and engine.cache_columns_used == used_before - freed
-    assert engine.cache_utilization >= 0.4  # retired holes reclaimed
-    # The engine still serves exactly after an explicit compact.
-    p = rng.integers(1, 256, (6,)).astype(np.int32)
-    r = engine.submit(p)
-    out = engine.run()[r]
-    np.testing.assert_array_equal(out, _solo(llama, p, 6)[: len(out)])
-
-
-# ------------------------------------------------------- paged KV cache (r13)
-
-
-def _paged(model, **overrides):
-    kw = dict(batch_slots=2, max_new_tokens=8, max_cache_len=512,
-              cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-              paged=True, block_size=4)
-    kw.update(overrides)
-    return ContinuousBatcher(model, **kw)
-
-
-@pytest.mark.parametrize("sync_every", [1, 4])
-def test_paged_matches_contiguous_and_solo(llama, sync_every):
-    """The tentpole contract: a mixed-length wave through the paged engine is
-    token-identical to the contiguous engine AND to per-request solo greedy
-    decode, at every sync cadence — block tables, gather views, and scatter
-    writes are pure layout, never numerics."""
-    rng = np.random.default_rng(200)
-    prompts = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (5, 9, 3, 12, 7, 4)]
-    contiguous = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=8,
-                                   max_cache_len=512, cache_dtype=jnp.float32,
-                                   bucket_sizes=(8, 16), sync_every=sync_every)
-    paged = _paged(llama, sync_every=sync_every)
-    rc = [contiguous.submit(p) for p in prompts]
-    rp = [paged.submit(p) for p in prompts]
-    oc, op = contiguous.run(), paged.run()
-    for a, b, p in zip(rc, rp, prompts):
-        np.testing.assert_array_equal(op[b], oc[a], err_msg=f"prompt {p[:3]}")
-        ref = _solo(llama, p, 8)
-        np.testing.assert_array_equal(op[b], ref[: len(op[b])])
-
-
-def test_paged_gpt2_absolute_positions():
-    """Learned-wpe models stay exact on paged chains: positions ride the
-    token-position channel, never the chain-slot index."""
-    model = GPT2(GPT2Config(vocab_size=128, hidden_size=32, num_hidden_layers=2,
-                            num_attention_heads=2, max_position_embeddings=64))
-    model.init_params(jax.random.key(3))
-    rng = np.random.default_rng(201)
-    prompts = [rng.integers(1, 128, (n,)).astype(np.int32) for n in (6, 3, 5)]
-    engine = _paged(model, batch_slots=1, max_new_tokens=5, max_cache_len=64,
-                    bucket_sizes=(8,))
-    rids = [engine.submit(p) for p in prompts]
-    outs = engine.run()
-    for rid, p in zip(rids, prompts):
-        np.testing.assert_array_equal(
-            outs[rid], _solo(model, p, 5)[: len(outs[rid])], err_msg=f"rid {rid}"
-        )
-
-
-def test_paged_windowed_model_serves_exactly():
-    """Sliding windows measure valid-slot distance across the gathered view,
-    so bucket-padding holes inside chains never stretch the window."""
-    model = Llama(LlamaConfig.tiny(num_hidden_layers=2, num_attention_heads=4,
-                                   num_key_value_heads=2, sliding_window=4))
-    model.init_params(jax.random.key(11))
-    rng = np.random.default_rng(202)
-    prompts = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (7, 4, 9, 5)]
-    engine = _paged(model, max_new_tokens=6)
-    rids = [engine.submit(p) for p in prompts]
-    outs = engine.run()
-    for rid, p in zip(rids, prompts):
-        ref = _solo(model, p, 6)
-        np.testing.assert_array_equal(outs[rid], ref[: len(outs[rid])], err_msg=f"rid {rid}")
-
-
-def test_paged_prefix_aliasing_matches_solo_concat(llama):
-    """set_prefix generalized to refcounted block aliasing: staggered
-    admissions REUSE the first request's resident prefix blocks (the
-    aliased_blocks ledger proves sharing engaged, not just correctness), and
-    every output equals solo generate(prefix + suffix). A second wave through
-    the same engine crosses the free/realloc path — paged 'compaction' —
-    and stays exact."""
-    rng = np.random.default_rng(203)
-    prefix = rng.integers(1, 256, (12,)).astype(np.int32)
-    sufs = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (4, 7, 3, 6)]
-    engine = _paged(llama, max_new_tokens=6, bucket_sizes=(8,), prefill_chunk=8,
-                    max_tokens_per_request=64)
-    assert engine.set_prefix(prefix) == 12
-    rids = [engine.submit(s) for s in sufs]
-    outs = engine.run()
-    for rid, s in zip(rids, sufs):
-        ref = _solo(llama, np.concatenate([prefix, s]), 6)
-        np.testing.assert_array_equal(outs[rid], ref[: len(outs[rid])], err_msg=f"rid {rid}")
-    # Requests 3 and 4 were admitted after request 1's aligned chunk landed:
-    # its full prefix blocks were aliased, not re-prefilled.
-    assert engine.slo_report()["decisions"]["aliased_blocks"] > 0
-    # Wave 2: chains freed at collect, blocks reallocated — the paged analog
-    # of the contiguous engine's post-compaction wave.
-    rids2 = [engine.submit(s) for s in sufs[:2]]
-    outs2 = engine.run()
-    for rid, s in zip(rids2, sufs[:2]):
-        ref = _solo(llama, np.concatenate([prefix, s]), 6)
-        np.testing.assert_array_equal(outs2[rid], ref[: len(outs2[rid])])
+# ------------------------------------------------------- the block pool (r13)
 
 
 def test_paged_chunked_prefill_exact_and_bounds_stall(llama):
@@ -593,9 +600,10 @@ def test_paged_chunked_prefill_exact_and_bounds_stall(llama):
 
 def test_paged_steady_state_loop_has_zero_blocking_transfers(llama):
     """The one-window-lookahead sync: each window's report is fetched only
-    after the NEXT window is dispatched, so the steady-state engine loop
-    performs zero blocking device→host fetches and zero blocking input
-    transfers (the final drain may block once)."""
+    after the NEXT window is dispatched, so the loop never waits to hand an
+    input to the device and fetches a fixed number of arrays a window.
+    Whether a fetch found its array ready (``blocking``) is the CPU's timing
+    and is not pinned."""
     from accelerate_tpu.utils.transfer import reset_transfer_stats, transfer_stats
 
     engine = _paged(llama, batch_slots=1, max_new_tokens=24, bucket_sizes=(8,),
@@ -605,37 +613,12 @@ def test_paged_steady_state_loop_has_zero_blocking_transfers(llama):
     out = engine.run()[rid]
     stats = transfer_stats()
     assert stats["h2d_blocking"] == 0
-    assert stats["blocking"] <= 1, stats  # drain only; steady state adds none
-    assert stats["fetches"] >= 10  # the sync really ran every window
+    # 24 tokens in windows of 2: the first token comes from the chunk, the
+    # other 23 take 12 windows and the finish shows in the 12th report, read
+    # with a 13th window in flight; a report costs two fetches (active, n_out)
+    # and the finish one more (the output buffer).
+    assert stats["fetches"] == 2 * 13 + 1, stats
     np.testing.assert_array_equal(out, _solo(llama, np.arange(1, 6, dtype=np.int32), 24))
-
-
-def test_paged_effective_capacity_exceeds_contiguous(llama):
-    """The capacity headline: on a mixed-length wave at IDENTICAL outputs,
-    admitted tokens per consumed KV slot (bytes per slot are equal across
-    modes) improve >= 1.3x over the contiguous cache — chains consume per
-    request, the contiguous scheme consumes B x global-columns."""
-    rng = np.random.default_rng(205)
-    prompts = [rng.integers(1, 256, (n,)).astype(np.int32)
-               for n in (5, 14, 3, 12, 7, 4, 9, 6)]
-
-    def serve(paged):
-        kw = dict(batch_slots=4, max_new_tokens=8, max_cache_len=1024,
-                  cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2)
-        if paged:
-            kw.update(paged=True, block_size=4)
-        engine = ContinuousBatcher(llama, **kw)
-        rids = [engine.submit(p) for p in prompts]
-        outs = engine.run()
-        admitted = sum(p.size for p in prompts) + sum(len(outs[r]) for r in rids)
-        return [outs[r] for r in rids], admitted, engine.kv_consumed_slots_peak
-
-    out_c, tok_c, slots_c = serve(False)
-    out_p, tok_p, slots_p = serve(True)
-    for a, b in zip(out_c, out_p):
-        np.testing.assert_array_equal(a, b)
-    ratio = (tok_p / slots_p) / (tok_c / slots_c)
-    assert ratio >= 1.3, f"effective capacity ratio {ratio:.2f} < 1.3"
 
 
 def test_paged_capacity_dead_end_and_backpressure(llama):
@@ -739,7 +722,7 @@ def test_paged_telemetry_histograms_and_gauges(llama):
 
 
 class _JoinedCache:
-    """Test oracle: the joined form the paged engine used to build. It takes
+    """Test oracle: the joined form of the two-part cache. It takes
     the two-part cache, concatenates view and write window into one ordinary
     cache, runs the wrapped model's one-part forward over it and hands back
     the window's columns — same mathematics, one softmax over one array."""
@@ -807,14 +790,13 @@ def _two_part_case(name):
     """(model, prompts, shared prefix or None, paged-engine overrides, max_new)."""
     rng = np.random.default_rng(310)
     ragged = [rng.integers(1, 120, (n,)).astype(np.int32) for n in (5, 9, 3, 12, 7, 4)]
-    tiny = dict(num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
     if name == "gpt2":
         model = GPT2(GPT2Config(vocab_size=128, hidden_size=32, num_hidden_layers=2,
                                 num_attention_heads=2, max_position_embeddings=64))
         model.init_params(jax.random.key(3))
         return model, ragged[:3], None, dict(max_cache_len=64, bucket_sizes=(16,)), 5
     window = 4 if name == "sliding_window" else None
-    model = Llama(LlamaConfig.tiny(sliding_window=window, **tiny))
+    model = Llama(LlamaConfig.tiny(sliding_window=window, **TINY))
     model.init_params(jax.random.key(0))
     if name == "plain":  # bucket-sized prompts, one-step windows: no hole anywhere
         full = [rng.integers(1, 256, (n,)).astype(np.int32) for n in (8, 16)]
@@ -842,11 +824,11 @@ def _serve(engine, prompts, prefix):
 def test_paged_two_part_cache_serves_the_joined_cache_s_tokens(case):
     """The paged programs attend (read-only view, write window) without ever
     joining them. Their tokens equal those of the same engine over the joined
-    cache (the oracle above), the contiguous engine's and solo generate()'s:
+    cache (the oracle above) and solo generate()'s:
     with no hole, with bucket and finished-row holes, across aliased prefix
     blocks, with a window whose valid-slot rank crosses the seam, and for a
-    model with learned positions. An int8 pool is lossy against the
-    contiguous float cache, so it is held to the joined oracle alone (which
+    model with learned positions. An int8 pool is lossy against
+    generate()'s float cache, so it is held to the joined oracle alone (which
     reads and writes the same quantized rows)."""
     model, prompts, prefix, overrides, max_new = _two_part_case(case)
     served = _serve(_paged(model, max_new_tokens=max_new, **overrides), prompts, prefix)
@@ -856,15 +838,7 @@ def test_paged_two_part_cache_serves_the_joined_cache_s_tokens(case):
         np.testing.assert_array_equal(got, want)
     if case == "kv_quant":
         return
-    contiguous = _serve(
-        ContinuousBatcher(model, batch_slots=2, max_new_tokens=max_new,
-                          max_cache_len=overrides.get("max_cache_len", 512),
-                          cache_dtype=jnp.float32,
-                          bucket_sizes=overrides.get("bucket_sizes", (8, 16)),
-                          sync_every=overrides.get("sync_every", 2)),
-        prompts, prefix)
-    for got, want, p in zip(served, contiguous, prompts):
-        np.testing.assert_array_equal(got, want)
+    for got, p in zip(served, prompts):
         whole = p if prefix is None else np.concatenate([prefix, p])
         np.testing.assert_array_equal(got, _solo(model, whole, max_new)[: len(got)])
 
@@ -976,15 +950,14 @@ CHUNKED = dict(batch_slots=3, max_new_tokens=6, bucket_sizes=(8,), prefill_chunk
 
 def _chunked_case(name):
     """(model, paged-engine overrides) of a family that serves paged."""
-    tiny = dict(num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
     if name == "gpt2":
         return _two_part_case("gpt2")[0], dict(max_cache_len=192)
-    model = Llama(LlamaConfig.tiny(qk_norm=name == "qk_norm", **tiny))
+    model = Llama(LlamaConfig.tiny(qk_norm=name == "qk_norm", **TINY))
     model.init_params(jax.random.key(0))
     if name == "kv_quant":
         return model, dict(kv_quant="int8")
     if name == "speculative":
-        draft = Llama(LlamaConfig.tiny(**tiny))
+        draft = Llama(LlamaConfig.tiny(**TINY))
         draft.init_params(jax.random.key(7))  # other weights: it mispredicts
         return model, dict(speculative_k=2, draft_model=draft)
     return model, {}

@@ -92,7 +92,7 @@ def _clean_slate():
 def _paged(model, **overrides):
     kw = dict(batch_slots=2, max_new_tokens=8, max_cache_len=1024,
               cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-              paged=True, block_size=4, prefill_chunk=8,
+              block_size=4, prefill_chunk=8,
               max_tokens_per_request=48)
     kw.update(overrides)
     return ContinuousBatcher(model, **kw)

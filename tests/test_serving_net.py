@@ -59,7 +59,7 @@ def llama():
 def _paged(model, **overrides):
     kw = dict(batch_slots=2, max_new_tokens=8, max_cache_len=1024,
               cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-              paged=True, block_size=4, prefill_chunk=8,
+              block_size=4, prefill_chunk=8,
               max_tokens_per_request=48)
     kw.update(overrides)
     return ContinuousBatcher(model, **kw)
@@ -167,16 +167,9 @@ def test_chain_import_rejects_layout_mismatch(llama):
 
 
 def test_frontend_role_validation(llama):
-    """The frontend refuses roles it cannot serve: router runs no engine,
-    and the disaggregated roles require a paged engine (chain surgery)."""
+    """The frontend refuses the role it cannot serve: router runs no engine."""
     with pytest.raises(ValueError, match="router role runs no engine"):
         ServingFrontend(_paged(llama), role="router")
-    contiguous = ContinuousBatcher(
-        llama, batch_slots=2, max_new_tokens=8, max_cache_len=512,
-        cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-    )
-    with pytest.raises(ValueError, match="paged engine"):
-        ServingFrontend(contiguous, role="prefill")
 
 
 # ================================================================ SSE wire

@@ -43,7 +43,7 @@ def llama():
 def _paged(model, **overrides):
     kw = dict(batch_slots=2, max_new_tokens=12, max_cache_len=512,
               cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=4,
-              paged=True, block_size=4, prefill_chunk=8, max_tokens_per_request=64)
+              block_size=4, prefill_chunk=8, max_tokens_per_request=64)
     kw.update(overrides)
     return ContinuousBatcher(model, **kw)
 
@@ -184,16 +184,6 @@ def test_trace_requests_false_records_nothing_and_moves_no_transfer(llama, trace
         assert stats_on[key] == stats_off[key], key
 
 
-def test_contiguous_run_gets_serve_run_and_no_other_span(llama):
-    engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=8, max_cache_len=512,
-                               cache_dtype=jnp.float32, bucket_sizes=(8, 16))
-    engine.submit(np.arange(1, 6, dtype=np.int32))
-    reset_spans()
-    engine.run()
-    records = get_span_ring().snapshot()
-    assert [(r.name, r.attrs) for r in records] == [("serve.run", {"finished": 1})]
-
-
 # -------------------------------------------------------------- the front end
 def _post_generate(endpoint, prompt, max_new):
     req = urllib.request.Request(
@@ -239,17 +229,9 @@ def test_frontend_request_yields_one_submit_and_one_relay(llama, trace_requests)
     ("serve_decode_window", lambda e: e._decode().lower(*e._decode_args())),
     ("serve_prefill_chunk_8", lambda e: e._chunk_fn(8).lower(*e._chunk_args(8))),
     ("serve_prefill_chunk_16", lambda e: e._chunk_fn(16).lower(*e._chunk_args(16))),
+    ("serve_spec_verify", lambda e: e._spec_verify().lower(*e._verify_args())),
 ])
 def test_paged_programs_carry_their_names(llama, name, lower):
-    text = lower(_paged(llama)).as_text()
+    spec = dict(speculative_k=2, draft_model=llama) if name == "serve_spec_verify" else {}
+    text = lower(_paged(llama, **spec)).as_text()
     assert f"module @jit_{name} " in text.split("\n", 1)[0]
-
-
-def test_contiguous_programs_carry_their_names(llama):
-    engine = ContinuousBatcher(llama, batch_slots=2, max_new_tokens=8, max_cache_len=512,
-                               cache_dtype=jnp.float32, bucket_sizes=(8, 16))
-    state = engine._state_tuple()
-    decode = engine._decode().lower(engine.params, engine._cache, state)
-    assert "module @jit_serve_decode_contiguous " in decode.as_text().split("\n", 1)[0]
-    admit = engine._admit_fn(8)
-    assert admit.__wrapped__.__name__ == "serve_admit_8"

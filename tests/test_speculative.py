@@ -54,7 +54,7 @@ def _solo(model, prompt, max_new, **kw):
 def _paged(model, **overrides):
     kw = dict(batch_slots=2, max_new_tokens=8, max_cache_len=512,
               cache_dtype=jnp.float32, bucket_sizes=(8, 16), sync_every=2,
-              paged=True, block_size=4)
+              block_size=4)
     kw.update(overrides)
     return ContinuousBatcher(model, **kw)
 
@@ -145,7 +145,6 @@ def test_spec_bit_identity_across_waves_and_refill(llama, draft):
     engine = _paged(llama, speculative_k=3, draft_model=draft)
     r1 = [engine.submit(p) for p in w1]
     o1 = engine.run()
-    engine.compact()  # mode-agnostic wave-boundary call (paged: no-op)
     r2 = [engine.submit(p) for p in w2]
     o2 = engine.run()
     for rid, p in zip(r1 + r2, w1 + w2):
@@ -294,15 +293,8 @@ def test_int8_weight_serving_matches_solo(llama):
 
 
 def test_spec_and_quant_guards(llama, draft):
-    """Construction guards: both levers require the paged engine; a draft
-    without speculation, a negative k, and an unknown quant token all fail
-    fast with actionable errors."""
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(llama, batch_slots=2, max_new_tokens=4,
-                          max_cache_len=64, speculative_k=2, draft_model=draft)
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(llama, batch_slots=2, max_new_tokens=4,
-                          max_cache_len=64, kv_quant="int8")
+    """Construction guards: a draft without speculation, a negative k, and
+    an unknown quant token all fail fast with actionable errors."""
     with pytest.raises(ValueError, match="draft_model"):
         _paged(llama, draft_model=draft)
     with pytest.raises(ValueError, match="speculative_k"):
