@@ -13,8 +13,13 @@ This module is the op-level seam:
 
 - :func:`init_kv_pool` / :func:`gather_block_view` / :func:`gather_block_mask`
   are the pool primitives the engine's compiled programs are built from. The
-  gather is the **reference lowering** — an XLA gather over the block axis
-  that materializes each slot's chain as a contiguous per-slot view. The
+  gather is the **reference lowering**: one XLA gather over the block axis,
+  its ids clamped to the pool and its slices kept to a size the TPU's
+  compiler gathers in one pass (:func:`_gather_chains`), that materializes
+  each slot's chain as a contiguous per-slot view. Table ids are in range by
+  the pool's invariant (below), so no out-of-range read is masked, filled or
+  selected on the device (``jnp.take``'s default did all three, over the
+  whole view). The
   engine hands that view to the model READ-ONLY, as the ``prefix`` of a
   two-part cache whose second part is a write window the size of what the
   program writes; ``cached_attention(prefix=...)`` attends both under one
@@ -61,6 +66,7 @@ Pool invariants (shared with serving.py):
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .attention import cached_attention
@@ -193,6 +199,43 @@ def import_chain_blocks(pool, block_ids, chain):
     return out
 
 
+# The largest gather slice the v5e's compiler was seen to gather natively (a
+# fusion that reads every slice and writes the result once). A larger slice it
+# copies one at a time in a serial loop into a buffer it fills first: 512 KiB
+# slices compile to the first and 896 KiB slices to the second
+# (tests/test_chip_compile.py holds the first for the gather below).
+_NATIVE_SLICE_BYTES = 512 * 1024
+
+
+def _gather_chains(pool, block_tables):
+    """``pool`` (L, N, bs, H, D), ``block_tables`` (B, M) -> (L, B, M, bs, H, D):
+    one gather, ids clamped to the pool (``mode="clip"``: no in-bounds mask is
+    built and nothing is selected, where the default fill for out-of-range
+    ids pays a select over the whole result).
+
+    A slice is one table entry's block over every layer where that stays
+    within what the compiler gathers natively, and one layer's block
+    otherwise, with the layer as a second index: the bf16 pool of a 28-layer
+    model (896 KiB an entry) is then read and written once, where its
+    whole-entry gather ran as a fill, 1,176 serial copies and a select.
+    Depths in between are not used: their result needs a further copy to
+    bring the layers of a slice before the slots."""
+    L, n, bs, h, d = pool.shape
+    b, m = block_tables.shape
+    depth = L if L * bs * h * d * pool.dtype.itemsize <= _NATIVE_SLICE_BYTES else 1
+    groups = L // depth
+    grid = (groups, b, m)
+    starts = jnp.stack([
+        jnp.broadcast_to(jnp.arange(groups, dtype=jnp.int32)[:, None, None], grid),
+        jnp.broadcast_to(block_tables.astype(jnp.int32)[None], grid)], axis=-1)
+    chains = jax.lax.gather(
+        pool.reshape(groups, depth, n, bs, h, d), starts,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(1, 4, 5, 6), collapsed_slice_dims=(0, 2), start_index_map=(0, 2)),
+        slice_sizes=(1, depth, 1, bs, h, d), mode="clip")  # (groups, depth, B, M, bs, H, D)
+    return chains.reshape(L, b, m, bs, h, d)
+
+
 def gather_block_view(pool_kv, block_tables, *, active=None, scales=None,
                       out_dtype=None):
     """Materialize per-slot contiguous KV views from the pool.
@@ -200,7 +243,10 @@ def gather_block_view(pool_kv, block_tables, *, active=None, scales=None,
     ``pool_kv``: ``(..., N, bs, H, D)`` (a single layer or the L-stacked
     pool); ``block_tables``: ``(B, M)`` int32 block ids. Returns
     ``(..., B, M*bs, H, D)`` — slot ``b``'s chain left-packed in table order.
-    This is the reference XLA-gather lowering of paged attention.
+    This is the reference lowering of paged attention's view assembly: one
+    clamped gather (:func:`_gather_chains`), equal to
+    ``jnp.take(pool_kv, block_tables, axis=-4)`` bit for bit on ids that are
+    in range, which a table's are by the pool's invariant.
 
     ``scales`` (``(..., N, bs)`` per-block scale tables of a quantized pool)
     arms the dequant seam: the int8 view is gathered together with its
@@ -215,13 +261,15 @@ def gather_block_view(pool_kv, block_tables, *, active=None, scales=None,
     rows are masked garbage either way, and only the kernel bothers to skip
     them. Use :func:`gather_view` for registry-dispatched assembly."""
     del active  # reference computes all slots; masks make the garbage inert
-    m = block_tables.shape[-1]
-    view = jnp.take(pool_kv, block_tables, axis=-4)  # (..., B, M, bs, H, D)
-    view = view.reshape(view.shape[:-4] + (m * view.shape[-3],) + view.shape[-2:])
+    b, m = block_tables.shape
+    lead, (n, bs, h, d) = pool_kv.shape[:-4], pool_kv.shape[-4:]
+    view = _gather_chains(pool_kv.reshape((-1, n, bs, h, d)), block_tables)
+    view = view.reshape(lead + (b, m * bs, h, d))
     if scales is None:
         return view if out_dtype is None else view.astype(out_dtype)
-    s = jnp.take(scales, block_tables, axis=-2)  # (..., B, M, bs)
-    s = s.reshape(s.shape[:-2] + (m * s.shape[-1],))
+    # A float a token row: small, so a plain clamped gather.
+    s = jnp.take(scales, block_tables, axis=-2, mode="clip")  # (..., B, M, bs)
+    s = s.reshape(s.shape[:-2] + (m * bs,))
     deq = view.astype(jnp.float32) * s[..., None, None].astype(jnp.float32)
     return deq.astype(out_dtype if out_dtype is not None else jnp.float32)
 
@@ -230,7 +278,8 @@ def gather_view(pool_kv, block_tables, *, active=None, scales=None,
                 out_dtype=None, backend=None):
     """Registry-dispatched view assembly (op ``paged_gather``): the Pallas
     chain-walk kernel when ``ACCELERATE_KERNELS`` (or ``backend``) selects
-    it, the XLA-gather reference otherwise. Bit-identical for active slots
+    it, the clamped gather of :func:`gather_block_view` otherwise.
+    Bit-identical for active slots
     (pure data movement, or gather+dequant when ``scales`` arms the int8
     path); the kernel skips ``active == 0`` slots."""
     from .registry import dispatch, resolve_backend
@@ -248,7 +297,8 @@ def gather_block_mask(pool_mask, block_tables):
     """Per-slot validity view: ``(N, bs)`` pool mask + ``(B, M)`` tables →
     ``(B, M*bs)`` — the paged analog of the contiguous cache's ``kv_mask``."""
     b, m = block_tables.shape
-    return jnp.take(pool_mask, block_tables, axis=0).reshape(b, m * pool_mask.shape[1])
+    return jnp.take(pool_mask, block_tables, axis=0, mode="clip").reshape(
+        b, m * pool_mask.shape[1])
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, *, q_positions,

@@ -14,10 +14,17 @@ process compiles.
 
 Six cases must compile with a ``tpu_custom_call`` in the program. Three are
 refused by the compiler today and are strict ``xfail``s carrying its words: the
-PR that repairs one of those kernels has to flip its case.
+PR that repairs one of those kernels has to flip its case. One more case
+compiles a whole program, the paged engine's decode window at the geometry of
+the benchmark's Qwen3 cells, and reads what the compiler made of its view
+assembly.
 """
 
+import json
+import math
 import os
+import re
+from pathlib import Path
 from dataclasses import dataclass
 from typing import Callable
 
@@ -189,3 +196,56 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
             raise KernelRefused(str(exc)[:400]) from exc
         raise
     assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel in the compiled program"
+
+
+def test_decode_window_assembles_its_view_without_a_select_for_v5e(one_chip, no_persistent_cache):
+    """The decode window of an engine at the Qwen3 cells' geometry (12 slots,
+    98 table entries of 16 tokens, 8 KV heads of 128, a pool of 961 blocks;
+    the published widths and depth, a small vocabulary), with abstract
+    arguments. ``gather_block_view`` clamps its ids to the pool and gathers a
+    layer's block a slice (an entry over 28 layers is 896 KiB, past what the
+    compiler gathers natively), so the optimised program holds no ``select``
+    the size of a view (a gather that fills out-of-range reads pays one a
+    view, and a mask beside it), no serial copy loop into a buffer the size
+    of a view, and what it needs beyond its arguments and its results stays
+    under 1.75 times the K and V views: it reads 1.50 (two views and the
+    gather's result for one of them, which a copy brings into the readers'
+    layout) where the ``mode="fill"`` gather of whole entries read 2.00. (The
+    results count only where the engine drops donation, as it does on a CPU
+    backend with a persistent compilation cache set, which another test of
+    the worker's process may have done.)"""
+    from accelerate_tpu.models import Llama, LlamaConfig
+    from accelerate_tpu.ops import paged_attention
+    from accelerate_tpu.serving import ContinuousBatcher
+
+    cell = json.loads((Path(__file__).parents[1] / "chipbench/configs/qwen3-1.7b.json").read_text())
+    fields = {k: cell[k] for k in ("hidden_size", "intermediate_size", "num_hidden_layers",
+                                   "num_attention_heads", "num_key_value_heads", "head_dim",
+                                   "rope_theta", "rms_norm_eps", "tie_word_embeddings")}
+    model = Llama(LlamaConfig(vocab_size=1024, **fields, **cell["model_overrides"]))
+    params = jax.eval_shape(lambda: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16), model.init(jax.random.key(0))))
+    engine = ContinuousBatcher(model, params=params, **cell["engine"])
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), engine._decode_args())
+    compiled = engine._decode().lower(*args).compile()
+
+    layers, _, block, kv_heads, head_dim = engine._pool["k"].shape
+    assert (engine.B, engine.max_blocks_per_slot, block, kv_heads, head_dim) == (12, 98, 16, 8, 128)
+    assert layers * block * kv_heads * head_dim * 2 > paged_attention._NATIVE_SLICE_BYTES
+    view_elements = layers * engine.B * engine.max_blocks_per_slot * block * kv_heads * head_dim
+
+    program = compiled.as_text().splitlines()
+
+    def view_sized(opcode):
+        return [line.strip()[:160] for line in program
+                for found in [re.search(rf"= \w+\[([\d,]+)\]\S* {opcode}\(", line)] if found
+                and math.prod(int(n) for n in found.group(1).split(",")) == view_elements]
+
+    assert not view_sized("select"), view_sized("select")
+    assert not view_sized("dynamic-update-slice"), view_sized("dynamic-update-slice")
+    memory = compiled.memory_analysis()
+    views = 2 * view_elements * 2  # K and V, bf16
+    temporaries = (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+                   - (memory.output_size_in_bytes - memory.alias_size_in_bytes))
+    assert temporaries < 1.75 * views, (temporaries / views, memory)

@@ -63,6 +63,83 @@ def test_gather_block_view_roundtrip():
     assert vmask.shape == (b, m * bs)
 
 
+def _assembly_case(name, **kw):
+    return pytest.param(kw, id=name)
+
+
+ASSEMBLY_CASES = [
+    _assembly_case("one_layer_b3"),
+    _assembly_case("one_layer_b1_a_chunks_view", b=1),
+    _assembly_case("stacked_b3", layers=3),
+    _assembly_case("stacked_b1", layers=2, b=1),
+    _assembly_case("block64_kv2", layers=2, bs=64, hkv=2, m=3),
+    _assembly_case("block16_kv8", layers=2, bs=16, hkv=8, d=16),
+    _assembly_case("bf16_stacked", layers=2, dtype=jnp.bfloat16),
+    # An entry over all layers passes the native slice size: gathered a layer at a time.
+    _assembly_case("a_layer_a_slice_block64_kv8", layers=5, b=2, m=3, bs=64, hkv=8, d=128,
+                   per_layer=True),
+    _assembly_case("a_layer_a_slice_b1_bf16", layers=40, b=1, m=4, bs=16, hkv=8, d=128,
+                   dtype=jnp.bfloat16, per_layer=True),
+    _assembly_case("int8_scales_f32", layers=2, quant=True),
+    _assembly_case("int8_scales_bf16_out", layers=2, quant=True, out_dtype=jnp.bfloat16),
+    _assembly_case("out_of_range_ids_clamp", layers=2, wild=True),
+]
+
+
+@pytest.mark.parametrize("case", ASSEMBLY_CASES)
+def test_view_assembly_equals_jnp_take_bit_for_bit(case):
+    """``gather_block_view`` (one clamped gather whose slices span every
+    layer of a block, or one layer where that would pass the size the TPU's
+    compiler gathers natively) against ``jnp.take(pool, tables, axis=-4)``,
+    bit for bit: tables with trash-block entries (0) and blocks shared
+    between slots (aliased prefixes), a single layer and the stacked pool,
+    one slot and several, the int8 pool with its scales and ``out_dtype``,
+    and ``gather_block_mask``. An id outside the pool reads a block in range
+    (clamped, as ``mode="clip"`` does), never a fill."""
+    from accelerate_tpu.ops import paged_attention
+
+    kw = dict(layers=None, b=3, m=5, bs=4, hkv=2, d=8, dtype=jnp.float32, quant=False,
+              out_dtype=None, wild=False, per_layer=False)
+    kw.update(case)
+    rng = np.random.default_rng(7)
+    b, m, bs, hkv, d = kw["b"], kw["m"], kw["bs"], kw["hkv"], kw["d"]
+    n = b * m + 1
+    lead = () if kw["layers"] is None else (kw["layers"],)
+    shape = lead + (n, bs, hkv, d)
+    if kw["quant"]:
+        pool = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        scales = jnp.asarray(rng.uniform(0.01, 2.0, lead + (n, bs)), jnp.float32)
+    else:
+        pool = jnp.asarray(rng.standard_normal(shape), kw["dtype"])
+        scales = None
+    entry_bytes = int(np.prod(lead + (bs, hkv, d))) * pool.dtype.itemsize
+    assert (entry_bytes > paged_attention._NATIVE_SLICE_BYTES) == kw["per_layer"]
+    tables = rng.integers(1, n, (b, m)).astype(np.int32)
+    tables[0, -1] = 0                      # an unassigned entry: the trash block
+    tables[-1, 0] = tables[0, 0]           # a block two slots share
+    tables[0, 2] = tables[0, 1]            # and one a slot holds twice
+    if kw["wild"]:
+        tables[0, 0], tables[-1, 1] = n + 5, -3
+    tables = jnp.asarray(tables)
+
+    want = jnp.take(pool, tables, axis=-4, mode="clip" if kw["wild"] else None)  # (..., B, M, bs, H, D)
+    want = want.reshape(want.shape[:-4] + (m * bs,) + want.shape[-2:])
+    if kw["quant"]:
+        s = jnp.take(scales, tables, axis=-2).reshape(lead + (b, m * bs))
+        want = (want.astype(jnp.float32) * s[..., None, None]).astype(kw["out_dtype"] or jnp.float32)
+    got = jax.jit(lambda p, t, sc: gather_block_view(
+        p, t, scales=sc, out_dtype=kw["out_dtype"]))(pool, tables, scales)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+    pool_mask = jnp.asarray(rng.integers(0, 2, (n, bs)), jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(gather_block_mask(pool_mask, tables)),
+        np.asarray(jnp.take(pool_mask, tables, axis=0, mode="clip" if kw["wild"] else None))
+        .reshape(b, m * bs))
+
+
 @pytest.mark.parametrize("window", [None, 3])
 def test_paged_attention_matches_cached_attention(window):
     """paged_attention == cached_attention on the gathered-equivalent dense
