@@ -1,6 +1,7 @@
 from .bert import BertConfig, BertForSequenceClassification
 from .gpt2 import GPT2, GPT2Config
 from .gptx import GPTX, GPTXConfig
+from .laguna import Laguna, LagunaConfig
 from .llama import Llama, LlamaConfig
 from .minicpm_sala import MiniCPMSALA, MiniCPMSALAConfig
 from .moe import MoELlama, MoELlamaConfig

@@ -148,7 +148,7 @@ class MiniCPMSALA(Module):
     # holds by slot, and that a key's chain column must be its token's
     # position (ops/paged_attention.py ``cache_layout``).
     cache_layout = {"by_token": ("k", "v"), "by_slot": ("state",), "dense_chain": True,
-                    "attended_keys": True}
+                    "counters": {"decode": ("attended_keys", "context_keys")}}
 
     def __init__(self, config: MiniCPMSALAConfig):
         self.config = config

@@ -19,6 +19,11 @@ loop), with the implementation picked per mesh (``moe_ffn``):
 Both share one routing semantics (same capacity drop rule, same Switch aux
 loss) — pinned by ``tests/test_moe.py::test_sorted_and_einsum_dispatch_agree``.
 
+**Serving** has a layer of its own, :func:`expert_share_ffn`: drop-free (no
+capacity), told which experts of the router's width it holds, and returning
+their part of the routed sum alone (a chip's share of a layer; the model adds
+a shared expert). ``ACCELERATE_MOE_DISPATCH`` does not reach it.
+
 Reference context: the reference has no MoE implementation of its own (only
 DeepSpeed-MoE passthrough flags, ``utils/dataclasses.py``); this is a native
 capability of the framework (SURVEY.md §2.4 lists EP as a note-only strategy
@@ -308,6 +313,119 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, *, k: int, capacity_factor: float
             f"(valid: auto|{'|'.join(sorted(fns))})"
         )
     return fns[impl](x, router_w, w_gate, w_up, w_down, k=k, capacity_factor=capacity_factor)
+
+
+# ---------------------------------------------------------------------------
+# The serving expert layer: drop-free, told which experts it holds.
+
+def route_top_k(router_logits, k: int, *, norm_topk_prob: bool = True):
+    """Softmax over every expert the router scores, the ``k`` largest, and (with
+    ``norm_topk_prob``) their weights renormalised to sum to 1: all float32.
+    ``router_logits``: (N, R). Returns ``(weights (N, k), experts (N, k))``."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts
+
+
+def router_logits(x, router_w):
+    """``x`` (..., h) against the router's whole width (h, R): float32 operands
+    at the highest precision, whatever the weights' dtype or the engine's
+    ``matmul_precision`` (a near tie decides which expert a token gets)."""
+    return jax.lax.dot_general(
+        x.astype(jnp.float32), router_w.astype(jnp.float32), (((x.ndim - 1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _grouped_matmul(rows, weights, sizes, group_of_row, layer, precision: str):
+    """``rows`` (M, a), sorted by group, times its group's ``weights[layer, g]``
+    (L, E, a, b), as one ``lax.ragged_dot`` over ALL the layers' experts with
+    sizes zero outside ``layer``: the product skips a group that has no row,
+    so it reads the experts touched and no others, and no layer's experts are
+    sliced out first (a copy of all of them, 1.8 ms a layer at 32 experts of
+    3072 x 1024 x 3: PERF.md, PR 36). Under ``int8`` both operands are
+    quantized as ``ops/int8.int8_matmul`` quantizes them (rows by row, the
+    layer's weights by expert and column) and the int8 values, exact in bf16,
+    are multiplied with float32 accumulation."""
+    layers, experts = weights.shape[:2]
+    if precision == "int8":
+        from .int8 import quantize_rowwise
+
+        q_rows, s_rows = quantize_rowwise(rows, axis=-1)
+        q_w, s_w = quantize_rowwise(
+            jax.lax.dynamic_index_in_dim(weights, layer, 0, keepdims=False), axis=1)  # (E, 1, b)
+        acc = jax.lax.ragged_dot(q_rows.astype(jnp.bfloat16), q_w.astype(jnp.bfloat16), sizes,
+                                 preferred_element_type=jnp.float32)
+        scale = jnp.take(s_w[:, 0], jnp.minimum(group_of_row, experts - 1), axis=0)
+        return (acc * s_rows * scale).astype(rows.dtype)
+    if precision != "default":
+        raise ValueError(f"matmul precision must be 'default' or 'int8', got {precision!r}")
+    group = jnp.arange(layers * experts)
+    every = jnp.where(group // experts == layer, jnp.take(sizes, group % experts), 0)
+    exact = jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
+    return jax.lax.ragged_dot(rows, weights.reshape(-1, *weights.shape[2:]).astype(rows.dtype),
+                              every, precision=exact)
+
+
+def _experts_grouped(x, weights, local, held, w_gate, w_up, w_down, layer, precision):
+    """Claims sorted by the expert held (claims of absent experts last, in no
+    group) into three grouped products; each claim's row comes back by the
+    inverse permutation, so nothing is scatter-added. Returns ``(out (N, h)
+    float32, claims (E,))``."""
+    n, k = local.shape
+    e = w_gate.shape[1]
+    flat = jnp.where(held, local, e).reshape(n * k)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
+    group = jnp.take(flat, order)
+    rows = jnp.take(x, order // k, axis=0)  # (n·k, h), expert-contiguous
+    mm = lambda a, w: _grouped_matmul(a, w, sizes, group, layer, precision)
+    out = mm(jax.nn.silu(mm(rows, w_gate)) * mm(rows, w_up), w_down)
+    inverse = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.arange(n * k, dtype=jnp.int32))
+    back = jnp.take(out, inverse, axis=0).reshape(n, k, -1).astype(jnp.float32)
+    # Rows outside every group are whatever the product left there: never read.
+    return jnp.sum(jnp.where(held[..., None], back * weights[..., None], 0.0), axis=1), sizes
+
+
+def expert_share_ffn(x, router_w, w_gate, w_up, w_down, *, first: int, k: int,
+                     norm_topk_prob: bool = True, scale: float = 1.0,
+                     precision: str = "default", row_mask=None, layer=None):
+    """The part of a routed expert layer that the experts held here give
+    (``model-configs`` guide, section 4; what expert parallelism asks anyway).
+
+    ``x``: (N, h) rows; ``router_w``: (h, R), the router at its whole width;
+    ``w_gate``/``w_up``: (E, h, i) and ``w_down``: (E, i, h), the SwiGLU experts
+    ``first .. first + E`` of the R; with ``layer`` (a traced index will do)
+    the three are stacked over layers, (L, E, ...), and ``layer`` says which:
+    a model that scans its layers hands the stacks over whole. Every row is
+    routed over all R experts (float32 softmax, the ``k`` largest,
+    renormalised under ``norm_topk_prob``, times ``scale``); the result is the
+    weighted sum over the claims that name an expert held here, and nothing
+    for the others: **no claim is dropped, no capacity exists**, and no code
+    stands in for absent experts. A row whose ``row_mask`` (N,) is false
+    (bucket padding, a free slot's row) claims nothing. A shared expert is the
+    caller's. Returns ``(out (N, h) in x's dtype, claims (E,) int32: the
+    claims each held expert got)``.
+
+    One form serves a decode step's handful of rows and a prefill chunk's
+    thousand (:func:`_experts_grouped`): claims sorted by expert into
+    ``lax.ragged_dot``, which reads the experts that got a claim and no
+    others, so a decode step is bound by the bytes of the experts touched and
+    a chunk by its grouped products (``PERF.md``, PR 36, has every form timed
+    on the chip at both shapes). ``precision`` is ``ops/int8.matmul``'s, and
+    reaches the experts' products; the router stays float32 under either."""
+    if layer is None:
+        layer, (w_gate, w_up, w_down) = 0, (w_gate[None], w_up[None], w_down[None])
+    experts_held = w_gate.shape[1]
+    weights, chosen = route_top_k(router_logits(x, router_w), k, norm_topk_prob=norm_topk_prob)
+    weights = weights * scale
+    local = chosen - first
+    held = (local >= 0) & (local < experts_held)
+    if row_mask is not None:
+        held = held & row_mask.astype(bool)[:, None]
+    out, claims = _experts_grouped(x, weights, local, held, w_gate, w_up, w_down, layer, precision)
+    return out.astype(x.dtype), claims
 
 
 def _constrain_expert_layout(t):

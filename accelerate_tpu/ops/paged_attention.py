@@ -78,11 +78,16 @@ from .attention import cached_attention
 # ``(layers, batch, ...)`` of any dtype and are held once a batch slot, whatever
 # the sequence's length (a recurrent state). ``dense_chain`` asks the engine to
 # keep a chain free of holes below its frontier, so that a key's column is its
-# token's position. ``attended_keys`` says that the cached forward of one token
-# returns ``"attended_keys"`` and ``"context_keys"`` (B,) beside its logits. A
-# module without a ``cache_layout`` is the plain case.
+# token's position. ``counters`` names the counts that the cached forward
+# returns beside its logits, float32, one a row (B,) or one for the batch ():
+# under ``"decode"`` those of one token a row, under ``"chunk"`` those of a
+# prefill chunk; the engine sums them on the device and sets them on the span
+# that dispatched the program. ``row_mask`` asks the decode step for
+# ``attention_mask`` = the rows that decode (a model whose work depends on
+# which rows are real: a free slot's pad token must claim no expert). A module
+# without a ``cache_layout`` is the plain case.
 PLAIN_CACHE_LAYOUT = {"by_token": ("k", "v"), "by_slot": (), "dense_chain": False,
-                      "attended_keys": False}
+                      "counters": {}, "row_mask": False}
 
 
 def cache_layout(module) -> dict:

@@ -534,13 +534,14 @@ class ContinuousBatcher:
         from .telemetry.spans import record_span
 
         pool, now = self._pool, time.perf_counter()
-        held = [pool[name] for name in self._layout["by_slot"]]
+        held = {name: pool[name] for name in self._layout["by_slot"]}
         record_span(
             "serve.cache_layout", now, now,
             kv_bytes_per_token=self._pool_bytes["kv"] // (pool["mask"].shape[0] * self.block_size),
             state_bytes_per_slot=self._pool_bytes["state"] // self.B,
             kv_layers=int(pool["k"].shape[0]),
-            state_layers=sum(int(x.shape[0]) for x in held),
+            state_layers=sum(int(x.shape[0]) for x in held.values()),
+            slot_bytes={name: int(x.nbytes) // self.B for name, x in held.items()},
         )
 
     def _build_draft_from_preset(self, preset: str):
@@ -580,6 +581,7 @@ class ContinuousBatcher:
         ``keep_prefix=False`` to drop the prefix too."""
         B = self.B
         self._streamed.clear()
+        self._chunk_counts = None  # the last chunk's counts, where the model names any
         if self.tracer is not None:
             # In-flight slots are about to be wiped: their lifecycle records
             # close as cancelled (queued requests survive and stay queued).
@@ -988,6 +990,7 @@ class ContinuousBatcher:
         bs = self.block_size
         spec = bool(self.speculative_k)
         by_slot, dense_chain = self._layout["by_slot"], self._layout["dense_chain"]
+        counted = self._layout["counters"].get("chunk", ())
 
         def body(params, pool, state, tables, lens, slot, chunk_row, mask_row,
                  base_pos, is_final, rid, base_rng, req_max, req_temp, req_eos,
@@ -1064,9 +1067,11 @@ class ContinuousBatcher:
             active = active.at[slot].set(is_final & ~done0)
             state = (tok, pos, n_out, active, out_buf, keys,
                      slot_max, slot_temp, slot_eos)
+            # What the model counted over this chunk (cache_layout "counters").
+            counts = (jnp.stack([jnp.sum(out[name]) for name in counted]),) if counted else ()
             if spec:
-                return pool, d_pool, state
-            return pool, state
+                return (pool, d_pool, state) + counts
+            return (pool, state) + counts
 
         if spec:
             def chunk(params, d_params, pool, d_pool, state, tables, lens, slot,
@@ -1143,7 +1148,9 @@ class ContinuousBatcher:
         pad = self.pad
         bs = self.block_size
         w = self.sync_every
-        by_slot, counted = self._layout["by_slot"], self._layout["attended_keys"]
+        by_slot = self._layout["by_slot"]
+        counted = self._layout["counters"].get("decode", ())
+        row_mask = self._layout["row_mask"]
 
         def serve_decode_window(params, pool, tables, lens, commit, force_stop, state):
             (tok, pos, n_out, active, out_buf, keys,
@@ -1164,9 +1171,12 @@ class ContinuousBatcher:
                  slot_max, slot_temp, slot_eos) = state
                 col = window["pos"]  # window column this step writes
                 feed = jnp.where(active, tok, pad)
+                # A model that asks (cache_layout "row_mask") is told which
+                # rows decode; for the others the program is what it was.
+                rows = {"attention_mask": active[:, None].astype(jnp.int32)} if row_mask else {}
                 out = module.apply(params, input_ids=feed[:, None],
                                    cache={**window, "view": view},
-                                   positions=pos[:, None])
+                                   positions=pos[:, None], **rows)
                 nxt = self._sample_rows(out["logits"][:, -1], keys, n_out, slot_temp)
                 nxt = jnp.where(active, nxt, pad)
                 window2 = out["cache"]
@@ -1186,15 +1196,17 @@ class ContinuousBatcher:
                 state = (nxt, pos + 1, n_out, still, out_buf, keys,
                          slot_max, slot_temp, slot_eos)
                 if counted:
-                    # Keys attended and keys in context, over the rows that
-                    # decode this step (a block-sparse model reports them).
+                    # What the model counted this step (cache_layout
+                    # "counters"): a count a row over the rows that decode,
+                    # a count for the batch as it is.
                     seen = carry[2] + jnp.stack([
-                        jnp.sum(jnp.where(active, out[name], 0.0))
-                        for name in ("attended_keys", "context_keys")])
+                        jnp.sum(jnp.where(active, out[name], 0.0)) if out[name].ndim
+                        else out[name] for name in counted])
                     return (window2, state, seen), None
                 return (window2, state), None
 
-            carry = (window, state) + ((jnp.zeros((2,), jnp.float32),) if counted else ())
+            carry = (window, state) + (
+                (jnp.zeros((len(counted),), jnp.float32),) if counted else ())
             carry, _ = jax.lax.scan(one_step, carry, None, length=w)
             window, state = carry[:2]
             # Persist the window: committed slots append their written
@@ -1830,14 +1842,17 @@ class ContinuousBatcher:
             jnp.float32(req.temperature), jnp.int32(req.eos),
         )
         if self.speculative_k:
-            self._pool, self._draft_pool, state = self._chunk_fn(p)(
+            self._pool, self._draft_pool, state, *counts = self._chunk_fn(p)(
                 self.params, self._draft_params, self._pool, self._draft_pool,
                 state, *tail,
             )
         else:
-            self._pool, state = self._chunk_fn(p)(
+            self._pool, state, *counts = self._chunk_fn(p)(
                 self.params, self._pool, state, *tail,
             )
+        # The model's counts of this chunk (if it names any) stay on the
+        # device until a report that was dispatched after them has been read.
+        self._chunk_counts = counts[0] if counts else None
         self._sync(state)  # instance fields track the LIVE (post-donation) buffers
         self._log_dispatch(f"chunk:{p}")
         if self.tracer is not None:
@@ -1926,17 +1941,24 @@ class ContinuousBatcher:
         ``serve.report_wait`` is that first read alone: how long the host
         was blocked on the device (its end is the moment the window's report
         became ready, where the host waited at all)."""
-        report, req_map, dispatched = report
+        report, req_map, dispatched, chunks = report
         with self._span("serve.report_wait"):
             active_np = host_fetch(report[0]).copy()
+        # What the model counted (cache_layout "counters"), on the spans that
+        # dispatched the programs (the ring holds the records themselves):
+        # read here, with the report, and the chunks' that ran before it.
+        counters = self._layout["counters"]
         if len(report) > 3:
-            # What the window attended, on the span that dispatched it (the
-            # ring holds the record itself): read here, with the report.
-            seen = host_fetch(report[3])
-            dispatched.attrs.update(attended_keys=float(seen[0]), context_keys=float(seen[1]))
+            self._set_counts(dispatched, counters["decode"], report[3])
+        for rec, counts in chunks:
+            self._set_counts(rec, counters["chunk"], counts)
         with self._span("serve.process_report") as rec:
             rec.attrs["tokens"], rec.attrs["finished"] = self._consume_report(
                 report, req_map, active_np, force_stop)
+
+    @staticmethod
+    def _set_counts(rec, names, counts):
+        rec.attrs.update(zip(names, map(float, host_fetch(counts))))
 
     def _consume_report(self, report, req_map, active_np, force_stop: np.ndarray):
         """``_process_report`` past its first read. Returns the tokens this
@@ -2009,6 +2031,7 @@ class ContinuousBatcher:
         with self._span("serve.run") as wave:
             state = self._state_tuple()
             pending = None
+            counted_chunks = []  # (span record, counts on the device) since the last window
             force_stop = np.zeros((self.B,), bool)
             last_dispatch_t = None
             window_pace = None
@@ -2026,8 +2049,10 @@ class ContinuousBatcher:
                         chunk_p, tokens, final = self._next_chunk(chunk_slot)
                         with self._span("serve.dispatch_chunk", rid=self._slot_req[chunk_slot].rid,
                                         p=chunk_p, tokens=tokens, final=final,
-                                        rows_computed=chunk_p):
+                                        rows_computed=chunk_p) as rec:
                             state = self._dispatch_chunk(chunk_slot, state)
+                        if self._chunk_counts is not None:
+                            counted_chunks.append((rec, self._chunk_counts))
                     decoding = sum(m == "decode" for m in self._slot_mode)
                     turn.attrs.update(
                         chunk=chunk_p, decoding=decoding,
@@ -2038,7 +2063,8 @@ class ContinuousBatcher:
                         with self._span("serve.dispatch_decode", decoding=decoding,
                                         slots=self.B, window=window) as rec:
                             state, new_pending = self._dispatch_decode(state, force_stop)
-                        new_pending += (rec,)
+                        new_pending += (rec, counted_chunks)
+                        counted_chunks = []
                         force_stop[:] = False
                         t = time.monotonic()
                         if last_dispatch_t is not None:
@@ -2061,6 +2087,8 @@ class ContinuousBatcher:
                         if all(m == "free" for m in self._slot_mode):
                             break
             self._sync(state)
+            for rec, counts in counted_chunks:  # chunks that no window followed
+                self._set_counts(rec, self._layout["counters"]["chunk"], counts)
             self._publish_pool_gauges()
             results, self._results = self._results, {}
             wave.attrs["finished"] = len(results)

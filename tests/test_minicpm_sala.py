@@ -310,7 +310,7 @@ def test_cache_layout_record_and_pool_layout(small):
     engine = engine_for(model, params)
     (record,) = [r for r in get_span_ring().snapshot() if r.name == "serve.cache_layout"]
     assert record.attrs == {"kv_bytes_per_token": 2 * 2 * 16 * 2 * 4, "state_bytes_per_slot": 6 * 4 * 16 * 16 * 4,
-                            "kv_layers": 2, "state_layers": 6}
+                            "kv_layers": 2, "state_layers": 6, "slot_bytes": {"state": 6 * 4 * 16 * 16 * 4}}
     assert cache_layout(model)["by_slot"] == ("state",) and cache_layout(object()) == PLAIN_CACHE_LAYOUT
     pool = init_kv_pool(model, 10, 64, dtype=jnp.bfloat16, slots=5)
     assert pool["k"].shape == (2, 11, 64, 2, 16) and pool["state"].shape == (6, 5, 4, 16, 16)
