@@ -21,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 from ..modules import ModelOutput, Module
 from ..ops.attention import attention as _attention
 from ..ops.losses import cross_entropy_loss
+from ..ops.paged_attention import view_capacity
 
 
 from ..ops.norms import layer_norm as _layer_norm
@@ -233,7 +234,7 @@ class GPT2(Module):
         wpe_positions = slot_positions if positions is None else positions
         view = cache.get("view")  # paged serving's read-only chains (Llama._apply_cached)
         if view is not None and positions is None:
-            wpe_positions = slot_positions + view["k"].shape[2]
+            wpe_positions = slot_positions + view_capacity(view)
         chunk_mask = (
             attention_mask.astype(jnp.int32)
             if attention_mask is not None

@@ -38,6 +38,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..modules import ModelOutput, Module
 from ..ops.losses import cross_entropy_loss
+from ..ops.paged_attention import view_capacity
 from ..utils.dataclasses import resolve_remat_policy
 
 
@@ -756,9 +757,11 @@ class Llama(Module):
         slot_positions = jnp.broadcast_to(slot_positions, (B, S))
         rope_positions = slot_positions if positions is None else positions
         if view is not None:
-            capacity += view["k"].shape[2]
+            # The table's width, not this view's: the engine gathers a decode
+            # window's view at one of a few widths (ops/paged_attention.py).
+            capacity += view_capacity(view)
             if positions is None:
-                rope_positions = slot_positions + view["k"].shape[2]
+                rope_positions = slot_positions + view_capacity(view)
         chunk_mask = (
             attention_mask.astype(jnp.int32)
             if attention_mask is not None

@@ -101,6 +101,16 @@ def cache_layout(module) -> dict:
     return layout
 
 
+def view_capacity(view) -> int:
+    """The columns of the WHOLE block table behind a gathered view: the one
+    length a generation has, whatever width this program's view was gathered
+    at (the engine's decode window takes the narrowest of a few widths that
+    covers its longest chain, so ``view["k"].shape[2]`` differs from window to
+    window). The engine states it as ``view["capacity"]``, a static int; a
+    view built by hand without one is as wide as its table."""
+    return view.get("capacity", view["k"].shape[2])
+
+
 def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
                  quant: str | None = None, slots: int = 1):
     """Allocate the block pool for ``module``'s cache layout.

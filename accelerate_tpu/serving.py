@@ -49,7 +49,11 @@ scheduling idea of vLLM/Orca, shaped for XLA's static-compilation model:
   contiguous READ-ONLY view and give the model forward a two-part cache:
   that view plus an empty write window the size of what the program writes
   (``_paged_view_cache``). A chunk program belongs to one slot and runs at
-  batch 1: that slot's chain, state and row, no other. Every layer attends
+  batch 1: that slot's chain, state and row, no other. The decode window's
+  view is as wide as the longest chain among the rows that decode, rounded
+  up to a half, three quarters or the whole of the table: one program holds
+  the three widths as the branches of a ``lax.switch`` and computes the index
+  itself (``_decode``). Every layer attends
   both parts under one softmax and writes the window alone
   (``ops/attention.py`` ``cached_attention(prefix=...)``); the decode
   window's scan carries the window, never the view, and
@@ -86,6 +90,7 @@ coincide, which is what makes engine output == solo output).
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from collections import deque
@@ -240,6 +245,32 @@ def _first_stop_end(row: np.ndarray, stops: tuple) -> int | None:
 # distributions; these only back slo_report()'s recent view and the tests'
 # structural pins).
 _SLO_HISTORY = 4096
+
+# A decode window gathers a view as wide as its longest decoding chain, rounded
+# up to a half, three quarters or the whole of the table (``_decode``). A width
+# under this many columns is not made: such a view is a few megabytes a layer,
+# and a step's read of the weights dwarfs it.
+_MIN_VIEW_COLS = 512
+# Nor is any narrower width made where the whole view is under this share of
+# what a decode step reads (the weights and the view): each further body of
+# the program costs seconds of every start (tracing and loading it), which a
+# narrower view cannot pay back where the weights are most of the step. On the
+# chip (PERF.md, PR 37): Qwen3-1.7B's 12 slots of 1,568 columns are 39% of a
+# step and three widths cut a window by a fifth for 1.4 s of set-up; Laguna's
+# view is 16% and MiniCPM-SALA's 4%, and three widths cost them 8 and 4 s of
+# set-up for 2% of their token time.
+_MIN_VIEW_SHARE = 0.25
+
+
+def _view_ladder(max_blocks: int, block_size: int, view_share: float) -> tuple:
+    """The widths, in table entries and ascending, that a decode window's view
+    may take; the last is the whole table. One width means one program body.
+    ``view_share``: the whole view's share of a decode step's bytes."""
+    if view_share < _MIN_VIEW_SHARE:
+        return (max_blocks,)
+    widths = sorted({-(-max_blocks // 2), -(-3 * max_blocks // 4), max_blocks})
+    return tuple(nb for nb in widths
+                 if nb == max_blocks or nb * block_size >= _MIN_VIEW_COLS)
 
 
 @dataclass
@@ -524,6 +555,13 @@ class ContinuousBatcher:
         self.stream = None
         self._streamed: dict[int, int] = {}
         self.reset()
+        layers, _, _, heads, dim = self._pool["k"].shape
+        view_bytes = (2 * layers * self.B * self.max_blocks_per_slot * self.block_size
+                      * heads * dim * jnp.dtype(self.cache_dtype).itemsize)
+        weight_bytes = sum(x.size * x.dtype.itemsize
+                           for x in jax.tree_util.tree_leaves(self.params))
+        self._view_ladder = _view_ladder(self.max_blocks_per_slot, self.block_size,
+                                         view_bytes / (view_bytes + weight_bytes))
         if trace_requests:
             self._record_cache_layout()
 
@@ -895,7 +933,15 @@ class ContinuousBatcher:
         prefills for a chunk program. ``view`` (``{"k", "v"}`` of
         (L, B, T, Hkv, D) and a ``"kv_mask"`` of (B, T)) is those rows' block
         chains gathered once, and is read-only: a program closes over it,
-        every layer attends it, nothing writes it. ``window`` is an ordinary
+        every layer attends it, nothing writes it. ``T`` is the width of the
+        ``tables`` handed in: the whole table for a chunk program and the
+        verify round, one of the ladder's widths for a decode window
+        (``_decode`` chooses, and hands in the table's leading entries), so a
+        row whose chain is longer than ``T`` gets a view cut short. The view
+        also carries ``"capacity"``, a static int: the columns of the WHOLE
+        table. A model that needs one length for a whole generation (a
+        length-dependent rope) takes it from there and never from
+        ``view["k"].shape``. ``window`` is an ordinary
         empty cache ``write_cols`` wide (``"k"``, ``"v"`` of
         (L, B, write_cols, Hkv, D), ``"kv_mask"``, ``"pos"`` 0) whose columns
         follow the view's: what the program writes lands there, at one
@@ -908,7 +954,7 @@ class ContinuousBatcher:
         comparison masks stale bits of reused (freed→reallocated) blocks, so
         the free-list never needs device-side scrubbing."""
         bs = self.block_size
-        t = self.max_blocks_per_slot * bs
+        t = tables.shape[1] * bs
         # Registry-dispatched assembly (op `paged_gather`): the Pallas
         # chain-walk kernel skips slots with an empty chain (bucket padding /
         # drained slots — their view rows are masked garbage on the reference
@@ -932,7 +978,8 @@ class ContinuousBatcher:
         vmask = jnp.where(jnp.arange(t)[None] < lens[:, None], vmask, 0)
         zeros = jnp.zeros(view_k.shape[:2] + (write_cols,) + view_k.shape[3:],
                           view_k.dtype)
-        view = {"k": view_k, "v": view_v, "kv_mask": vmask}
+        view = {"k": view_k, "v": view_v, "kv_mask": vmask,
+                "capacity": self.max_blocks_per_slot * bs}
         window = {"k": zeros, "v": zeros, "pos": jnp.int32(0),
                   "kv_mask": jnp.zeros((b, write_cols), jnp.int32)}
         if self._stateful:
@@ -943,6 +990,26 @@ class ContinuousBatcher:
                 window[name] = pool[name]
             view = self.module.prepare_view(view)
         return view, window
+
+    def _view_rung(self, lens, commit):
+        """Which of the ladder's widths a decode window takes, computed by the
+        program from its own arguments: the first that covers the longest
+        chain among the rows that decode (a view holds columns ``[0, len)``;
+        the window's new columns live beside it, not in it)."""
+        longest = jnp.max(jnp.where(commit, lens, 0))
+        narrower = [nb * self.block_size for nb in self._view_ladder[:-1]]
+        return jnp.sum(longest > jnp.asarray(narrower, jnp.int32)).astype(jnp.int32)
+
+    def _view_cols(self) -> int:
+        """The host's account of the same choice, from its own bookkeeping and
+        free (the ``view_cols`` of ``serve.dispatch_decode``): the columns of
+        the view that a window dispatched now gathers. The verify round
+        gathers the whole table."""
+        bs = self.block_size
+        longest = max((int(self._slot_len[s]) for s, mode in enumerate(self._slot_mode)
+                       if mode == "decode"), default=0)
+        ladder = self._view_ladder[-1:] if self.speculative_k else self._view_ladder
+        return next((nb for nb in ladder if longest <= nb * bs), ladder[-1]) * bs
 
     def _scatter_pool(self, pool, blk, off, k_new, v_new, mask_new):
         """Append freshly written view columns onto chain tails — the single
@@ -1141,7 +1208,20 @@ class ContinuousBatcher:
         where ``report`` is an optimization-barrier'd (active, n_out,
         out_buf) copy the host can read AFTER donating ``state`` to the next
         window — the one-window-lookahead handle that makes the steady-state
-        engine loop's sync non-blocking."""
+        engine loop's sync non-blocking.
+
+        The view is as wide as the longest chain among the rows that decode,
+        rounded up to one of the ladder's widths (``_view_ladder``): the
+        program holds the gather and the steps once a width, as the branches
+        of one ``lax.switch`` whose index it computes itself from ``lens`` and
+        ``commit`` (``_view_rung``), so the host decides nothing and one
+        executable serves every window. The pool is read-only inside the
+        branches; the scatter and the by-slot write-back follow the switch
+        and read the whole table. A row that does not decode (free, or
+        mid-prefill with a chain longer than the width) gets a view cut
+        short: it is inactive, commits nothing and keeps its state. An engine
+        whose ladder is one width (a small table, or a view that is a small
+        share of a step's bytes: ``_view_ladder``) has no switch."""
         if self._decode_fn is not None:
             return self._decode_fn
         module = self.module
@@ -1151,6 +1231,7 @@ class ContinuousBatcher:
         by_slot = self._layout["by_slot"]
         counted = self._layout["counters"].get("decode", ())
         row_mask = self._layout["row_mask"]
+        ladder = self._view_ladder
 
         def serve_decode_window(params, pool, tables, lens, commit, force_stop, state):
             (tok, pos, n_out, active, out_buf, keys,
@@ -1161,11 +1242,10 @@ class ContinuousBatcher:
             active = active & ~force_stop
             state = (tok, pos, n_out, active, out_buf, keys,
                      slot_max, slot_temp, slot_eos)
-            view, window = self._paged_view_cache(pool, tables, lens, w)
 
-            def one_step(carry, _):
-                # The carry is the window alone; the view is closed over and
-                # constant across the steps.
+            def one_step(view, carry, _):
+                # The carry is the window alone; the view is constant across
+                # the steps.
                 window, state = carry[:2]
                 (tok, pos, n_out, active, out_buf, keys,
                  slot_max, slot_temp, slot_eos) = state
@@ -1205,9 +1285,22 @@ class ContinuousBatcher:
                     return (window2, state, seen), None
                 return (window2, state), None
 
-            carry = (window, state) + (
-                (jnp.zeros((len(counted),), jnp.float32),) if counted else ())
-            carry, _ = jax.lax.scan(one_step, carry, None, length=w)
+            def steps(nb):
+                """The window's steps over a view of the tables' first ``nb``
+                entries: ``(window, state)`` and, where the model counts, what
+                it counted. Reads the pool, writes none of it."""
+                view, window = self._paged_view_cache(pool, tables[:, :nb], lens, w)
+                carry = (window, state) + (
+                    (jnp.zeros((len(counted),), jnp.float32),) if counted else ())
+                carry, _ = jax.lax.scan(functools.partial(one_step, view), carry, None, length=w)
+                return carry
+
+            if len(ladder) == 1:
+                carry = steps(ladder[0])
+            else:
+                carry = jax.lax.switch(
+                    self._view_rung(lens, commit),
+                    [functools.partial(steps, nb) for nb in ladder])
             window, state = carry[:2]
             # Persist the window: committed slots append their written
             # columns (valid or holed: a committed chain advances by the
@@ -2036,6 +2129,7 @@ class ContinuousBatcher:
             last_dispatch_t = None
             window_pace = None
             window = (self.speculative_k + 1) if self.speculative_k else self.sync_every
+            view_cols_full = self.max_blocks_per_slot * self.block_size
             while True:
                 with self._span("serve.iteration") as turn:
                     now = time.monotonic()
@@ -2061,7 +2155,9 @@ class ContinuousBatcher:
                     new_pending = None
                     if decoding:
                         with self._span("serve.dispatch_decode", decoding=decoding,
-                                        slots=self.B, window=window) as rec:
+                                        slots=self.B, window=window,
+                                        view_cols=self._view_cols(),
+                                        view_cols_full=view_cols_full) as rec:
                             state, new_pending = self._dispatch_decode(state, force_stop)
                         new_pending += (rec, counted_chunks)
                         counted_chunks = []

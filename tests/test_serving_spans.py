@@ -157,9 +157,12 @@ def test_dispatch_spans_count_what_the_dispatch_log_counts(traced_wave):
         mine = [r for r in chunks if r.rid == rid]
         assert sum(r.attrs["tokens"] for r in mine) == n
         assert [r.attrs["final"] for r in mine] == [False] * (len(mine) - 1) + [True]
+    full = engine.max_blocks_per_slot * engine.block_size
     for w in windows:
         assert w.attrs["slots"] == 2 and w.attrs["window"] == 4
         assert 1 <= w.attrs["decoding"] <= 2
+        # the view's width: this engine's table is under 512 columns, one width
+        assert w.attrs["view_cols"] == w.attrs["view_cols_full"] == full
 
 
 def test_process_report_tokens_sum_to_the_tokens_returned(traced_wave):
