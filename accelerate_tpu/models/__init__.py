@@ -1,4 +1,5 @@
 from .bert import BertConfig, BertForSequenceClassification
+from .glm5 import Glm5, Glm5Config
 from .gpt2 import GPT2, GPT2Config
 from .gptx import GPTX, GPTXConfig
 from .laguna import Laguna, LagunaConfig

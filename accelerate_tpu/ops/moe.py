@@ -318,12 +318,25 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, *, k: int, capacity_factor: float
 # ---------------------------------------------------------------------------
 # The serving expert layer: drop-free, told which experts it holds.
 
-def route_top_k(router_logits, k: int, *, norm_topk_prob: bool = True):
-    """Softmax over every expert the router scores, the ``k`` largest, and (with
+def route_top_k(router_logits, k: int, *, norm_topk_prob: bool = True,
+                scoring: str = "softmax", bias=None):
+    """Every expert the router scores (``scoring``: ``"softmax"`` over all of
+    them, or ``"sigmoid"`` of each logit alone), the ``k`` largest, and (with
     ``norm_topk_prob``) their weights renormalised to sum to 1: all float32.
-    ``router_logits``: (N, R). Returns ``(weights (N, k), experts (N, k))``."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    ``bias`` (R,), where given, is added to the scores for the choice alone:
+    the chosen experts' weights are their scores without it. ``router_logits``:
+    (N, R). Returns ``(weights (N, k), experts (N, k))``."""
+    if scoring == "softmax":
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    else:
+        raise ValueError(f"router scoring must be 'softmax' or 'sigmoid', got {scoring!r}")
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        _, experts = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, experts
@@ -390,7 +403,8 @@ def _experts_grouped(x, weights, local, held, w_gate, w_up, w_down, layer, preci
 
 def expert_share_ffn(x, router_w, w_gate, w_up, w_down, *, first: int, k: int,
                      norm_topk_prob: bool = True, scale: float = 1.0,
-                     precision: str = "default", row_mask=None, layer=None):
+                     precision: str = "default", row_mask=None, layer=None,
+                     scoring: str = "softmax", bias=None):
     """The part of a routed expert layer that the experts held here give
     (``model-configs`` guide, section 4; what expert parallelism asks anyway).
 
@@ -399,8 +413,9 @@ def expert_share_ffn(x, router_w, w_gate, w_up, w_down, *, first: int, k: int,
     ``first .. first + E`` of the R; with ``layer`` (a traced index will do)
     the three are stacked over layers, (L, E, ...), and ``layer`` says which:
     a model that scans its layers hands the stacks over whole. Every row is
-    routed over all R experts (float32 softmax, the ``k`` largest,
-    renormalised under ``norm_topk_prob``, times ``scale``); the result is the
+    routed over all R experts (float32 scores by ``scoring``, the ``k`` largest
+    of score plus ``bias`` where one is given, renormalised under
+    ``norm_topk_prob``, times ``scale``: :func:`route_top_k`); the result is the
     weighted sum over the claims that name an expert held here, and nothing
     for the others: **no claim is dropped, no capacity exists**, and no code
     stands in for absent experts. A row whose ``row_mask`` (N,) is false
@@ -418,7 +433,8 @@ def expert_share_ffn(x, router_w, w_gate, w_up, w_down, *, first: int, k: int,
     if layer is None:
         layer, (w_gate, w_up, w_down) = 0, (w_gate[None], w_up[None], w_down[None])
     experts_held = w_gate.shape[1]
-    weights, chosen = route_top_k(router_logits(x, router_w), k, norm_topk_prob=norm_topk_prob)
+    weights, chosen = route_top_k(router_logits(x, router_w), k, norm_topk_prob=norm_topk_prob,
+                                  scoring=scoring, bias=bias)
     weights = weights * scale
     local = chosen - first
     held = (local >= 0) & (local < experts_held)

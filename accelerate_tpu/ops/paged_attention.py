@@ -73,8 +73,11 @@ from .attention import cached_attention
 
 
 # What a module's ``init_cache`` dict holds, for the paged engine. ``by_token``
-# entries are ``(layers, batch, columns, kv_heads, head_dim)`` and become block
-# pools, paged by token through the block tables; ``by_slot`` entries are
+# names the entries that become block pools, paged by token through the block
+# tables: each ``(layers, batch, columns, heads, dim)`` with its own layers,
+# heads, dim and dtype (keys and values; a latent row beside an indexer's key);
+# every one is gathered into the view and given a write window under its own
+# name. ``by_slot`` entries are
 # ``(layers, batch, ...)`` of any dtype and are held once a batch slot, whatever
 # the sequence's length (a recurrent state). ``dense_chain`` asks the engine to
 # keep a chain free of holes below its frontier, so that a key's column is its
@@ -84,20 +87,20 @@ from .attention import cached_attention
 # prefill chunk; the engine sums them on the device and sets them on the span
 # that dispatched the program. ``row_mask`` asks the decode step for
 # ``attention_mask`` = the rows that decode (a model whose work depends on
-# which rows are real: a free slot's pad token must claim no expert). A module
-# without a ``cache_layout`` is the plain case.
+# which rows are real: a free slot's pad token must claim no expert).
+# ``speculative`` says whether the cached forward returns the logits of every
+# position of a multi-token window, which the verify round compares (a model
+# that returns the last position's alone is refused with ``speculative_k``). A
+# module without a ``cache_layout`` is the plain case.
 PLAIN_CACHE_LAYOUT = {"by_token": ("k", "v"), "by_slot": (), "dense_chain": False,
-                      "counters": {}, "row_mask": False}
+                      "counters": {}, "row_mask": False, "speculative": True}
 
 
 def cache_layout(module) -> dict:
     """``module.cache_layout`` over the plain layout: which entries of its
     ``init_cache`` are paged by token and which are held by slot."""
     layout = {**PLAIN_CACHE_LAYOUT, **getattr(module, "cache_layout", {})}
-    if tuple(layout["by_token"]) != ("k", "v"):
-        raise ValueError(
-            f"the paged pool pages 'k' and 'v' by token; {type(module).__name__}."
-            f"cache_layout asks for {layout['by_token']!r}")
+    layout["by_token"] = tuple(layout["by_token"])
     return layout
 
 
@@ -105,28 +108,30 @@ def view_capacity(view) -> int:
     """The columns of the WHOLE block table behind a gathered view: the one
     length a generation has, whatever width this program's view was gathered
     at (the engine's decode window takes the narrowest of a few widths that
-    covers its longest chain, so ``view["k"].shape[2]`` differs from window to
+    covers its longest chain, so the view's columns differ from window to
     window). The engine states it as ``view["capacity"]``, a static int; a
     view built by hand without one is as wide as its table."""
-    return view.get("capacity", view["k"].shape[2])
+    return view.get("capacity", view["kv_mask"].shape[1])
 
 
 def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
                  quant: str | None = None, slots: int = 1):
     """Allocate the block pool for ``module``'s cache layout.
 
-    Returns ``{"k": (L, N, bs, Hkv, D), "v": same, "mask": (N, bs) int32}``
-    with ``N = num_blocks + 1`` — block 0 is the reserved trash block (see
-    module docstring). The layer/head/dim axes are probed from the ``by_token``
-    entries of the module's own ``init_cache`` (:func:`cache_layout`), so every
-    cached decoder family (Llama/GPT-2/GPT-X) gets its exact layout without a
-    second cache contract; ``L`` counts the layers that HAVE keys and values.
+    Returns ``{name: (L, N, bs, H, D) for each by_token entry, "mask": (N, bs)
+    int32}`` (``"k"`` and ``"v"`` for a model that names nothing) with ``N =
+    num_blocks + 1`` — block 0 is the reserved trash block (see module
+    docstring). Each entry's layer/head/dim axes and dtype are probed from the
+    module's own ``init_cache`` (:func:`cache_layout`), so every cached decoder
+    family (Llama/GPT-2/GPT-X) gets its exact layout without a second cache
+    contract and two entries need not agree in any of them; ``L`` counts the
+    layers that HAVE the entry.
     Each ``by_slot`` entry of the probe (``(layers, 1, ...)``) is allocated as
     ``(layers, slots, ...)`` zeros of its own dtype beside the blocks: one a
     batch slot, never paged.
 
-    ``quant="int8"`` stores the K/V payloads as int8 and adds per-block scale
-    tables ``{"k_scale": (L, N, bs) float32, "v_scale": same}`` — one scale
+    ``quant="int8"`` stores the payloads as int8 and adds per-block scale
+    tables ``{"<name>_scale": (L, N, bs) float32}`` an entry — one scale
     per token row per layer (``ops/int8.quantize_kv``), so the pool costs
     ``1 + 8/(2·Hkv·D)`` bytes per bf16 element instead of 2: ~1.9x the
     chains per HBM byte at realistic head counts. Dequantization happens at
@@ -136,17 +141,14 @@ def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
         raise ValueError(f"kv pool quant must be None or 'int8', got {quant!r}")
     layout = cache_layout(module)
     probe = module.init_cache(1, block_size, dtype=dtype)
-    L, _, _, hkv, hd = probe["k"].shape
     n = num_blocks + 1
-    store = jnp.int8 if quant == "int8" else dtype
-    pool = {
-        "k": jnp.zeros((L, n, block_size, hkv, hd), store),
-        "v": jnp.zeros((L, n, block_size, hkv, hd), store),
-        "mask": jnp.zeros((n, block_size), jnp.int32),
-    }
-    if quant == "int8":
-        pool["k_scale"] = jnp.zeros((L, n, block_size), jnp.float32)
-        pool["v_scale"] = jnp.zeros((L, n, block_size), jnp.float32)
+    pool = {"mask": jnp.zeros((n, block_size), jnp.int32)}
+    for name in layout["by_token"]:
+        L, _, _, heads, dim = probe[name].shape
+        store = jnp.int8 if quant == "int8" else probe[name].dtype
+        pool[name] = jnp.zeros((L, n, block_size, heads, dim), store)
+        if quant == "int8":
+            pool[name + "_scale"] = jnp.zeros((L, n, block_size), jnp.float32)
     for name in layout["by_slot"]:
         held = probe[name]
         pool[name] = jnp.zeros((held.shape[0], slots) + held.shape[2:], held.dtype)
@@ -154,42 +156,50 @@ def init_kv_pool(module, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
 
 
 def pool_bytes(pool, layout=PLAIN_CACHE_LAYOUT) -> dict:
-    """The cache's persistent device bytes by kind: ``"kv"`` (keys and values
+    """The cache's persistent device bytes by kind: ``"kv"`` (the entries
     paged by token and, where quantized, their scales) and ``"state"`` (the
     ``by_slot`` entries)."""
-    kv = sum(int(pool[name].nbytes) for name in ("k", "v", "k_scale", "v_scale") if name in pool)
-    return {"kv": kv, "state": sum(int(pool[name].nbytes) for name in layout["by_slot"])}
+    paged = [name + tail for name in layout["by_token"] for tail in ("", "_scale")]
+    return {"kv": sum(int(pool[name].nbytes) for name in paged if name in pool),
+            "state": sum(int(pool[name].nbytes) for name in layout["by_slot"])}
+
+
+def token_bytes(pool, layout=PLAIN_CACHE_LAYOUT) -> dict:
+    """What one token costs in each ``by_token`` entry over its layers, bytes
+    (a quantized entry's scale included)."""
+    def one(x):
+        return int(x.nbytes) // (x.shape[1] * x.shape[2])
+    return {name: one(pool[name]) + (one(pool[name + "_scale"]) if name + "_scale" in pool else 0)
+            for name in layout["by_token"]}
 
 
 def pool_is_quantized(pool) -> bool:
     """Whether a pool carries int8 payloads + per-block scale tables."""
-    return "k_scale" in pool
+    return any(name.endswith("_scale") for name in pool)
 
 
-def export_chain_blocks(pool, block_ids):
-    """Extract one chain's K/V/mask block contents from the pool: the device
-    face of the prefill→decode KV handoff (serving_net/handoff.py).
+def export_chain_blocks(pool, block_ids, names=PLAIN_CACHE_LAYOUT["by_token"]):
+    """Extract one chain's block contents from the pool (the ``by_token``
+    entries ``names`` and the mask): the device face of the prefill→decode KV
+    handoff (serving_net/handoff.py).
 
     ``block_ids``: ``(n,)`` int32 pool block indices in chain order. Returns
-    ``{"k": (L, n, bs, Hkv, D), "v": same, "mask": (n, bs)}`` — a bounded
+    ``{name: (L, n, bs, H, D), "mask": (n, bs)}`` — a bounded
     per-chain payload (n blocks, never the pool), which is the whole point
     of the paged layout: ownership moves block-by-block without copying the
     cache. Pure gather; safe to jit or call eagerly."""
     ids = jnp.asarray(block_ids, jnp.int32)
-    chain = {
-        "k": jnp.take(pool["k"], ids, axis=1),
-        "v": jnp.take(pool["v"], ids, axis=1),
-        "mask": jnp.take(pool["mask"], ids, axis=0),
-    }
+    chain = {name: jnp.take(pool[name], ids, axis=1) for name in names}
+    chain["mask"] = jnp.take(pool["mask"], ids, axis=0)
     if pool_is_quantized(pool):
         # Quantized chains ship int8 payloads + their scales: the handoff
         # wire cost drops with the pool, and the importer splices verbatim.
-        chain["k_scale"] = jnp.take(pool["k_scale"], ids, axis=1)
-        chain["v_scale"] = jnp.take(pool["v_scale"], ids, axis=1)
+        for name in names:
+            chain[name + "_scale"] = jnp.take(pool[name + "_scale"], ids, axis=1)
     return chain
 
 
-def import_chain_blocks(pool, block_ids, chain):
+def import_chain_blocks(pool, block_ids, chain, names=PLAIN_CACHE_LAYOUT["by_token"]):
     """Splice an exported chain's block contents into ``pool`` at freshly
     allocated ``block_ids`` — the decode-host half of the handoff. The
     caller (host free-list surgery in serving_net/handoff.py) guarantees the
@@ -198,19 +208,17 @@ def import_chain_blocks(pool, block_ids, chain):
     reused blocks are overwritten rather than frontier-masked. Returns the
     updated pool (donation-friendly: one scatter per array)."""
     ids = jnp.asarray(block_ids, jnp.int32)
-    out = {
-        "k": pool["k"].at[:, ids].set(chain["k"].astype(pool["k"].dtype)),
-        "v": pool["v"].at[:, ids].set(chain["v"].astype(pool["v"].dtype)),
-        "mask": pool["mask"].at[ids].set(chain["mask"]),
-    }
+    out = {**pool, "mask": pool["mask"].at[ids].set(chain["mask"])}
+    for name in names:
+        out[name] = pool[name].at[:, ids].set(chain[name].astype(pool[name].dtype))
     if pool_is_quantized(pool):
-        if "k_scale" not in chain:
+        if names[0] + "_scale" not in chain:
             raise ValueError(
                 "import_chain_blocks: quantized pool but the chain carries no "
                 "scales — exporter and importer must agree on kv_quant"
             )
-        out["k_scale"] = pool["k_scale"].at[:, ids].set(chain["k_scale"])
-        out["v_scale"] = pool["v_scale"].at[:, ids].set(chain["v_scale"])
+        for name in names:
+            out[name + "_scale"] = pool[name + "_scale"].at[:, ids].set(chain[name + "_scale"])
     return out
 
 

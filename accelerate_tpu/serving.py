@@ -103,8 +103,8 @@ import jax.numpy as jnp
 
 from .generation import _unwrap, left_align, mask_positions
 from .ops.int8 import quantize_kv
-from .ops.paged_attention import (cache_layout, gather_block_mask, gather_view, init_kv_pool,
-                                  pool_bytes)
+from .ops.paged_attention import (PLAIN_CACHE_LAYOUT, cache_layout, gather_block_mask, gather_view,
+                                  init_kv_pool, pool_bytes, pool_is_quantized, token_bytes)
 from .utils.environment import safe_donate_argnums
 from .utils.transfer import host_fetch
 
@@ -398,12 +398,18 @@ class ContinuousBatcher:
         # be shared or truncated block by block, so prefix aliasing stands
         # down and speculative decoding is refused for such a model.
         self._layout = cache_layout(module)
+        self._by_token = self._layout["by_token"]
         self._stateful = bool(self._layout["by_slot"])
         if self._stateful and self.speculative_k:
             raise ValueError(
                 f"speculative decoding rolls a rejected draft back by block-table "
                 f"truncation, which cannot roll back the recurrent state "
                 f"{type(module).__name__} holds by slot; run it with speculative_k=0")
+        if self.speculative_k and not self._layout["speculative"]:
+            raise ValueError(
+                f"the speculative verify round compares the logits of every position of "
+                f"its window; {type(module).__name__}'s cached forward returns the last "
+                f"position's alone (cache_layout 'speculative'): run it with speculative_k=0")
         # ----------------------------------------------------- the KV pool
         # A block pool (ops/paged_attention.py): `num_blocks` blocks of
         # `block_size` token slots shared by all slots via per-slot block
@@ -555,9 +561,13 @@ class ContinuousBatcher:
         self.stream = None
         self._streamed: dict[int, int] = {}
         self.reset()
-        layers, _, _, heads, dim = self._pool["k"].shape
-        view_bytes = (2 * layers * self.B * self.max_blocks_per_slot * self.block_size
-                      * heads * dim * jnp.dtype(self.cache_dtype).itemsize)
+        # A view column's bytes over the by_token entries (a quantized pool's
+        # view is dequantized to the cache's dtype).
+        column_bytes = sum(
+            x.shape[0] * x.shape[3] * x.shape[4]
+            * jnp.dtype(self.cache_dtype if self.kv_quant else x.dtype).itemsize
+            for x in (self._pool[name] for name in self._by_token))
+        view_bytes = self.B * self.max_blocks_per_slot * self.block_size * column_bytes
         weight_bytes = sum(x.size * x.dtype.itemsize
                            for x in jax.tree_util.tree_leaves(self.params))
         self._view_ladder = _view_ladder(self.max_blocks_per_slot, self.block_size,
@@ -567,8 +577,9 @@ class ContinuousBatcher:
 
     def _record_cache_layout(self):
         """One ``serve.cache_layout`` record at engine start: what a token
-        costs in keys and values, what a slot costs in recurrent state, and
-        how many layers hold each (docs/observability.md)."""
+        costs in the entries paged by token (together and by name), what a
+        slot costs in recurrent state, and how many layers hold each
+        (docs/observability.md)."""
         from .telemetry.spans import record_span
 
         pool, now = self._pool, time.perf_counter()
@@ -577,9 +588,12 @@ class ContinuousBatcher:
             "serve.cache_layout", now, now,
             kv_bytes_per_token=self._pool_bytes["kv"] // (pool["mask"].shape[0] * self.block_size),
             state_bytes_per_slot=self._pool_bytes["state"] // self.B,
-            kv_layers=int(pool["k"].shape[0]),
+            kv_layers=int(pool[self._by_token[0]].shape[0]),
             state_layers=sum(int(x.shape[0]) for x in held.values()),
             slot_bytes={name: int(x.nbytes) // self.B for name, x in held.items()},
+            # A model that names its own token-paged entries: each one's bytes a token.
+            **({"token_bytes": token_bytes(pool, self._layout)}
+               if self._by_token != PLAIN_CACHE_LAYOUT["by_token"] else {}),
         )
 
     def _build_draft_from_preset(self, preset: str):
@@ -644,6 +658,8 @@ class ContinuousBatcher:
                          dtype=self.cache_dtype)
             if self.speculative_k else None
         )
+        self._draft_by_token = (cache_layout(self._draft_module)["by_token"]
+                                if self.speculative_k else ())
         self._tok = jnp.full((B,), self.pad, jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._n_out = jnp.zeros((B,), jnp.int32)
@@ -729,9 +745,12 @@ class ContinuousBatcher:
         its per-token scale planes too; speculative decoding adds the draft
         pool's blocks — both layouts the memcheck gate must cover."""
         total = sum(self._pool_bytes.values())
-        if self._draft_pool is not None:
-            total += int(self._draft_pool["k"].nbytes + self._draft_pool["v"].nbytes)
-        return total
+        return total + self._draft_pool_bytes()
+
+    def _draft_pool_bytes(self) -> int:
+        if self._draft_pool is None:
+            return 0
+        return sum(int(self._draft_pool[name].nbytes) for name in self._draft_by_token)
 
     @property
     def kv_consumed_slots_peak(self) -> int:
@@ -757,10 +776,7 @@ class ContinuousBatcher:
                 sum(m != "free" for m in self._slot_mode) if self._stateful else 0),
             "kv_quant": self.kv_quant,
             "speculative_k": self.speculative_k,
-            "draft_pool_bytes": (
-                int(self._draft_pool["k"].nbytes + self._draft_pool["v"].nbytes)
-                if self._draft_pool is not None else 0
-            ),
+            "draft_pool_bytes": self._draft_pool_bytes(),
         }
 
     def spec_report(self) -> dict:
@@ -925,8 +941,10 @@ class ContinuousBatcher:
         return jnp.where(temps > 0.0, sampled, greedy)
 
     # ------------------------------------------------------------- compiled
-    def _paged_view_cache(self, pool, tables, lens, write_cols: int):
+    def _paged_view_cache(self, pool, tables, lens, write_cols: int, names=None):
         """The two-part cache of a paged program, as ``(view, window)``.
+        ``names``: the pool's entries paged by token (the engine's own model's
+        by default; the draft's for its pool), written ``"k"``, ``"v"`` below.
 
         ``B`` is the number of rows of ``tables`` and ``lens``: every slot
         for the decode window and the verify round, the one slot that
@@ -964,24 +982,27 @@ class ContinuousBatcher:
         # gather kernel folds the per-token rescale into its DMA-to-VMEM step
         # (ops/pallas/paged_decode.py), the reference path multiplies after
         # the gather — bit-identical either way (the registry parity seam).
-        scales_k = pool.get("k_scale")
-        scales_v = pool.get("v_scale")
-        out_dt = self.cache_dtype if scales_k is not None else None
-        view_k = gather_view(pool["k"], tables, active=active, scales=scales_k,
-                             out_dtype=out_dt,
-                             backend=self.kernels)      # (L, B, T, Hkv, D)
-        view_v = gather_view(pool["v"], tables, active=active, scales=scales_v,
-                             out_dtype=out_dt,
-                             backend=self.kernels)
+        names = self._by_token if names is None else names
+        view = {}
+        for name in names:
+            scales = pool.get(name + "_scale")
+            view[name] = gather_view(
+                pool[name], tables, active=active, scales=scales,
+                out_dtype=self.cache_dtype if scales is not None else None,
+                backend=self.kernels)                     # (L, B, T, H, D)
         vmask = gather_block_mask(pool["mask"], tables)  # (B, T)
         b = vmask.shape[0]
         vmask = jnp.where(jnp.arange(t)[None] < lens[:, None], vmask, 0)
-        zeros = jnp.zeros(view_k.shape[:2] + (write_cols,) + view_k.shape[3:],
-                          view_k.dtype)
-        view = {"k": view_k, "v": view_v, "kv_mask": vmask,
-                "capacity": self.max_blocks_per_slot * bs}
-        window = {"k": zeros, "v": zeros, "pos": jnp.int32(0),
-                  "kv_mask": jnp.zeros((b, write_cols), jnp.int32)}
+        zeros = {}  # entries of one shape and dtype share one
+        window = {}
+        for name in names:
+            x = view[name]
+            shape = x.shape[:2] + (write_cols,) + x.shape[3:]
+            if (shape, x.dtype) not in zeros:
+                zeros[shape, x.dtype] = jnp.zeros(shape, x.dtype)
+            window[name] = zeros[shape, x.dtype]
+        view.update(kv_mask=vmask, capacity=self.max_blocks_per_slot * bs)
+        window.update(pos=jnp.int32(0), kv_mask=jnp.zeros((b, write_cols), jnp.int32))
         if self._stateful:
             # State held by slot rides in the window (it is read AND written);
             # what a model derives from the view once a program (compressed
@@ -1011,31 +1032,24 @@ class ContinuousBatcher:
         ladder = self._view_ladder[-1:] if self.speculative_k else self._view_ladder
         return next((nb for nb in ladder if longest <= nb * bs), ladder[-1]) * bs
 
-    def _scatter_pool(self, pool, blk, off, k_new, v_new, mask_new):
+    def _scatter_pool(self, pool, blk, off, written: dict, mask_new):
         """Append freshly written view columns onto chain tails — the single
         pool write point shared by the chunk / decode-window / spec-verify
-        programs. An int8 pool (``kv_quant``) quantizes the written rows here,
+        programs. ``written``: the new rows of each entry paged by token, by
+        name. An int8 pool (``kv_quant``) quantizes the written rows here,
         one (int8 payload, f32 scale) pair per token row (ops/int8.quantize_kv
         — a committed row is never rescaled, which is what lets blocks fill
         incrementally), and dequantizes at view assembly, so the quantization
         seam is invisible to the model forward."""
-        if "k_scale" in pool:
-            qk, sk = quantize_kv(k_new)
-            qv, sv = quantize_kv(v_new)
-            return {
-                **pool,
-                "k": pool["k"].at[:, blk, off].set(qk),
-                "v": pool["v"].at[:, blk, off].set(qv),
-                "k_scale": pool["k_scale"].at[:, blk, off].set(sk),
-                "v_scale": pool["v_scale"].at[:, blk, off].set(sv),
-                "mask": pool["mask"].at[blk, off].set(mask_new),
-            }
-        return {
-            **pool,
-            "k": pool["k"].at[:, blk, off].set(k_new),
-            "v": pool["v"].at[:, blk, off].set(v_new),
-            "mask": pool["mask"].at[blk, off].set(mask_new),
-        }
+        if pool_is_quantized(pool):
+            quantized = {name: quantize_kv(new) for name, new in written.items()}
+            written = {**{name: q for name, (q, _) in quantized.items()},
+                       **{name + "_scale": scale for name, (_, scale) in quantized.items()}}
+        out = dict(pool)
+        for name, new in written.items():  # payloads, then their scales
+            out[name] = pool[name].at[:, blk, off].set(new)
+        out["mask"] = pool["mask"].at[blk, off].set(mask_new)
+        return out
 
     def _chunk_fn(self, P: int):
         """Compiled prefill of ONE ``P``-token chunk of one slot's prompt
@@ -1093,7 +1107,7 @@ class ContinuousBatcher:
                 blk = table[idx // bs]
             off = idx % bs
             pool = self._scatter_pool(
-                pool, blk, off, out["cache"]["k"][:, 0], out["cache"]["v"][:, 0],
+                pool, blk, off, {name: out["cache"][name][:, 0] for name in self._by_token},
                 jnp.where(blk != 0, mask_row, 0),
             )
             for name in by_slot:  # the prefilled slot's state alone is written
@@ -1105,14 +1119,14 @@ class ContinuousBatcher:
                 # which are written exactly once, here) carries draft KV by
                 # the time the first verify round needs it.
                 d_view, d_window = self._paged_view_cache(
-                    d_pool, table[None], length[None], P)
+                    d_pool, table[None], length[None], P, self._draft_by_token)
                 d_out = d_module.apply(
                     d_params, input_ids=ids, attention_mask=mask,
                     cache={**d_window, "view": d_view},
                     positions=mask_positions(mask) + base_pos)
                 d_pool = self._scatter_pool(
                     d_pool, blk, off,
-                    d_out["cache"]["k"][:, 0], d_out["cache"]["v"][:, 0],
+                    {name: d_out["cache"][name][:, 0] for name in self._draft_by_token},
                     jnp.where(blk != 0, mask_row, 0),
                 )
             real = jnp.sum(mask_row).astype(jnp.int32)
@@ -1315,7 +1329,7 @@ class ContinuousBatcher:
             )
             off = (idx % bs).astype(jnp.int32)
             pool = self._scatter_pool(
-                pool, blk, off, window["k"], window["v"],
+                pool, blk, off, {name: window[name] for name in self._by_token},
                 jnp.where(blk != 0, window["kv_mask"], 0),
             )
             for name in by_slot:
@@ -1409,7 +1423,8 @@ class ContinuousBatcher:
             # ACCEPTED draft token's draft-KV is written too — without it a
             # fully-accepted round would leave the draft chain one column
             # short of the target chain.
-            d_view, d_window = self._paged_view_cache(d_pool, tables, lens, S)
+            d_view, d_window = self._paged_view_cache(d_pool, tables, lens, S,
+                                                      self._draft_by_token)
 
             def d_step(carry, _):
                 d_window, d_tok, d_pos = carry
@@ -1486,11 +1501,11 @@ class ContinuousBatcher:
             off = (idx % bs).astype(jnp.int32)
             window = out["cache"]
             pool = self._scatter_pool(
-                pool, blk, off, window["k"], window["v"],
+                pool, blk, off, {name: window[name] for name in self._by_token},
                 jnp.where(blk != 0, window["kv_mask"], 0),
             )
             d_pool = self._scatter_pool(
-                d_pool, blk, off, d_window["k"], d_window["v"],
+                d_pool, blk, off, {name: d_window[name] for name in self._draft_by_token},
                 jnp.where(blk != 0, d_window["kv_mask"], 0),
             )
             report = jax.lax.optimization_barrier((state[3], state[2], state[4]))

@@ -154,6 +154,24 @@ def run_prefill_only(engine, rid: int) -> None:
         state = engine._dispatch_chunk(s, state)
 
 
+def _pool_layout(engine) -> dict:
+    """What both tiers' pools must agree on: the first token-paged entry's
+    layers, heads, dim and dtype under the names they always had, and, for a
+    model that names its own entries, each entry's ``[layers, heads, dim,
+    dtype]``."""
+    first = engine._pool[engine._by_token[0]]
+    layout = {
+        "layers": int(first.shape[0]), "kv_heads": int(first.shape[3]),
+        "head_dim": int(first.shape[4]), "block_size": engine.block_size,
+        "dtype": str(np.dtype(first.dtype).name),
+    }
+    if engine._by_token != ("k", "v"):
+        layout["by_token"] = {
+            name: [int(x.shape[0]), int(x.shape[3]), int(x.shape[4]), str(np.dtype(x.dtype).name)]
+            for name, x in ((name, engine._pool[name]) for name in engine._by_token)}
+    return layout
+
+
 # ------------------------------------------------------------------ export
 def export_chain(engine, rid: int, endpoint: str | None = None,
                  free: bool = True) -> dict:
@@ -189,9 +207,8 @@ def export_chain(engine, rid: int, endpoint: str | None = None,
     slot_len = int(engine._slot_len[s])
     n_data = -(-slot_len // bs)
     data_ids = engine._slot_blocks[s][:n_data]
-    chain = export_chain_blocks(engine._pool, data_ids)
-    chain_enc = {name: _encode(host_fetch(chain[name])) for name in ("k", "v", "mask")}
-    pool_k = engine._pool["k"]
+    chain = export_chain_blocks(engine._pool, data_ids, engine._by_token)
+    chain_enc = {name: _encode(host_fetch(chain[name])) for name in (*engine._by_token, "mask")}
     # One blocking fetch per field is fine here: export is a per-request
     # boundary event, not the steady-state decode loop.
     slot = {
@@ -211,13 +228,7 @@ def export_chain(engine, rid: int, endpoint: str | None = None,
     payload = {
         "version": PAYLOAD_VERSION,
         "rid": int(rid),
-        "model": {
-            "layers": int(pool_k.shape[0]),
-            "kv_heads": int(pool_k.shape[3]),
-            "head_dim": int(pool_k.shape[4]),
-            "block_size": bs,
-            "dtype": str(np.dtype(pool_k.dtype).name),
-        },
+        "model": _pool_layout(engine),
         "chain": chain_enc,
         "data_blocks": n_data,
         "reserved_blocks": len(engine._slot_blocks[s]),
@@ -268,13 +279,8 @@ def import_chain(engine, payload: dict, endpoint: str | None = None) -> int:
             f"handoff payload version {payload.get('version')!r} != "
             f"{PAYLOAD_VERSION}; tiers must run the same serving build"
         )
-    pool_k = engine._pool["k"]
     model = payload["model"]
-    local = {
-        "layers": int(pool_k.shape[0]), "kv_heads": int(pool_k.shape[3]),
-        "head_dim": int(pool_k.shape[4]), "block_size": engine.block_size,
-        "dtype": str(np.dtype(pool_k.dtype).name),
-    }
+    local = _pool_layout(engine)
     if model != local:
         raise ValueError(
             f"handoff layout mismatch: exporter {model} vs importer {local} "
@@ -308,8 +314,8 @@ def import_chain(engine, payload: dict, endpoint: str | None = None) -> int:
     for blk in fresh:
         engine._block_ref[blk] += 1
     chain = {name: jnp.asarray(_decode(payload["chain"][name]))
-             for name in ("k", "v", "mask")}
-    engine._pool = import_chain_blocks(engine._pool, fresh[:n_data], chain)
+             for name in (*engine._by_token, "mask")}
+    engine._pool = import_chain_blocks(engine._pool, fresh[:n_data], chain, engine._by_token)
     slot = payload["slot"]
     prompt = _decode(payload["tokens"])
     engine._tables_np[s, :] = 0

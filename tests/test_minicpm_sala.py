@@ -323,8 +323,8 @@ def test_cache_layout_record_and_pool_layout(small):
     class Odd:
         cache_layout = {"by_token": ("keys",)}
 
-    with pytest.raises(ValueError, match="pages 'k' and 'v' by token"):
-        cache_layout(Odd())
+    # A model names its own token-paged entries (PR 38: the refusal went with the k/v-only pool).
+    assert cache_layout(Odd())["by_token"] == ("keys",)
 
 
 # ------------------------------------------ (c) and (d): the state held by slot
