@@ -24,6 +24,10 @@ from .utils.dataclasses import (
     SequenceParallelPlugin,
     TensorParallelPlugin,
 )
+# Registers the listener that records each program's start-up into the span
+# ring (telemetry/spans.py), so that every program built after this import is
+# recorded, the model's weights among them.
+from .telemetry import spans as _spans  # noqa: F401
 
 
 def __getattr__(name):

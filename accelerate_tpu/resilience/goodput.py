@@ -6,7 +6,8 @@ XLA compiles, checkpoint saves, restores after a preemption, restart backoff —
 is *badput*: time the chips were reserved but no tokens were trained. This
 module keeps one process-wide ledger that the rest of the framework feeds
 (``checkpointing`` times saves/restores, ``run_resilient`` times restart
-downtime, ``bench.py`` times compiles and steps) and that surfaces in two
+downtime, ``telemetry/spans.py`` books each program's trace, lowering and
+compile as JAX reports them, ``bench.py`` times steps) and that surfaces in two
 places: ``Accelerator.log_goodput()`` pushes the breakdown through the normal
 tracker path, and ``bench.py`` embeds it in its JSON lines. The telemetry
 registry (telemetry/metrics.py) additionally exports the summary as
